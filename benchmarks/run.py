@@ -21,12 +21,15 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.launch import compile_cache
+
 
 def _section(title):
     print(f"\n{'=' * 72}\n== {title}\n{'=' * 72}", flush=True)
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="fewer epochs / one seed for the NoC sims")

@@ -32,7 +32,7 @@ axis with the upper VCs masked off, so 2-subnet and 4-subnet configurations
 share ONE compiled program.  ``simulate_batch`` vmaps that program over a
 leading batch axis (configs x workloads x seeds evaluated in lockstep, with
 donated carry buffers) and can shard that axis data-parallel across devices
-(``devices=``/``mesh=``, via the `repro.dist.sharding.shard_map` shim);
+(``devices=``/``mesh=``, via `jax.shard_map`);
 ``sweep`` / ``sweep_sharded`` are the drivers the paper-figure benchmarks
 run on.
 
@@ -1033,10 +1033,21 @@ def simulate(
     full-cycle lane kernel); each backend is its own `SimStatic`, so opting
     into a Pallas path never perturbs the default program's trace count.
     """
+    return _SIM_JIT(*sim_args(cfg, source, padded, backend))
+
+
+def sim_args(
+    cfg: NoCConfig,
+    source: TrafficSourceLike,
+    padded: bool = True,
+    backend: str | None = None,
+) -> tuple:
+    """The `_SIM_JIT` arguments `simulate` dispatches: (SimStatic, policy,
+    demand rows, seed, initial state, faults, placement)."""
     stc = cfg.static_spec(padded)
     if backend is not None:
         stc = dataclasses.replace(stc, backend=backend)
-    return _SIM_JIT(
+    return (
         stc,
         cfg.mode_policy(padded),
         resolve_source(source, stc.n_epochs),
@@ -1091,16 +1102,12 @@ def _sharded_jit(stc: SimStatic, mesh):
     """Data-parallel batched entry: the vmapped program under shard_map.
 
     The batch axis is split across the mesh's `sweep` axis; each device runs
-    the SAME per-shard vmapped program with no cross-device communication
-    (psum-free), which keeps it clear of the jax-0.4.37 partial-manual
-    collective SIGABRT (DESIGN.md §10) — all mesh axes are manual here and
-    no collective is ever emitted.
+    the SAME per-shard vmapped program with no cross-device communication:
+    every mesh axis is manual here and no collective is ever emitted.
     """
     key = (stc, mesh)
     if key not in _SHARD_JIT:
         from jax.sharding import PartitionSpec as P
-
-        from repro.dist import sharding as dist_sharding
 
         batched = jax.vmap(_simulate_impl, in_axes=(None, 0, 0, 0, 0, 0, 0))
 
@@ -1108,21 +1115,68 @@ def _sharded_jit(stc: SimStatic, mesh):
             return batched(stc, mp, prof, seeds, state0, flt, plc)
 
         spec = P(SWEEP_AXIS)
-        # check_vma off: jax 0.4.37's replication checker mis-types the
-        # epoch-scan carry under shard_map and aborts the trace; with every
-        # mesh axis manual and zero collectives the check has nothing to
-        # verify here anyway.  Carry donation mirrors _batch_jit (state0 is
-        # shard_body arg 3; CPU has no donation support).
+        # check_vma off: the scans in `_simulate_impl` start carries such as
+        # the zeroed epoch counters from constants, which are invariant over
+        # the `sweep` axis while the scan bodies make them per-shard; jax
+        # 0.9.0 refuses such a scan unless every one of those carries is
+        # pcast to varying inside the program the unsharded paths share.
+        # No collective is emitted, so the check has nothing to verify.
+        # Carry donation mirrors _batch_jit (state0 is shard_body arg 3;
+        # CPU has no donation support).
         donate = () if jax.default_backend() == "cpu" else (3,)
         _SHARD_JIT[key] = jax.jit(
-            dist_sharding.shard_map(
+            jax.shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(spec,) * 6, out_specs=spec,
-                axis_names=(SWEEP_AXIS,), check_vma=False,
+                axis_names={SWEEP_AXIS}, check_vma=False,
             ),
             donate_argnums=donate,
         )
     return _SHARD_JIT[key]
+
+
+def batch_args(
+    cfgs: Sequence[NoCConfig],
+    sources: TrafficSourceLike | Sequence,
+    seeds: Sequence[int] | None = None,
+) -> tuple:
+    """Stack B configs into the batched program's inputs: (SimStatic,
+    policy, demand rows, seeds, faults, placement), each leaf with a
+    leading (B,) axis (see `simulate_batch` for the arguments)."""
+    cfgs = list(cfgs)
+    if not cfgs:
+        raise ValueError("simulate_batch needs at least one config")
+    stc = cfgs[0].static_spec()
+    for c in cfgs[1:]:
+        if c.static_spec() != stc:
+            raise ValueError(
+                "all configs in a batch must share the same structural "
+                f"config; got {c.static_spec()} != {stc} — group with sweep()"
+            )
+    B = len(cfgs)
+    # NB WorkloadProfile is itself a tuple, so a single source must be
+    # detected by type (name or TrafficSource), not by Sequence-ness.
+    if isinstance(sources, (str, TrafficSource)):
+        sources = [sources] * B
+    profiles = [resolve_source(s, stc.n_epochs) for s in sources]
+    if len(profiles) != B:
+        raise ValueError(f"{len(profiles)} sources for {B} configs")
+    if seeds is None:
+        seeds = [c.seed for c in cfgs]
+    seeds = jnp.asarray(list(seeds), jnp.int32)
+    if seeds.shape[0] != B:
+        raise ValueError(f"{seeds.shape[0]} seeds for {B} configs")
+
+    mp = jax.tree.map(lambda *xs: jnp.stack(xs), *[c.mode_policy() for c in cfgs])
+    prof = stack_profiles(profiles)
+    flt = jax.tree.map(
+        lambda *xs: jnp.stack(xs), *[_run_faults(c.faults, stc) for c in cfgs]
+    )
+    plc = jax.tree.map(
+        lambda *xs: jnp.stack(xs),
+        *[_run_placement(c.placement, stc) for c in cfgs],
+    )
+    return stc, mp, prof, seeds, flt, plc
 
 
 def simulate_batch(
@@ -1158,39 +1212,8 @@ def simulate_batch(
 
     Returns a `SimResult` whose leaves carry a leading (B,) axis.
     """
-    cfgs = list(cfgs)
-    if not cfgs:
-        raise ValueError("simulate_batch needs at least one config")
-    stc = cfgs[0].static_spec()
-    for c in cfgs[1:]:
-        if c.static_spec() != stc:
-            raise ValueError(
-                "all configs in a batch must share the same structural "
-                f"config; got {c.static_spec()} != {stc} — group with sweep()"
-            )
-    B = len(cfgs)
-    # NB WorkloadProfile is itself a tuple, so a single source must be
-    # detected by type (name or TrafficSource), not by Sequence-ness.
-    if isinstance(sources, (str, TrafficSource)):
-        sources = [sources] * B
-    profiles = [resolve_source(s, stc.n_epochs) for s in sources]
-    if len(profiles) != B:
-        raise ValueError(f"{len(profiles)} sources for {B} configs")
-    if seeds is None:
-        seeds = [c.seed for c in cfgs]
-    seeds = jnp.asarray(list(seeds), jnp.int32)
-    if seeds.shape[0] != B:
-        raise ValueError(f"{seeds.shape[0]} seeds for {B} configs")
-
-    mp = jax.tree.map(lambda *xs: jnp.stack(xs), *[c.mode_policy() for c in cfgs])
-    prof = stack_profiles(profiles)
-    flt = jax.tree.map(
-        lambda *xs: jnp.stack(xs), *[_run_faults(c.faults, stc) for c in cfgs]
-    )
-    plc = jax.tree.map(
-        lambda *xs: jnp.stack(xs),
-        *[_run_placement(c.placement, stc) for c in cfgs],
-    )
+    stc, mp, prof, seeds, flt, plc = batch_args(cfgs, sources, seeds)
+    B = int(seeds.shape[0])
 
     if devices is not None or mesh is not None:
         if mesh is None:

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import sharding
-
 Array = jax.Array
 
 
@@ -77,10 +75,13 @@ def pipeline_apply(
                 outs, jnp.where(valid, y, cur), out_idx, 0)
             return (jax.lax.ppermute(y, stage_axis, perm), outs), None
 
-        outs0 = jnp.zeros_like(mbs)
+        # the ring buffer and the outputs differ per stage from the first
+        # tick on, so their initial values are typed as varying over stages
+        buf0, outs0 = jax.lax.pcast(
+            (jnp.zeros_like(mbs[0]), jnp.zeros_like(mbs)), (stage_axis,),
+            to="varying")
         (_, outs), _ = jax.lax.scan(
-            tick, (jnp.zeros_like(mbs[0]), outs0),
-            jnp.arange(n_micro + n_stages - 1))
+            tick, (buf0, outs0), jnp.arange(n_micro + n_stages - 1))
         # only the last stage holds results; sum-select replicates them
         outs = jax.lax.psum(
             jnp.where(s == n_stages - 1, outs, jnp.zeros_like(outs)),
@@ -88,8 +89,8 @@ def pipeline_apply(
         return outs
 
     w_specs = jax.tree.map(lambda _: P(stage_axis), params)
-    return sharding.shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(w_specs, P()), out_specs=P(),
-        axis_names={stage_axis}, check_vma=False,
+        axis_names={stage_axis},
     )(params, microbatches)

@@ -200,15 +200,7 @@ def _in_manual_region() -> bool:
     """True while tracing inside shard_map (named mesh axes in scope).
     with_sharding_constraint over manual axes is invalid there — the body is
     already per-device — so `constrain` becomes the identity."""
-    try:
-        env = jax.core.trace_ctx.axis_env          # jax <= 0.4.x
-        return bool(getattr(env, "axis_sizes", None))
-    except AttributeError:
-        pass
-    try:
-        return bool(jax.core.nonempty_axis_env_DO_NOT_USE())
-    except Exception:
-        return False
+    return bool(jax.core.nonempty_axis_env_DO_NOT_USE())
 
 
 def constrain(x, *logical_axes):
@@ -231,9 +223,8 @@ def sweep_mesh(n_devices: Optional[int] = None, axis: str = "sweep"):
     """A 1-D mesh over host-local devices for data-parallel sweep dispatch.
 
     The NoC sweep engine (core/noc/sim.py) splits its flat batch axis over
-    this mesh's single `sweep` axis — pure data parallelism, no collectives,
-    so the shard_map shim below stays on the psum-safe path on every jax
-    version.  `n_devices=None` takes every local device.
+    this mesh's single `sweep` axis — pure data parallelism, no
+    collectives.  `n_devices=None` takes every local device.
     """
     import numpy as np
 
@@ -245,29 +236,3 @@ def sweep_mesh(n_devices: Optional[int] = None, axis: str = "sweep"):
         )
     return jax.sharding.Mesh(np.asarray(devs[:n]), (axis,))
 
-
-# --------------------------------------------------------------------------
-# shard_map version compat
-# --------------------------------------------------------------------------
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma: bool = True):
-    """`jax.shard_map` across jax versions.
-
-    New jax exposes jax.shard_map(axis_names=..., check_vma=...); 0.4.x has
-    jax.experimental.shard_map.shard_map(auto=..., check_rep=...) with the
-    complementary axis set.  Call sites (train/step.py, dist/pipeline.py)
-    use the new-style signature.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset()
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)

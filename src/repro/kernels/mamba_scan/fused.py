@@ -28,7 +28,7 @@ from jax.experimental.pallas import tpu as pltpu
 def _fused_kernel(
     dt_ref, xc_ref, b_ref, c_ref, a_mat_ref,   # (1,TC,TD) x2, (1,TC,S) x2, (TD,S)
     y_ref, hlast_ref,                          # (1,TC,TD), (1,TD,S)
-    h_scr,                                     # VMEM (TD, S)
+    h_scr,                                     # VMEM (1, TD, S)
     *,
     chunk: int,
     n_chunks: int,
@@ -60,13 +60,15 @@ def _fused_kernel(
                  jnp.where(live, a * b_prev + bx, bx))
         shift *= 2
 
-    hs = a * h_scr[...][None] + bx                         # (TC, TD, S)
+    hs = a * h_scr[...] + bx                               # (TC, TD, S)
     y_ref[0] = jnp.sum(hs * ct[:, None, :], axis=-1).astype(y_ref.dtype)
-    h_scr[...] = hs[-1]
+    # static slice: a value index (`hs[-1]`) lowers to a dynamic_slice,
+    # which Mosaic does not implement
+    h_scr[...] = hs[chunk - 1:chunk]
 
     @pl.when(il == n_chunks - 1)
     def _final():
-        hlast_ref[0] = hs[-1]
+        hlast_ref[...] = h_scr[...]
 
 
 def fused_mamba_scan_kernel(
@@ -107,7 +109,7 @@ def fused_mamba_scan_kernel(
             jax.ShapeDtypeStruct((bsz, L, d), jnp.float32),
             jax.ShapeDtypeStruct((bsz, d, s), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_d, s), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_d, s), jnp.float32)],
         interpret=interpret,
     )(dt, xc, b, c, a_mat)
     return y, hlast

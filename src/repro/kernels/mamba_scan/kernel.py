@@ -30,7 +30,7 @@ from jax.experimental.pallas import tpu as pltpu
 def _scan_kernel(
     a_ref, b_ref, h0_ref,     # (1, TC, TD, S), (1, TC, TD, S), (1, TD, S)
     hs_ref, hlast_ref,        # (1, TC, TD, S), (1, TD, S)
-    h_scr,                    # VMEM (TD, S) fp32 carry across chunks
+    h_scr,                    # VMEM (1, TD, S) fp32 carry across chunks
     *,
     chunk: int,
     n_chunks: int,
@@ -39,7 +39,7 @@ def _scan_kernel(
 
     @pl.when(il == 0)
     def _init():
-        h_scr[...] = h0_ref[0]
+        h_scr[...] = h0_ref[...]
 
     a = a_ref[0]              # (TC, TD, S)
     b = b_ref[0]
@@ -59,13 +59,15 @@ def _scan_kernel(
 
     # prefix over the chunk composed with the incoming carry
     h0 = h_scr[...]
-    hs = a * h0[None] + b     # (TC, TD, S)
+    hs = a * h0 + b           # (TC, TD, S)
     hs_ref[0] = hs
-    h_scr[...] = hs[-1]
+    # the last row is read back through the ref: a value index (`hs[-1]`)
+    # lowers to a dynamic_slice, which Mosaic does not implement
+    h_scr[...] = hs_ref[0, pl.ds(chunk - 1, 1)]
 
     @pl.when(il == n_chunks - 1)
     def _final():
-        hlast_ref[0] = hs[-1]
+        hlast_ref[...] = h_scr[...]
 
 
 def mamba_scan_kernel(
@@ -101,7 +103,7 @@ def mamba_scan_kernel(
             jax.ShapeDtypeStruct((bsz, L, d, s), jnp.float32),
             jax.ShapeDtypeStruct((bsz, d, s), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_d, s), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_d, s), jnp.float32)],
         interpret=interpret,
     )(a, b, h0)
     return hs, hlast

@@ -20,9 +20,10 @@ subnet block is XY-shift-closed (a mesh neighbor is always `l +/- 1` or
 padded or masked lanes only).  Per-node state (MC queues, MSHRs, source
 backlogs, burst phase, epoch counters) rides one 128-lane block with
 routers in lanes `0..R-1`.  Rows are the microarchitectural axes,
-flattened C-style exactly like the dense state:
+flattened C-style like the dense state, except that the buffer slot
+axis comes first so each slot is one contiguous (P*V)-row block:
 
-  buf_meta/buf_binj : (P*V*B, S*64)  row = (p*V + v)*B + b
+  buf_meta/buf_binj : (B*P*V, S*64)  row = b*P*V + p*V + v
   head/count        : (P*V,   S*64)  row = p*V + v
   rr                : (P,     S*64)
   mcq               : (Q,     128)
@@ -142,8 +143,8 @@ class LaneDims(NamedTuple):
 class LaneState(NamedTuple):
     """The whole cycle-scan carry in lane layout (all int32, lanes last)."""
 
-    buf_meta: Array  # (P*V*B, S*64)
-    buf_binj: Array  # (P*V*B, S*64)
+    buf_meta: Array  # (B*P*V, S*64)
+    buf_binj: Array  # (B*P*V, S*64)
     head: Array      # (P*V,   S*64)
     count: Array     # (P*V,   S*64)
     rr: Array        # (P,     S*64)
@@ -195,6 +196,18 @@ class LaneArb(NamedTuple):
     sel: list        # O x (PV, L) bool — winner one-hot over requesters
 
 
+def _bsel(c: Array, a: Array, b: Array) -> Array:
+    """`jnp.where(c, a, b)` for bool `a`/`b` as mask logic: Mosaic cannot
+    lower a select over i1 vectors (it truncates through i8)."""
+    return (c & a) | (~c & b)
+
+
+def stack_rows(rows: list) -> Array:
+    """Concatenate bool rows along sublanes as int32 0/1: Mosaic cannot
+    relayout i1 vectors through a concatenate."""
+    return jnp.concatenate([r.astype(jnp.int32) for r in rows], axis=0)
+
+
 def lane_arbitrate(
     valid: Array,    # (PV, L) bool — head packet present
     cls: Array,      # (PV, L) int32
@@ -242,7 +255,7 @@ def lane_arbitrate(
         sel_o = pv_iota == win_o                       # (PV, L) one-hot
         wcls_o = jnp.sum(jnp.where(sel_o, cls, 0), axis=0, keepdims=True)
 
-        allowed = jnp.where(wcls_o == 1, gmask, cmask)  # (V, L)
+        allowed = _bsel(wcls_o == 1, gmask, cmask)      # (V, L)
         dc_o = down[o * V:(o + 1) * V, :]               # (V, L)
         has = (dc_o < depth) & allowed
         credit_o = jnp.any(has, axis=0, keepdims=True)
@@ -303,11 +316,15 @@ def _shift(x: Array, delta: int) -> Array:
 
 def _tile_r(x: Array, S: int) -> Array:
     """Broadcast a per-node (k, 128) row onto the (k, S*64) subnet lanes."""
+    if x.dtype == jnp.bool_:  # lane relayouts of i1 vectors do not lower
+        return _tile_r(x.astype(jnp.int32), S) != 0
     return jnp.concatenate([x[:, :R_PAD]] * S, axis=1)
 
 
 def _pad_r(x: Array) -> Array:
     """Pad a (k, 64) router block back up to the (k, 128) node lanes."""
+    if x.dtype == jnp.bool_:
+        return _pad_r(x.astype(jnp.int32)) != 0
     k, w = x.shape
     pad = jnp.zeros((k, LANES_R - w), x.dtype)
     return jnp.concatenate([x, pad], axis=1)
@@ -373,10 +390,10 @@ def router_stage_lanes(
 ):
     """One full router cycle over lanes (cycle_body stage 2 / router_cycle).
 
-    Head peeks are B-step one-hot sums over strided buffer rows, the route
-    lookup is an R-step one-hot sum over the route table rows, and every
-    neighbor gather (downstream credit, upstream traversal) is a static
-    lane shift: input port p of lane l is driven only by lane
+    Head peeks are B-step one-hot sums over the slot-major buffer blocks,
+    the route lookup is an R-step one-hot sum over the route table rows,
+    and every neighbor gather (downstream credit, upstream traversal) is
+    a static lane shift: input port p of lane l is driven only by lane
     `l + deltas[p]`'s output port `OPP[p]`.  Cross-block and mesh-edge
     shift reads are garbage but always masked by `exists` before use.
 
@@ -388,15 +405,15 @@ def router_stage_lanes(
     §14; dead code when probes are off).
     """
     i32 = jnp.int32
-    V, B, P = d.V, d.B, N_PORTS
+    V, B, P, PV = d.V, d.B, N_PORTS, d.PV
 
     # --- peek head-of-line packets
     meta_h = jnp.zeros_like(head)
     binj_h = jnp.zeros_like(head)
     for b in range(B):
         at_b = head == b
-        meta_h = meta_h + jnp.where(at_b, buf_meta[b::B], 0)
-        binj_h = binj_h + jnp.where(at_b, buf_binj[b::B], 0)
+        meta_h = meta_h + jnp.where(at_b, buf_meta[b * PV:(b + 1) * PV], 0)
+        binj_h = binj_h + jnp.where(at_b, buf_binj[b * PV:(b + 1) * PV], 0)
     dest_h = meta_h & ((1 << META_SRC_SHIFT) - 1)
     cls_h = meta_h >> META_CLS_SHIFT
     valid = count > 0
@@ -453,7 +470,7 @@ def router_stage_lanes(
     eject_src = w_src[PORT_L:PORT_L + 1]
     eject_cls = arb.w_cls[PORT_L]
     eject_binj = w_binj[PORT_L:PORT_L + 1]
-    moved = jnp.sum(jnp.concatenate(arb.grant, axis=0).astype(i32))
+    moved = jnp.sum(stack_rows(arb.grant))
     blocked_local = arb.any_req[PORT_L] & ~accept
     dram_block_gpu = jnp.sum(
         (blocked_local & (arb.w_cls[PORT_L] == 1)).astype(i32)
@@ -468,30 +485,30 @@ def router_stage_lanes(
 
     # --- link traversals as dense pulls through static lane shifts
     tail = (head2 + count2) % B
-    new_meta, new_binj, vmask_rows = [], [], []
+    v_iota = jax.lax.broadcasted_iota(i32, (V, head.shape[1]), 0)
+    vmask_p, in_meta_p, in_binj_p = [], [], []
     for p in range(P):
         po = OPP[p]
         dl = d.deltas[p]
         in_ok = _shift(arb.grant[po], dl) & exists[p:p + 1]
         in_vc = _shift(arb.down_vc[po], dl)
-        in_meta = _shift(w_meta[po:po + 1], dl)
-        in_binj = _shift(w_binj[po:po + 1], dl)
-        for v in range(V):
-            pv = p * V + v
-            vm = in_ok & (in_vc == v)
-            vmask_rows.append(vm)
-            for b in range(B):
-                row = pv * B + b
-                bm = vm & (tail[pv:pv + 1] == b)
-                new_meta.append(
-                    jnp.where(bm, in_meta, buf_meta[row:row + 1])
-                )
-                new_binj.append(
-                    jnp.where(bm, in_binj, buf_binj[row:row + 1])
-                )
+        vmask_p.append(in_ok & (in_vc == v_iota))                 # (V, L)
+        in_meta_p.append(jnp.broadcast_to(
+            _shift(w_meta[po:po + 1], dl), v_iota.shape))
+        in_binj_p.append(jnp.broadcast_to(
+            _shift(w_binj[po:po + 1], dl), v_iota.shape))
+    vmask = stack_rows(vmask_p) != 0                              # (PV, L)
+    in_meta = jnp.concatenate(in_meta_p, axis=0)
+    in_binj = jnp.concatenate(in_binj_p, axis=0)
+    new_meta, new_binj = [], []
+    for b in range(B):
+        bm = vmask & (tail == b)
+        blk = slice(b * PV, (b + 1) * PV)
+        new_meta.append(jnp.where(bm, in_meta, buf_meta[blk]))
+        new_binj.append(jnp.where(bm, in_binj, buf_binj[blk]))
     buf_meta2 = jnp.concatenate(new_meta, axis=0)
     buf_binj2 = jnp.concatenate(new_binj, axis=0)
-    count3 = count2 + jnp.concatenate(vmask_rows, axis=0).astype(i32)
+    count3 = count2 + vmask.astype(i32)
 
     return (
         buf_meta2, buf_binj2, head2, count3, rr2,
@@ -517,7 +534,7 @@ def inject_lanes(
     l0 = PORT_L * V
 
     lcount = count[l0:l0 + V]                         # (V, L)
-    allowed = jnp.where(cls == 1, gmask, cmask)       # (V, L)
+    allowed = _bsel(cls == 1, gmask, cmask)           # (V, L)
     has = (lcount < B) & allowed
     v_iota = jax.lax.broadcasted_iota(i32, has.shape, 0)
     first = jnp.min(jnp.where(has, v_iota, V), axis=0, keepdims=True)
@@ -527,24 +544,20 @@ def inject_lanes(
 
     tail = (head[l0:l0 + V] + lcount) % B
     meta = dest + (src << META_SRC_SHIFT) + (cls << META_CLS_SHIFT)
-    new_meta, new_binj, vmask_rows = [], [], []
-    for v in range(V):
-        vm = ok & (vc == v)
-        vmask_rows.append(vm)
-        for b in range(B):
-            row = (l0 + v) * B + b
-            bm = vm & (tail[v:v + 1] == b)
-            new_meta.append(jnp.where(bm, meta, buf_meta[row:row + 1]))
-            new_binj.append(jnp.where(bm, binj, buf_binj[row:row + 1]))
-    buf_meta2 = jnp.concatenate(
-        [buf_meta[:l0 * B]] + new_meta, axis=0
-    )
-    buf_binj2 = jnp.concatenate(
-        [buf_binj[:l0 * B]] + new_binj, axis=0
-    )
+    vmask = ok & (vc == v_iota)                       # (V, L)
+    # the Local port is the last port, so its V rows close every b-block
+    new_meta, new_binj = [], []
+    for b in range(B):
+        bm = vmask & (tail == b)
+        lo, hi = b * d.PV + l0, (b + 1) * d.PV
+        new_meta += [buf_meta[b * d.PV:lo],
+                     jnp.where(bm, meta, buf_meta[lo:hi])]
+        new_binj += [buf_binj[b * d.PV:lo],
+                     jnp.where(bm, binj, buf_binj[lo:hi])]
+    buf_meta2 = jnp.concatenate(new_meta, axis=0)
+    buf_binj2 = jnp.concatenate(new_binj, axis=0)
     count2 = jnp.concatenate(
-        [count[:l0], lcount + jnp.concatenate(vmask_rows, axis=0).astype(i32)],
-        axis=0,
+        [count[:l0], lcount + vmask.astype(i32)], axis=0
     )
     return buf_meta2, buf_binj2, count2, ok
 
@@ -654,8 +667,8 @@ def cycle_step_lanes(
 
     # ---- MC acceptance: queue depth BEFORE this cycle's service
     mc_count0 = st.mc[MC_COUNT:MC_COUNT + 1]
-    can_accept = jnp.where(is_mc_r, mc_count0 <= Q - n_req, True)
-    accept = jnp.where(sub_req, _tile_r(can_accept, S), True)
+    can_accept = ~is_mc_r | (mc_count0 <= Q - n_req)
+    accept = ~sub_req | _tile_r(can_accept, S)
 
     # ---- 1. MC service
     mc_head, mc_count, mc_timer, svalid, sdst, scls = mc_service_lanes(
@@ -988,8 +1001,8 @@ def pack_state(
         axis=0,
     )
     return LaneState(
-        buf_meta=_to_sr_rows(d, subs.buf_meta),
-        buf_binj=_to_sr_rows(d, subs.buf_binj),
+        buf_meta=_to_sr_rows(d, jnp.moveaxis(subs.buf_meta, -1, 2)),
+        buf_binj=_to_sr_rows(d, jnp.moveaxis(subs.buf_binj, -1, 2)),
         head=_to_sr_rows(d, subs.head),
         count=_to_sr_rows(d, subs.count),
         rr=_to_sr_rows(d, subs.rr_ptr),
@@ -1008,8 +1021,10 @@ def unpack_state(d: LaneDims, ls: LaneState, mc_cls, binj_dtype):
     engine's wraparound store)."""
     P, V, B = N_PORTS, d.V, d.B
     subs = SubnetState(
-        buf_meta=_from_sr_rows(d, ls.buf_meta, (P, V, B), jnp.int16),
-        buf_binj=_from_sr_rows(d, ls.buf_binj, (P, V, B), binj_dtype),
+        buf_meta=jnp.moveaxis(
+            _from_sr_rows(d, ls.buf_meta, (B, P, V), jnp.int16), 2, -1),
+        buf_binj=jnp.moveaxis(
+            _from_sr_rows(d, ls.buf_binj, (B, P, V), binj_dtype), 2, -1),
         head=_from_sr_rows(d, ls.head, (P, V), jnp.int8),
         count=_from_sr_rows(d, ls.count, (P, V), jnp.int8),
         rr_ptr=_from_sr_rows(d, ls.rr, (P,), jnp.int8),

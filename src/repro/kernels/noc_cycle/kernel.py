@@ -53,12 +53,12 @@ def _noc_cycle_kernel(
         active_ref[...] != 0,
         depth=depth,
     )
-    grant_ref[...] = jnp.concatenate(arb.grant, axis=0).astype(jnp.int32)
+    grant_ref[...] = fused.stack_rows(arb.grant)
     winner_ref[...] = jnp.concatenate(arb.winner, axis=0)
     down_vc_ref[...] = jnp.concatenate(arb.down_vc, axis=0)
     deq_ref[...] = arb.deq
     new_rr_ref[...] = jnp.concatenate(arb.new_rr, axis=0)
-    any_req_ref[...] = jnp.concatenate(arb.any_req, axis=0).astype(jnp.int32)
+    any_req_ref[...] = fused.stack_rows(arb.any_req)
     w_cls_ref[...] = jnp.concatenate(arb.w_cls, axis=0)
 
 

@@ -16,6 +16,7 @@ single device.
 """
 import argparse
 import json
+import sys
 import time
 import traceback
 
@@ -25,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 
 import repro.configs as configs
 from repro.dist import sharding
+from repro.launch import compile_cache
 from repro.launch import hlo_cost, roofline, specs
 from repro.launch.mesh import make_production_mesh
 from repro.train import step as step_lib
@@ -231,6 +233,7 @@ def _mem_dict(mem) -> dict:
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)
@@ -253,6 +256,7 @@ def main():
 
     cells = (specs.all_cells() if args.all
              else [(args.arch, args.shape)])
+    n_errors = 0
     for arch, shape in cells:
         if arch is None or shape is None:
             ap.error("--arch/--shape required unless --all")
@@ -262,6 +266,7 @@ def main():
                        remat=args.remat, moe_group=args.moe_group,
                        fused_scan=args.fused_scan, tag=args.tag)
         status = rec["status"]
+        n_errors += status == "error"
         extra = ""
         if status == "ok":
             rl = rec["roofline"]
@@ -276,6 +281,10 @@ def main():
                   f"bytes/chip={rec['roofline']['hbm_bytes_per_chip']:.3e} "
                   f"wire/chip={rec['roofline']['wire_bytes_per_chip']:.3e}",
                   flush=True)
+        elif status == "error":
+            print(f"  error: {rec['error']}", file=sys.stderr, flush=True)
+    if n_errors:
+        raise SystemExit(f"[dryrun] {n_errors} cell(s) failed")
 
 
 if __name__ == "__main__":

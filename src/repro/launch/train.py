@@ -21,6 +21,7 @@ from repro.data import synthetic
 from repro.dist import sharding
 from repro.dist.kf_scheduler import KFScheduler, SchedulerConfig
 from repro.dist.telemetry import StaticCosts, Telemetry
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.train import loop as loop_lib
 from repro.train import optimizer as opt_lib
@@ -63,6 +64,7 @@ def build(arch: str, size: str, seq_len: int, global_batch: int,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b")
     ap.add_argument("--size", default="smoke", choices=["smoke", "full"])

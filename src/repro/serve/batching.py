@@ -23,6 +23,7 @@ class Request:
     t_first_token: float = -1.0
     t_done: float = -1.0
     tokens_out: int = 0
+    out_ids: list = dataclasses.field(default_factory=list)  # decoded ids
 
     @property
     def ttft(self) -> float:

@@ -152,9 +152,14 @@ class Engine:
             return
         logits, self.state = self._decode_fn(
             self.params, self._tokens, self.state)
+        # greedy: each slot's next input is its own argmax token
+        self._tokens = jnp.argmax(logits[:, -1], axis=-1).astype(
+            jnp.int32)[:, None]
+        ids = np.asarray(self._tokens[:, 0])
         self.clock += (len(active) * self.ecfg.c_decode + self.ecfg.c_iter)
         for i in active:
             r = self.slots[i]
+            r.out_ids.append(int(ids[i]))
             r.tokens_out += 1
             if r.tokens_out >= r.gen_len:
                 r.t_done = self.clock

@@ -26,7 +26,8 @@ def test_pipeline_multi_stage_numeric_and_grad():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.dist import pipeline
-        mesh = jax.make_mesh((4,), ("stage",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("stage",))
         key = jax.random.PRNGKey(0)
         w = jax.random.normal(key, (4, 8, 8)) * 0.3
         def stage_fn(p, x):
@@ -50,12 +51,13 @@ def test_comm_priority_multipod_compiles_with_int8_wire():
     out = _run("""
         import jax, jax.numpy as jnp, re
         import repro.configs as configs
+        from repro.launch.mesh import make_mesh
         from repro.dist import sharding
         from repro.launch import specs
         from repro.train import step as step_lib
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = configs.smoke("llama3.2-3b")
         opt_cfg = specs.default_opt_cfg(cfg)
         with sharding.activate(mesh):
@@ -103,7 +105,8 @@ def test_seq_parallel_option_changes_sharding():
     out = _run("""
         import jax, jax.numpy as jnp
         from repro.dist import sharding
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         with sharding.activate(mesh):
             sharding.set_option("seq_parallel", True)
             x = jnp.ones((2, 8, 4))
@@ -123,12 +126,13 @@ def test_comm_priority_variant_trains_equivalently():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         import repro.configs as configs
+        from repro.launch.mesh import make_mesh
         from repro.dist import sharding
         from repro.data import synthetic
         from repro.train import optimizer as opt_lib, step as step_lib
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = configs.smoke("llama3.2-3b")
         opt_cfg = opt_lib.OptimizerConfig(lr=1e-3, warmup_steps=1,
                                           total_steps=20)

@@ -208,7 +208,7 @@ class TestIdentityPlacement:
         """Committed PR-4 goldens replay bitwise with the placement layer
         in the loop, on every backend — the tentpole's no-regression pin."""
         with open(GOLDEN_PATH) as f:
-            golden = json.load(f)
+            golden = json.load(f)["cases"]
         for backend in BACKENDS:
             for key, g in golden.items():
                 mode, wl, gs, ss = key.split("/")
