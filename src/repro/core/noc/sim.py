@@ -60,6 +60,7 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from repro.core import kalman, predictor
 from repro.core.allocator import (
@@ -90,6 +91,7 @@ from repro.core.noc.placement import (
 )
 from repro.core.noc.topology import make_topology
 from repro.obs.probes import ProbeConfig, SimTrace
+from repro.obs.profiling import span
 from repro.core.noc.traffic import (
     TrafficSource,
     TrafficSourceLike,
@@ -518,6 +520,13 @@ def _simulate_impl(
         )
 
     def epoch_body(carry, epoch_xs):
+        # device labels (DESIGN.md §18): the epoch step is `epoch.boundary`
+        # except where a nested label names its RNG streams or cycle scan;
+        # the epoch loop itself carries none
+        with set_xla_metadata(noc_layer="epoch.boundary"):
+            return epoch_step(carry, epoch_xs)
+
+    def epoch_step(carry, epoch_xs):
         # prof: this epoch's scalar-leaf profile; flt: this epoch's fault
         # masks — link_ok (R, P), router_ok (R,), mc_ok (R,), telem ()s;
         # plc: this epoch's placement plans — cls0/cls1 (R,)
@@ -567,16 +576,17 @@ def _simulate_impl(
         # value-preserving transform), so every stream is bitwise-identical
         # to drawing inside the loop.
         ep_len = stc.epoch_len
-        keys = jax.random.split(epoch_key, ep_len)
-        k3 = jax.vmap(lambda k: jax.random.split(k, 3))(keys)
-        u_phase = jax.vmap(lambda k: jax.random.uniform(k, ()))(k3[:, 0])
-        u_gen = jax.vmap(
-            lambda k: jax.random.uniform(k, (R,), jnp.float32)
-        )(k3[:, 1])
-        d_idx = jax.vmap(
-            lambda k: jax.random.randint(k, (R,), 0, mc_ids.shape[0])
-        )(k3[:, 2])
-        dests_all = jnp.take(mc_ids, d_idx)                     # (L, R)
+        with set_xla_metadata(noc_layer="epoch.rng"):
+            keys = jax.random.split(epoch_key, ep_len)
+            k3 = jax.vmap(lambda k: jax.random.split(k, 3))(keys)
+            u_phase = jax.vmap(lambda k: jax.random.uniform(k, ()))(k3[:, 0])
+            u_gen = jax.vmap(
+                lambda k: jax.random.uniform(k, (R,), jnp.float32)
+            )(k3[:, 1])
+            d_idx = jax.vmap(
+                lambda k: jax.random.randint(k, (R,), 0, mc_ids.shape[0])
+            )(k3[:, 2])
+            dests_all = jnp.take(mc_ids, d_idx)                 # (L, R)
         cycles = cycle0 + jnp.arange(ep_len, dtype=jnp.int32)
         sa_all = epoch_sa_prefs(mp, config_idx, cycles)         # (L,)
         # subnet link activation: full width (2-subnet) or alternating-cycle
@@ -822,15 +832,18 @@ def _simulate_impl(
                 return (ls, pb), None
 
             if probe_on:
-                (ls, pb), _ = jax.lax.scan(
-                    fused_cycle_probed, (ls0, lanes.zero_probe(lane_dims)),
-                    (xi, xf), unroll=stc.cycle_unroll,
-                )
+                with set_xla_metadata(noc_layer="cycle.scan"):
+                    (ls, pb), _ = jax.lax.scan(
+                        fused_cycle_probed,
+                        (ls0, lanes.zero_probe(lane_dims)),
+                        (xi, xf), unroll=stc.cycle_unroll,
+                    )
                 prb = _ProbeAcc(*lanes.unpack_probe(lane_dims, pb))
             else:
-                ls, _ = jax.lax.scan(
-                    fused_cycle, ls0, (xi, xf), unroll=stc.cycle_unroll
-                )
+                with set_xla_metadata(noc_layer="cycle.scan"):
+                    ls, _ = jax.lax.scan(
+                        fused_cycle, ls0, (xi, xf), unroll=stc.cycle_unroll
+                    )
             subs, mc, outst, backlog, phase = lanes.unpack_state(
                 lane_dims, ls, MCState, subnets0.buf_binj.dtype
             )
@@ -841,13 +854,14 @@ def _simulate_impl(
             inner0 = (subs, mc, phase, outst, backlog, _zero_counters())
             if probe_on:
                 inner0 = inner0 + (_zero_probe_acc(S, R, V),)
-                (subs, mc, phase, outst, backlog, cnt, prb), _ = jax.lax.scan(
+            with set_xla_metadata(noc_layer="cycle.scan"):
+                inner, _ = jax.lax.scan(
                     cycle_body, inner0, xs, unroll=stc.cycle_unroll
                 )
+            if probe_on:
+                subs, mc, phase, outst, backlog, cnt, prb = inner
             else:
-                (subs, mc, phase, outst, backlog, cnt), _ = jax.lax.scan(
-                    cycle_body, inner0, xs, unroll=stc.cycle_unroll
-                )
+                subs, mc, phase, outst, backlog, cnt = inner
         cycle = cycle0 + jnp.int32(stc.epoch_len)
 
         # ---- KF epoch update (paper §3.2)
@@ -914,8 +928,9 @@ def _simulate_impl(
             out = (out, (prb, kfi, z, faults_active, cls_e))
         return (subs, mc, phase, outst, backlog, policy, pred_state, cycle), out
 
-    key0 = jax.random.PRNGKey(seed)
-    epoch_keys = jax.random.split(key0, stc.n_epochs)
+    with set_xla_metadata(noc_layer="epoch.rng"):
+        key0 = jax.random.PRNGKey(seed)
+        epoch_keys = jax.random.split(key0, stc.n_epochs)
     carry0 = (
         subnets0,
         mc0,
@@ -1033,7 +1048,10 @@ def simulate(
     full-cycle lane kernel); each backend is its own `SimStatic`, so opting
     into a Pallas path never perturbs the default program's trace count.
     """
-    return _SIM_JIT(*sim_args(cfg, source, padded, backend))
+    with span("noc.args"):
+        args = sim_args(cfg, source, padded, backend)
+    with span("noc.dispatch"):
+        return _SIM_JIT(*args)
 
 
 def sim_args(
@@ -1211,46 +1229,60 @@ def simulate_batch(
                 pass `mesh` to reuse one (must have a `sweep` axis).
 
     Returns a `SimResult` whose leaves carry a leading (B,) axis.
+
+    Host spans (DESIGN.md §18): `noc.args` around `batch_args` and each
+    tile's arguments, `noc.dispatch` around each call of the program,
+    `noc.rows` around cutting the answer back to B rows.
     """
-    stc, mp, prof, seeds, flt, plc = batch_args(cfgs, sources, seeds)
+    with span("noc.args"):
+        stc, mp, prof, seeds, flt, plc = batch_args(cfgs, sources, seeds)
     B = int(seeds.shape[0])
 
     if devices is not None or mesh is not None:
-        if mesh is None:
-            from repro.dist import sharding as dist_sharding
+        with span("noc.args"):
+            if mesh is None:
+                from repro.dist import sharding as dist_sharding
 
-            mesh = dist_sharding.sweep_mesh(devices)
-        ndev = int(mesh.devices.size)
-        padded_b = -(-B // ndev) * ndev
-        mp, prof, seeds, flt, plc = (
-            _pad_rows(t, padded_b - B) for t in (mp, prof, seeds, flt, plc)
-        )
-        out = _sharded_jit(stc, mesh)(
-            mp, prof, seeds, init_sim_state(stc, padded_b), flt, plc
-        )
-        return _tree_rows(out, slice(0, B))
+                mesh = dist_sharding.sweep_mesh(devices)
+            ndev = int(mesh.devices.size)
+            padded_b = -(-B // ndev) * ndev
+            mp, prof, seeds, flt, plc = (
+                _pad_rows(t, padded_b - B)
+                for t in (mp, prof, seeds, flt, plc)
+            )
+            state0 = init_sim_state(stc, padded_b)
+        with span("noc.dispatch"):
+            out = _sharded_jit(stc, mesh)(mp, prof, seeds, state0, flt, plc)
+        del state0  # the running program holds it; freed when it ends
+        with span("noc.rows"):
+            return _tree_rows(out, slice(0, B))
 
     tile = B if batch_tile is None else batch_tile
-    parts = []
+    outs = []
     for lo in range(0, B, tile):
-        sl = slice(lo, min(lo + tile, B))
-        n = sl.stop - sl.start
-        mp_t, prof_t, seeds_t, flt_t, plc_t = (
-            _tree_rows(t, sl) for t in (mp, prof, seeds, flt, plc)
-        )
-        if n < tile:  # pad the ragged tail by repeating row 0 (discarded)
+        with span("noc.args"):
+            sl = slice(lo, min(lo + tile, B))
+            n = sl.stop - sl.start
             mp_t, prof_t, seeds_t, flt_t, plc_t = (
-                _pad_rows(t, tile - n)
-                for t in (mp_t, prof_t, seeds_t, flt_t, plc_t)
+                _tree_rows(t, sl) for t in (mp, prof, seeds, flt, plc)
             )
-        out = _batch_jit()(
-            stc, mp_t, prof_t, seeds_t, init_sim_state(stc, tile), flt_t,
-            plc_t,
-        )
-        parts.append(_tree_rows(out, slice(0, n)))
-    if len(parts) == 1:
-        return parts[0]
-    return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
+            if n < tile:  # pad the ragged tail by repeating row 0 (discarded)
+                mp_t, prof_t, seeds_t, flt_t, plc_t = (
+                    _pad_rows(t, tile - n)
+                    for t in (mp_t, prof_t, seeds_t, flt_t, plc_t)
+                )
+            state0 = init_sim_state(stc, tile)
+        with span("noc.dispatch"):
+            out = _batch_jit()(
+                stc, mp_t, prof_t, seeds_t, state0, flt_t, plc_t,
+            )
+        del state0  # the running program holds it; freed when it ends
+        outs.append((out, n))
+    with span("noc.rows"):
+        parts = [_tree_rows(out, slice(0, n)) for out, n in outs]
+        if len(parts) == 1:
+            return parts[0]
+        return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
 
 
 class SweepSpec(NamedTuple):
@@ -1314,35 +1346,39 @@ def sweep(
     `simulate_batch`, and results come back as one `SimResult` per spec, in
     input order.  `overrides` are forwarded to every row's `NoCConfig`
     (e.g. n_epochs=30); `devices`/`mesh` select the device-sharded dispatch
-    path (see `simulate_batch`).
+    path (see `simulate_batch`).  The whole call is the host span
+    `noc.sweep` (DESIGN.md §18).
     """
-    specs = list(specs)
-    rows: list[SimResult | None] = [None] * len(specs)
-    groups: dict[SimStatic, list[int]] = defaultdict(list)
-    cfgs = []
-    for i, sp in enumerate(specs):
-        kw = dict(overrides)
-        kw.setdefault("faults", sp.faults)
-        kw.setdefault("guard", sp.guard)
-        kw.setdefault("placement", sp.placement)
-        kw.setdefault("control", sp.control)
-        cfg = NoCConfig(
-            mode=sp.mode, static_gpu_vcs=sp.static_gpu_vcs, seed=sp.seed,
-            predictor=sp.predictor, **kw,
-        )
-        cfgs.append(cfg)
-        groups[cfg.static_spec()].append(i)
-    for idxs in groups.values():
-        res = simulate_batch(
-            [cfgs[i] for i in idxs],
-            [specs[i].workload for i in idxs],
-            batch_tile=batch_tile,
-            devices=devices,
-            mesh=mesh,
-        )
-        for j, i in enumerate(idxs):
-            rows[i] = _tree_rows(res, j)
-    return rows
+    with span("noc.sweep"):
+        specs = list(specs)
+        rows: list[SimResult | None] = [None] * len(specs)
+        groups: dict[SimStatic, list[int]] = defaultdict(list)
+        cfgs = []
+        with span("noc.args"):
+            for i, sp in enumerate(specs):
+                kw = dict(overrides)
+                kw.setdefault("faults", sp.faults)
+                kw.setdefault("guard", sp.guard)
+                kw.setdefault("placement", sp.placement)
+                kw.setdefault("control", sp.control)
+                cfg = NoCConfig(
+                    mode=sp.mode, static_gpu_vcs=sp.static_gpu_vcs,
+                    seed=sp.seed, predictor=sp.predictor, **kw,
+                )
+                cfgs.append(cfg)
+                groups[cfg.static_spec()].append(i)
+        for idxs in groups.values():
+            res = simulate_batch(
+                [cfgs[i] for i in idxs],
+                [specs[i].workload for i in idxs],
+                batch_tile=batch_tile,
+                devices=devices,
+                mesh=mesh,
+            )
+            with span("noc.rows"):
+                for j, i in enumerate(idxs):
+                    rows[i] = _tree_rows(res, j)
+        return rows
 
 
 def sweep_sharded(

@@ -103,6 +103,8 @@ def noc_cycle_kernel(
             jax.ShapeDtypeStruct((r, lanes), jnp.int32) for r in out_rows
         ],
         interpret=interpret,
+        name="noc_cycle_arbitrate",
+        metadata={"noc_layer": "cycle.arbitrate"},
     )(valid, cls, out_port, rr_ptr, down_count, down_exists,
       gmask, cmask, sa_pref, accept, active)
 
@@ -242,6 +244,8 @@ def fused_cycle_kernel(
         out_specs=[spec(x) for x in carry],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in carry],
         interpret=interpret,
+        name="noc_cycle_fused",
+        metadata={"noc_layer": "cycle.kernel"},
     )(*ins, *carry)
     if probe is None:
         return fused.LaneState(*outs)

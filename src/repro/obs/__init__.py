@@ -1,6 +1,7 @@
-"""Flight-recorder observability layer (DESIGN.md §14).
+"""Flight-recorder observability layer (DESIGN.md §14, §18).
 
-Three layers, all opt-in and zero-cost when off:
+Four modules; the probes and the profiler captures are opt-in and cost
+nothing when off, the host spans cost two clock reads each:
 
   * probes.py    — `ProbeConfig` / `SimTrace`: per-epoch introspection
                    emitted by the traced simulator (occupancy, arbitration
@@ -9,12 +10,24 @@ Three layers, all opt-in and zero-cost when off:
   * ledger.py    — structured run records: the single append path for
                    BENCH_noc.json plus a JSONL mirror, with the schema
                    validator that benchmarks/check_bench.py enforces.
-  * profiling.py — jax.profiler trace contexts behind the fig drivers'
+  * profiling.py — `span(name)`: the program's host spans, each a
+                   `jax.profiler.TraceAnnotation` and a `jax.monitoring`
+                   span `/repro/<name, dots as slashes>`; and the
+                   jax.profiler trace contexts behind the fig drivers'
                    `--profile DIR` flag.
   * recorder.py  — `TraceRecorder`: captures the per-epoch demand rows of
                    any run as a replayable `traffic.RecordedTrace`
                    (DESIGN.md §15), optionally stamped with the observed
                    §14 telemetry digest.
+
+Names the program uses (DESIGN.md §18).  Host spans in `sim.sweep`,
+`sim.simulate_batch` and `sim.simulate`: `noc.sweep` (a whole sweep),
+`noc.args` (argument building), `noc.dispatch` (each call of the compiled
+program), `noc.rows` (cutting the answer into rows).  Device labels, the
+XLA frontend attribute `noc_layer` on the compiled operations of
+`_simulate_impl`: `epoch.rng`, `epoch.boundary`, `cycle.scan`; the
+`pallas_call`s carry `cycle.kernel` (fused) and `cycle.arbitrate` in their
+kernel metadata.
 """
 
 from repro.obs.probes import ProbeConfig, SimTrace

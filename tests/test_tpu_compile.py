@@ -12,6 +12,7 @@ only one process may load the TPU library at a time, and every test
 worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -90,14 +91,35 @@ def test_sim_program_compiles(one_chip, tpu_lowering, backend, side):
         sim._SIM_JIT.lower(stc, *_abstract(args, one_chip)).compile())
 
 
-def test_pallas_sweep_tile_compiles(one_chip, tpu_lowering):
+def _sweep_tile(one_chip):
     cfgs = [sim.NoCConfig(mode=m, backend="pallas", **SHORT)
             for m in ("4subnet", "baseline", "fair", "kf", "kf", "fair")]
     assert len(cfgs) == sim.SWEEP_TILE
     stc, mp, prof, seeds, flt, plc = sim.batch_args(cfgs, "BFS")
     state0 = sim.init_sim_state(stc, len(cfgs))
     args = _abstract((mp, prof, seeds, state0, flt, plc), one_chip)
-    _assert_kernel(sim._batch_jit().lower(stc, *args).compile())
+    return sim._batch_jit().lower(stc, *args).compile()
+
+
+def test_pallas_sweep_tile_compiles(one_chip, tpu_lowering):
+    _assert_kernel(_sweep_tile(one_chip))
+
+
+def test_sweep_tile_keeps_its_device_labels(one_chip, tpu_lowering):
+    """The `noc_layer` labels of `_simulate_impl` (DESIGN.md §18) survive
+    the TPU compiler on the operations a device trace names: top-level
+    fusions, the cycle loop and the kernel, whose own metadata the scan's
+    label sits beside without replacing it."""
+    text = _sweep_tile(one_chip).as_text()
+    assert re.search(r' fusion\(.*noc_layer="epoch\.rng"', text)
+    assert re.search(r' while\(.*noc_layer="cycle\.scan"', text)
+    assert re.search(r' fusion\(.*noc_layer="epoch\.boundary"', text)
+    kernel = re.search(
+        r'custom_call_target="tpu_custom_call".*?'
+        r'frontend_attributes=\{kernel_metadata=\{\s*'
+        r'"noc_layer":"cycle\.kernel"\s*\},noc_layer="cycle\.scan"\}',
+        text, re.S)
+    assert kernel
 
 
 def test_kf_bank_compiles(one_chip, tpu_lowering):
