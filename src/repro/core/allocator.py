@@ -24,6 +24,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.predictor import PredictorPolicy, predictor_policy
 
@@ -184,6 +185,10 @@ def mode_policy(
     controller, and the bitwise-identity default), "placement" (compute
     relocation between the placement stream's plans only), or "joint"
     (both).  Pure traced data — all three compile to one program.
+
+    The tensors are host (NumPy) arrays, built without a device op: the
+    simulator's argument layer crosses to the device once per dispatch
+    (DESIGN.md §18).
     """
     if control not in CONTROLS:
         raise ValueError(
@@ -195,34 +200,32 @@ def mode_policy(
         active_vcs = n_vcs
     if not 0 < active_vcs <= n_vcs:
         raise ValueError(f"active_vcs={active_vcs} outside (0, {n_vcs}]")
-    avail = jnp.arange(n_vcs) < active_vcs
+    avail = np.arange(n_vcs) < active_vcs
     if mode in ("baseline", "4subnet"):
         g0, c0 = avail, avail
     elif mode == "fair":
-        g0, c0 = vc_partition(jnp.int32(0), active_vcs)
+        g0, c0 = vc_partition(0, active_vcs)
     elif mode == "static":
-        g0 = (jnp.arange(n_vcs) < static_gpu_vcs) & avail
+        g0 = (np.arange(n_vcs) < static_gpu_vcs) & avail
         c0 = avail & ~g0
     elif mode == "kf":
-        g0, c0 = vc_partition(jnp.int32(0), active_vcs)
+        g0, c0 = vc_partition(0, active_vcs)
     else:
         raise ValueError(f"unknown mode {mode!r}")
     if mode == "kf":
-        g1, c1 = vc_partition(jnp.int32(1), active_vcs)
+        g1, c1 = vc_partition(1, active_vcs)
     else:
         g1, c1 = g0, c0  # config never leaves 0 when the KF is disabled
 
-    def pad_v(m: Array) -> Array:  # partition masks are built over active_vcs
-        if m.shape[0] == n_vcs:
-            return m
-        return jnp.concatenate([m, jnp.zeros((n_vcs - m.shape[0],), bool)])
+    def pad_v(m: np.ndarray) -> np.ndarray:  # masks are over active_vcs
+        return np.concatenate([m, np.zeros((n_vcs - m.shape[0],), bool)])
 
-    sub = jnp.arange(n_subnets)
+    sub = np.arange(n_subnets)
     if mode == "4subnet":
         if n_subnets != 4:
             raise ValueError("4subnet mode needs a 4-row subnet axis, got "
                              f"{n_subnets}")
-        sub_enabled = jnp.ones((n_subnets,), bool)
+        sub_enabled = np.ones((n_subnets,), bool)
         sub_is_req = sub % 2 == 0          # {CPU,GPU} x {req, reply}
     else:
         if n_subnets < 2:
@@ -234,14 +237,14 @@ def mode_policy(
     return ModePolicy(
         gpu_mask0=pad_v(g0), cpu_mask0=pad_v(c0),
         gpu_mask1=pad_v(g1), cpu_mask1=pad_v(c1),
-        sa_enable=jnp.asarray(is_kf), kf_enable=jnp.asarray(is_kf),
-        four_subnet=jnp.asarray(mode == "4subnet"),
+        sa_enable=np.asarray(is_kf), kf_enable=np.asarray(is_kf),
+        four_subnet=np.asarray(mode == "4subnet"),
         sub_enabled=sub_enabled,
         sub_is_req=sub_is_req,
         predictor=predictor_policy(predictor, ema_alpha=ema_alpha,
                                    guard=guard),
-        bw_enable=jnp.asarray(control != "placement"),
-        place_enable=jnp.asarray(control != "bandwidth"),
+        bw_enable=np.asarray(control != "placement"),
+        place_enable=np.asarray(control != "bandwidth"),
     )
 
 
@@ -326,16 +329,16 @@ def epoch_sa_prefs(policy: ModePolicy, config: Array, cycles: Array) -> Array:
                      jnp.int32(-1))
 
 
-def vc_partition(config: Array, n_vcs: int = 4) -> tuple[Array, Array]:
-    """Return boolean masks (gpu_vcs, cpu_vcs) over VC indices.
+def vc_partition(config: int, n_vcs: int = 4) -> tuple[np.ndarray, np.ndarray]:
+    """Return boolean masks (gpu_vcs, cpu_vcs) over VC indices, as host
+    (NumPy) arrays.
 
     config=0: GPU {0,1}, CPU {2,3}     (equal split)
     config=1: GPU {0,1,2}, CPU {3}     (75/25 boost)
     Generalized to n_vcs: equal split at n/2, boost at n-1.
     """
-    idx = jnp.arange(n_vcs)
-    gpu_hi = jnp.where(config > 0, n_vcs - 1, n_vcs // 2)  # exclusive bound
-    gpu_mask = idx < gpu_hi
+    gpu_hi = n_vcs - 1 if int(config) > 0 else n_vcs // 2  # exclusive bound
+    gpu_mask = np.arange(n_vcs) < gpu_hi
     return gpu_mask, ~gpu_mask
 
 

@@ -45,7 +45,6 @@ import difflib
 from typing import NamedTuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.noc.topology import N_PORTS, PORT_E, PORT_L, PORT_N, PORT_S, PORT_W
@@ -198,13 +197,7 @@ class FaultSchedule:
                             if nb >= 0:
                                 link_ok[np.ix_(epochs, [nb], [int(opp[p])])] \
                                     = False
-        return FaultStream(
-            link_ok=jnp.asarray(link_ok),
-            router_ok=jnp.asarray(router_ok),
-            mc_ok=jnp.asarray(mc_ok),
-            telem_mode=jnp.asarray(telem_mode),
-            telem_mag=jnp.asarray(telem_mag),
-        )
+        return FaultStream(link_ok, router_ok, mc_ok, telem_mode, telem_mag)
 
 
 def healthy_stream(
@@ -300,7 +293,8 @@ def resolve_faults(
 
     The ONE resolution path the simulator entry points call (mirroring
     `traffic.resolve_source`); the result is shape-validated so every
-    source kind feeds the simulator the same program shape.
+    source kind feeds the simulator the same program shape, and its
+    leaves are host (NumPy) arrays whatever the source held.
     """
     if source is None:
         stream = healthy_stream(n_epochs, n_routers, n_ports)
@@ -313,7 +307,7 @@ def resolve_faults(
             n_epochs, n_routers, n_ports, neighbor, opposite
         )
     elif isinstance(source, FaultStream):
-        stream = source
+        stream = FaultStream(*(np.asarray(x) for x in source))
     else:
         raise TypeError(
             f"cannot resolve fault source of type {type(source).__name__}; "

@@ -36,7 +36,6 @@ import difflib
 from typing import Callable, NamedTuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.noc.topology import NT_CPU, NT_GPU, NT_MC, Topology, make_topology
@@ -171,7 +170,7 @@ class PlacementSchedule:
                 )
             target = cls1 if ev.slot == "boost" else cls0
             target[lo:hi] = plan
-        return PlacementStream(cls0=jnp.asarray(cls0), cls1=jnp.asarray(cls1))
+        return PlacementStream(cls0=cls0, cls1=cls1)
 
 
 def static_placement(
@@ -252,7 +251,8 @@ def resolve_placement(
 
     The ONE resolution path the simulator entry points call (mirroring
     `faults.resolve_faults`); the result is shape-validated so every
-    source kind feeds the simulator the same program shape.
+    source kind feeds the simulator the same program shape, and its
+    leaves are host (NumPy) arrays whatever the source held.
     """
     topo = topology if topology is not None else make_topology()
     if source is None:
@@ -262,7 +262,7 @@ def resolve_placement(
     elif isinstance(source, PlacementSchedule):
         stream = source.materialize(n_epochs, topo)
     elif isinstance(source, PlacementStream):
-        stream = source
+        stream = PlacementStream(*(np.asarray(x) for x in source))
     else:
         raise TypeError(
             f"cannot resolve placement source of type {type(source).__name__}; "

@@ -61,6 +61,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental.xla_metadata import set_xla_metadata
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import kalman, predictor
 from repro.core.allocator import (
@@ -339,20 +341,23 @@ def _make_kf(stc: SimStatic):
 
 
 def init_sim_state(stc: SimStatic, batch: int | None = None):
-    """Zero-initialized carry buffers (subnets, MC queues, source backlogs).
+    """Zero-initialized carry buffers (subnets, MC queues, source backlogs),
+    as host (NumPy) arrays.
 
     Built outside the jitted entry points so the batched path can donate
     them: XLA then reuses the buffers in place instead of holding both the
-    init and the first-iteration copy live.
+    init and the first-iteration copy live.  They cross to the device in
+    the same transfer as the rest of a dispatch's arguments, which makes a
+    fresh buffer for every dispatch.
     """
     topo = make_topology(stc.width, stc.height, stc.n_mc)
     R = topo.n_routers
     S, V, B = stc.n_subnets, stc.n_vcs, stc.buf_depth
 
-    def z(shape, dtype=jnp.int32):
+    def z(shape, dtype=np.int32):
         if batch is not None:
             shape = (batch,) + shape
-        return jnp.zeros(shape, dtype)
+        return np.zeros(shape, dtype)
 
     # Injection stamps ride uint16 when every possible age fits: the latency
     # subtraction is wraparound-exact for ages <= 2^16 - 1.  The max age is
@@ -366,22 +371,22 @@ def init_sim_state(stc: SimStatic, batch: int | None = None):
     # boundary by tests/test_predictor_ablation.py.)
     total_cycles = stc.epoch_len * stc.n_epochs
     if stc.stamp_dtype == "int32":
-        binj_dtype = jnp.int32
+        binj_dtype = np.int32
     elif stc.stamp_dtype == "auto":
-        binj_dtype = jnp.uint16 if total_cycles <= 2**16 else jnp.int32
+        binj_dtype = np.uint16 if total_cycles <= 2**16 else np.int32
     else:
         raise ValueError(
             f"unknown stamp_dtype {stc.stamp_dtype!r}; expected auto|int32"
         )
     subnets0 = rt.SubnetState(
-        buf_meta=z((S, R, rt.N_PORTS, V, B), jnp.int16),
+        buf_meta=z((S, R, rt.N_PORTS, V, B), np.int16),
         buf_binj=z((S, R, rt.N_PORTS, V, B), binj_dtype),
-        head=z((S, R, rt.N_PORTS, V), jnp.int8),
-        count=z((S, R, rt.N_PORTS, V), jnp.int8),
-        rr_ptr=z((S, R, rt.N_PORTS), jnp.int8),
+        head=z((S, R, rt.N_PORTS, V), np.int8),
+        count=z((S, R, rt.N_PORTS, V), np.int8),
+        rr_ptr=z((S, R, rt.N_PORTS), np.int8),
     )
     mc0 = MCState(
-        q_meta=z((R, stc.mc_queue_cap), jnp.int8),
+        q_meta=z((R, stc.mc_queue_cap), np.int8),
         head=z((R,)),
         count=z((R,)),
         timer=z((R,)),
@@ -1049,9 +1054,10 @@ def simulate(
     into a Pallas path never perturbs the default program's trace count.
     """
     with span("noc.args"):
-        args = sim_args(cfg, source, padded, backend)
+        stc, *args = sim_args(cfg, source, padded, backend)
+        args = jax.device_put(args)
     with span("noc.dispatch"):
-        return _SIM_JIT(*args)
+        return _SIM_JIT(stc, *args)
 
 
 def sim_args(
@@ -1061,7 +1067,9 @@ def sim_args(
     backend: str | None = None,
 ) -> tuple:
     """The `_SIM_JIT` arguments `simulate` dispatches: (SimStatic, policy,
-    demand rows, seed, initial state, faults, placement)."""
+    demand rows, seed, initial state, faults, placement), every array a
+    host (NumPy) array; `simulate` puts them on the device in one
+    transfer."""
     stc = cfg.static_spec(padded)
     if backend is not None:
         stc = dataclasses.replace(stc, backend=backend)
@@ -1069,7 +1077,7 @@ def sim_args(
         stc,
         cfg.mode_policy(padded),
         resolve_source(source, stc.n_epochs),
-        jnp.int32(cfg.seed),
+        np.asarray(cfg.seed, np.int32),
         init_sim_state(stc),
         _run_faults(cfg.faults, stc),
         _run_placement(cfg.placement, stc),
@@ -1099,14 +1107,12 @@ def _tree_rows(tree, sl):
 
 
 def _pad_rows(tree, n_pad: int):
-    """Append n_pad copies of row 0 along axis 0 of every leaf (discarded
-    after the dispatch)."""
+    """Append n_pad copies of row 0 along axis 0 of every host leaf
+    (discarded after the dispatch)."""
     if n_pad == 0:
         return tree
     return jax.tree.map(
-        lambda x: jnp.concatenate(
-            [x, jnp.repeat(x[:1], n_pad, axis=0)], axis=0
-        ),
+        lambda x: np.concatenate([x, np.repeat(x[:1], n_pad, axis=0)]),
         tree,
     )
 
@@ -1125,8 +1131,6 @@ def _sharded_jit(stc: SimStatic, mesh):
     """
     key = (stc, mesh)
     if key not in _SHARD_JIT:
-        from jax.sharding import PartitionSpec as P
-
         batched = jax.vmap(_simulate_impl, in_axes=(None, 0, 0, 0, 0, 0, 0))
 
         def shard_body(mp, prof, seeds, state0, flt, plc):
@@ -1159,8 +1163,9 @@ def batch_args(
     seeds: Sequence[int] | None = None,
 ) -> tuple:
     """Stack B configs into the batched program's inputs: (SimStatic,
-    policy, demand rows, seeds, faults, placement), each leaf with a
-    leading (B,) axis (see `simulate_batch` for the arguments)."""
+    policy, demand rows, seeds, faults, placement), each leaf a host
+    (NumPy) array with a leading (B,) axis (see `simulate_batch` for the
+    arguments).  Dispatches no device op."""
     cfgs = list(cfgs)
     if not cfgs:
         raise ValueError("simulate_batch needs at least one config")
@@ -1181,19 +1186,17 @@ def batch_args(
         raise ValueError(f"{len(profiles)} sources for {B} configs")
     if seeds is None:
         seeds = [c.seed for c in cfgs]
-    seeds = jnp.asarray(list(seeds), jnp.int32)
+    seeds = np.asarray(list(seeds), np.int32)
     if seeds.shape[0] != B:
         raise ValueError(f"{seeds.shape[0]} seeds for {B} configs")
 
-    mp = jax.tree.map(lambda *xs: jnp.stack(xs), *[c.mode_policy() for c in cfgs])
+    def stack(trees):
+        return jax.tree.map(lambda *xs: np.stack(xs), *trees)
+
+    mp = stack([c.mode_policy() for c in cfgs])
     prof = stack_profiles(profiles)
-    flt = jax.tree.map(
-        lambda *xs: jnp.stack(xs), *[_run_faults(c.faults, stc) for c in cfgs]
-    )
-    plc = jax.tree.map(
-        lambda *xs: jnp.stack(xs),
-        *[_run_placement(c.placement, stc) for c in cfgs],
-    )
+    flt = stack([_run_faults(c.faults, stc) for c in cfgs])
+    plc = stack([_run_placement(c.placement, stc) for c in cfgs])
     return stc, mp, prof, seeds, flt, plc
 
 
@@ -1232,7 +1235,9 @@ def simulate_batch(
 
     Host spans (DESIGN.md §18): `noc.args` around `batch_args` and each
     tile's arguments, `noc.dispatch` around each call of the program,
-    `noc.rows` around cutting the answer back to B rows.
+    `noc.rows` around cutting the answer back to B rows.  Arguments are
+    built, sliced and padded on the host; each tile (or the sharded
+    batch) crosses to the device in one `jax.device_put`.
     """
     with span("noc.args"):
         stc, mp, prof, seeds, flt, plc = batch_args(cfgs, sources, seeds)
@@ -1246,14 +1251,13 @@ def simulate_batch(
                 mesh = dist_sharding.sweep_mesh(devices)
             ndev = int(mesh.devices.size)
             padded_b = -(-B // ndev) * ndev
-            mp, prof, seeds, flt, plc = (
-                _pad_rows(t, padded_b - B)
-                for t in (mp, prof, seeds, flt, plc)
-            )
-            state0 = init_sim_state(stc, padded_b)
+            args = _pad_rows((mp, prof, seeds, init_sim_state(stc, B), flt,
+                              plc), padded_b - B)
+            # each chip receives its own shard of the batch axis
+            args = jax.device_put(args, NamedSharding(mesh, P(SWEEP_AXIS)))
         with span("noc.dispatch"):
-            out = _sharded_jit(stc, mesh)(mp, prof, seeds, state0, flt, plc)
-        del state0  # the running program holds it; freed when it ends
+            out = _sharded_jit(stc, mesh)(*args)
+        del args  # the running program holds them; freed when it ends
         with span("noc.rows"):
             return _tree_rows(out, slice(0, B))
 
@@ -1261,22 +1265,16 @@ def simulate_batch(
     outs = []
     for lo in range(0, B, tile):
         with span("noc.args"):
-            sl = slice(lo, min(lo + tile, B))
-            n = sl.stop - sl.start
-            mp_t, prof_t, seeds_t, flt_t, plc_t = (
-                _tree_rows(t, sl) for t in (mp, prof, seeds, flt, plc)
-            )
-            if n < tile:  # pad the ragged tail by repeating row 0 (discarded)
-                mp_t, prof_t, seeds_t, flt_t, plc_t = (
-                    _pad_rows(t, tile - n)
-                    for t in (mp_t, prof_t, seeds_t, flt_t, plc_t)
-                )
-            state0 = init_sim_state(stc, tile)
+            n = min(tile, B - lo)
+            sl = slice(lo, lo + n)
+            args = tuple(_tree_rows(t, sl) for t in (mp, prof, seeds))
+            args += (init_sim_state(stc, n),)
+            args += tuple(_tree_rows(t, sl) for t in (flt, plc))
+            # pad the ragged tail by repeating row 0 (discarded)
+            args = jax.device_put(_pad_rows(args, tile - n))
         with span("noc.dispatch"):
-            out = _batch_jit()(
-                stc, mp_t, prof_t, seeds_t, state0, flt_t, plc_t,
-            )
-        del state0  # the running program holds it; freed when it ends
+            out = _batch_jit()(stc, *args)
+        del args  # the running program holds them; freed when it ends
         outs.append((out, n))
     with span("noc.rows"):
         parts = [_tree_rows(out, slice(0, n)) for out, n in outs]

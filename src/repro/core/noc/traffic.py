@@ -81,17 +81,18 @@ class WorkloadProfile(NamedTuple):
     def epoch_demand(self, n_epochs: int) -> "WorkloadProfile":
         """TrafficSource: broadcast stationary rates across the epoch axis.
 
-        Scalar leaves become constant ``(n_epochs,)`` float32 rows — the
-        same float32 values the scalar-leaf trace consumed, so the lowering
-        is value-invisible (pinned by tests/test_predictor_ablation.py).
-        Already-per-epoch leaves pass through after a length check, so a
-        materialized ``EpochDemand`` is itself a valid source.
+        Scalar leaves become constant ``(n_epochs,)`` float32 NumPy rows —
+        the same float32 values the scalar-leaf trace consumed, so the
+        lowering is value-invisible (pinned by
+        tests/test_predictor_ablation.py).  Already-per-epoch leaves pass
+        through after a length check, so a materialized ``EpochDemand`` is
+        itself a valid source.
         """
 
         def lower(x):
-            x = jnp.asarray(x, jnp.float32)
+            x = np.asarray(x, np.float32)
             if x.ndim == 0:
-                return jnp.broadcast_to(x, (n_epochs,))
+                return np.full((n_epochs,), x, np.float32)
             if x.shape != (n_epochs,):
                 raise ValueError(
                     f"per-epoch profile leaf has shape {x.shape}, expected "
@@ -125,10 +126,11 @@ PROFILES: dict[str, WorkloadProfile] = {
 
 
 def stack_profiles(profiles: Iterable[WorkloadProfile]) -> WorkloadProfile:
-    """Stack profiles into one pytree with (B,) float32 leaves (vmap axis 0)."""
+    """Stack profiles into one pytree with (B, ...) float32 NumPy leaves
+    (vmap axis 0)."""
     rows = list(profiles)
     return jax.tree.map(
-        lambda *xs: jnp.asarray(xs, jnp.float32), *rows
+        lambda *xs: np.stack(xs).astype(np.float32, copy=False), *rows
     )
 
 
@@ -258,9 +260,7 @@ class ScenarioSchedule:
             if seg.pin_phase is not None:
                 rows["p_enter"][lo:hi] = 1.0 if seg.pin_phase == 1 else 0.0
                 rows["p_exit"][lo:hi] = 0.0 if seg.pin_phase == 1 else 1.0
-        return WorkloadProfile(**{
-            f: jnp.asarray(rows[f]) for f in WorkloadProfile._fields
-        })
+        return WorkloadProfile(**rows)
 
     def epoch_demand(self, n_epochs: int) -> WorkloadProfile:
         """TrafficSource: lower the schedule to per-epoch demand rows."""
@@ -542,7 +542,7 @@ class RecordedTrace:
                 for f in WorkloadProfile._fields
             }
         return WorkloadProfile(**{
-            f: jnp.asarray(rows[f], jnp.float32)
+            f: np.asarray(rows[f], np.float32)
             for f in WorkloadProfile._fields
         })
 
@@ -709,7 +709,10 @@ def resolve_source(source: "TrafficSourceLike", n_epochs: int) -> EpochDemand:
                             to ``WorkloadProfile`` for one release.
 
     The result is validated to have exactly ``(n_epochs,)`` float32 leaves,
-    so every source kind feeds the simulator the same program shape.
+    so every source kind feeds the simulator the same program shape.  Its
+    leaves are host (NumPy) rows whatever the source returned: the
+    simulator's argument layer builds on the host and crosses to the
+    device once per dispatch (DESIGN.md §18).
     """
     if isinstance(source, str):
         source = lookup_workload(source)
@@ -727,9 +730,10 @@ def resolve_source(source: "TrafficSourceLike", n_epochs: int) -> EpochDemand:
                 "TrafficSource"
             )
     demand = source.epoch_demand(n_epochs)
+    rows = {}
     for f in WorkloadProfile._fields:
         leaf = getattr(demand, f)
-        if tuple(leaf.shape) != (n_epochs,) or leaf.dtype != jnp.float32:
+        if tuple(leaf.shape) != (n_epochs,) or leaf.dtype != np.float32:
             raise ValueError(
                 f"source {type(source).__name__} produced leaf {f!r} with "
                 f"shape {leaf.shape} dtype {leaf.dtype}; EpochDemand needs "
@@ -738,7 +742,7 @@ def resolve_source(source: "TrafficSourceLike", n_epochs: int) -> EpochDemand:
         # value gate: a NaN/inf or negative demand row fed to the sim
         # would silently poison injection gates and every KF observation
         # downstream — reject it here, at the ONE resolution path
-        row = np.asarray(leaf)
+        row = rows[f] = np.array(leaf)  # a copy: callers own their rows
         if not np.all(np.isfinite(row)):
             raise ValueError(
                 f"source {type(source).__name__} produced non-finite demand "
@@ -749,7 +753,7 @@ def resolve_source(source: "TrafficSourceLike", n_epochs: int) -> EpochDemand:
                 f"source {type(source).__name__} produced negative demand "
                 f"in leaf {f!r}"
             )
-    return demand
+    return WorkloadProfile(**rows)
 
 
 # The union accepted by resolve_source (and, transitionally, the old

@@ -47,6 +47,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import kalman
 
@@ -96,7 +97,9 @@ def predictor_policy(
     watchdog_limit: int = 3,
     cov_limit: float = 1e4,
 ) -> PredictorPolicy:
-    """Build the traced selector for one predictor by name.
+    """Build the traced selector for one predictor by name, as host
+    (NumPy) scalars: the simulator's argument layer crosses to the device
+    once per dispatch (DESIGN.md §18).
 
     `guard=True` arms the self-healing layer (innovation gate + divergence
     watchdog + covariance reset); with the default `guard=False` every
@@ -116,13 +119,13 @@ def predictor_policy(
     if cov_limit <= 0.0:
         raise ValueError(f"cov_limit={cov_limit} must be positive")
     return PredictorPolicy(
-        kind=jnp.int32(PREDICTORS[name]),
-        ema_alpha=jnp.float32(ema_alpha),
-        threshold=jnp.float32(threshold),
-        guard=jnp.asarray(bool(guard)),
-        nis_threshold=jnp.float32(nis_threshold),
-        watchdog_limit=jnp.int32(watchdog_limit),
-        cov_limit=jnp.float32(cov_limit),
+        kind=np.asarray(PREDICTORS[name], np.int32),
+        ema_alpha=np.asarray(ema_alpha, np.float32),
+        threshold=np.asarray(threshold, np.float32),
+        guard=np.asarray(bool(guard)),
+        nis_threshold=np.asarray(nis_threshold, np.float32),
+        watchdog_limit=np.asarray(watchdog_limit, np.int32),
+        cov_limit=np.asarray(cov_limit, np.float32),
     )
 
 

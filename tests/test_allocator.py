@@ -62,6 +62,18 @@ def test_vc_partition_tables():
     np.testing.assert_array_equal(c1, [False, False, False, True])
 
 
+@pytest.mark.parametrize("n_vcs", [1, 2, 3, 4, 8])
+def test_vc_partition_splits_any_vc_count(n_vcs):
+    """Host masks for any VC count: the GPU class holds the low n/2 VCs at
+    config 0 and the low n-1 at config 1, the CPU class the rest."""
+    for config, n_gpu in ((0, n_vcs // 2), (1, n_vcs - 1)):
+        g, c = vc_partition(config, n_vcs)
+        for m in (g, c):
+            assert type(m) is np.ndarray and m.dtype == np.bool_
+        np.testing.assert_array_equal(g, np.arange(n_vcs) < n_gpu)
+        np.testing.assert_array_equal(c, ~g)
+
+
 def test_mode_policy_tables():
     """The traced policy tensors reproduce each mode's trace-time branches."""
     mp = mode_policy("baseline", 4)

@@ -22,7 +22,7 @@ import pytest
 
 from repro.core.noc import sim
 from repro.core.noc.sim import NoCConfig, SweepSpec
-from repro.core.noc.traffic import PROFILES
+from repro.core.noc.traffic import PROFILES, RecordedTrace
 
 FAST = dict(n_epochs=8, epoch_len=100)
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -187,3 +187,147 @@ def test_summarize_seeds_reports_mean_and_std():
     )
     assert agg["gpu_ipc_std"] >= 0.0
     assert "avg_latency_std" in agg
+
+
+# ---------------------------------------------------------------------------
+# Host-built arguments (DESIGN.md §18): the argument layer dispatches no
+# device op and crosses to the device once per tile, or once per sharded
+# batch, each chip receiving its own shard.
+# ---------------------------------------------------------------------------
+
+def _trace_of(workload):
+    return RecordedTrace(demand=PROFILES[workload].epoch_demand(
+        FAST["n_epochs"]), name=f"{workload}-trace")
+
+
+HOST_CASES = {
+    "profile": (dict(mode="kf"), PROFILES["BFS"]),
+    "scenario": (dict(mode="kf"), "SHIFT_PATH_BFS"),
+    "recorded_trace": (dict(mode="fair"), _trace_of("STO")),
+    "fault_scenario": (dict(mode="kf", faults="FLAP_BFS", guard=True), "LIB"),
+    "placement_scenario": (dict(mode="kf", placement="GPU_NEAR_MC",
+                                control="joint"), "MUM"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HOST_CASES))
+def test_arguments_are_host_arrays(case):
+    """`batch_args` and `sim_args` return NumPy leaves only, and build them
+    with no transfer either way."""
+    kw, source = HOST_CASES[case]
+    cfgs = [NoCConfig(seed=s, **kw, **FAST) for s in (0, 1, 2)]
+    with jax.transfer_guard("disallow"):
+        _, *batch = sim.batch_args(cfgs, source)
+        _, *single = sim.sim_args(cfgs[0], source)
+    for leaf in jax.tree.leaves((batch, single)):
+        assert type(leaf) is np.ndarray, type(leaf)
+    # the host arguments are the ones the simulation consumes
+    _assert_rows_equal(sim.simulate_batch(cfgs[:1], source),
+                       jax.tree.map(lambda x: x[None],
+                                    sim.simulate(cfgs[0], source)),
+                       case)
+
+
+@pytest.mark.parametrize("n_points,tile,transfers", [
+    (12, 6, 2), (10, 6, 2), (5, None, 1),
+])
+def test_simulate_batch_crosses_to_the_device_once_per_tile(
+        monkeypatch, n_points, tile, transfers):
+    """One `jax.device_put` per tile (the ragged tail padded on the host):
+    every argument reaches the program on the device already, and the
+    call never reads the device back."""
+    cfgs = [NoCConfig(mode=("kf", "fair", "4subnet")[i % 3], seed=i, **FAST)
+            for i in range(n_points)]
+    sources = [("PATH", "BFS")[i % 2] for i in range(n_points)]
+    puts, dispatched = [], []
+    real_put, real_jit = jax.device_put, sim._batch_jit
+
+    def counting_put(*args, **kwargs):
+        puts.append(args[0])
+        return real_put(*args, **kwargs)
+
+    def spy_jit():
+        def run(stc, *args):
+            dispatched.append(args)
+            return real_jit()(stc, *args)
+        return run
+
+    sim.simulate_batch(cfgs[:1], sources[:1], batch_tile=tile)  # warm
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    monkeypatch.setattr(sim, "_batch_jit", spy_jit)
+    with jax.transfer_guard_device_to_host("disallow"):
+        res = sim.simulate_batch(cfgs, sources, batch_tile=tile)
+        jax.block_until_ready(res)
+    assert len(puts) == len(dispatched) == transfers
+    for tree in puts:  # the whole tile's arguments, initial state included
+        assert len(tree) == 6
+        assert all(type(x) is np.ndarray for x in jax.tree.leaves(tree))
+    for args in dispatched:
+        assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(args))
+    assert res.gpu_ipc.shape[0] == n_points
+    ref = sim.simulate(cfgs[-1], sources[-1])
+    _assert_rows_equal(jax.tree.map(lambda x: x[-1], res), ref, "last row")
+
+
+def test_simulate_crosses_to_the_device_once(monkeypatch):
+    cfg = NoCConfig(mode="kf", seed=4, **FAST)
+    sim.simulate(cfg, "BFS")  # warm
+    calls = []
+    real_put = jax.device_put
+    monkeypatch.setattr(jax, "device_put",
+                        lambda *a, **k: calls.append(a) or real_put(*a, **k))
+    with jax.transfer_guard("disallow"):
+        jax.block_until_ready(sim.simulate(cfg, "BFS"))
+    assert len(calls) == 1
+
+
+def test_sharded_batch_puts_each_shard_on_its_own_device():
+    """On 4 virtual CPU devices a 5-point batch is padded to 8 on the host
+    and crosses in one `device_put`: every argument reaches the program
+    split along the batch axis, two rows on each device."""
+    body = """
+        import jax, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.core.noc import sim
+        from repro.core.noc.sim import NoCConfig
+        assert len(jax.devices()) == 4
+        cfgs = [NoCConfig(mode=m, seed=i, n_epochs=2, epoch_len=50)
+                for i, m in enumerate(("kf", "fair", "4subnet", "kf", "baseline"))]
+        seen, puts = [], []
+        real_jit, real_put = sim._sharded_jit, jax.device_put
+        def spy_jit(stc, mesh):
+            fn = real_jit(stc, mesh)
+            def run(*args):
+                seen.append((mesh, args))
+                return fn(*args)
+            return run
+        def spy_put(*a, **k):
+            puts.append(a)
+            return real_put(*a, **k)
+        sim._sharded_jit = spy_jit
+        jax.device_put = spy_put
+        with jax.transfer_guard_device_to_host("disallow"):
+            res = sim.simulate_batch(cfgs, "PATH", devices=4)
+            jax.block_until_ready(res)
+        assert len(puts) == 1, len(puts)
+        (mesh, args), = seen
+        want = NamedSharding(mesh, P(sim.SWEEP_AXIS))
+        for x in jax.tree.leaves(args):
+            assert isinstance(x, jax.Array) and x.shape[0] == 8, x.shape
+            assert x.sharding.is_equivalent_to(want, x.ndim), x.sharding
+            shards = x.addressable_shards
+            assert len({s.device for s in shards}) == 4
+            assert all(s.data.shape[0] == 2 for s in shards)
+        assert res.gpu_ipc.shape[0] == 5
+        print("SHARDS_OK")
+    """
+    code = textwrap.dedent(f"""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        {textwrap.indent(textwrap.dedent(body), '        ').strip()}
+    """)
+    env = dict(os.environ, PYTHONPATH=SRC)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "SHARDS_OK" in out.stdout

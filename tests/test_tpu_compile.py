@@ -16,6 +16,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
@@ -70,9 +71,9 @@ def tpu_lowering(monkeypatch):
 
 
 def _abstract(tree, sharding):
+    """Each host argument's exact shape and dtype, on `sharding`."""
     return jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(
-            jnp.shape(x), jnp.asarray(x).dtype, sharding=sharding),
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
         tree)
 
 
@@ -91,14 +92,39 @@ def test_sim_program_compiles(one_chip, tpu_lowering, backend, side):
         sim._SIM_JIT.lower(stc, *_abstract(args, one_chip)).compile())
 
 
-def _sweep_tile(one_chip):
+def _sweep_tile_args():
     cfgs = [sim.NoCConfig(mode=m, backend="pallas", **SHORT)
             for m in ("4subnet", "baseline", "fair", "kf", "kf", "fair")]
     assert len(cfgs) == sim.SWEEP_TILE
     stc, mp, prof, seeds, flt, plc = sim.batch_args(cfgs, "BFS")
     state0 = sim.init_sim_state(stc, len(cfgs))
-    args = _abstract((mp, prof, seeds, state0, flt, plc), one_chip)
-    return sim._batch_jit().lower(stc, *args).compile()
+    return stc, (mp, prof, seeds, state0, flt, plc)
+
+
+def _sweep_tile(one_chip):
+    stc, args = _sweep_tile_args()
+    return sim._batch_jit().lower(stc, *_abstract(args, one_chip)).compile()
+
+
+def _signature(tree):
+    return [(x.shape, np.dtype(x.dtype)) for x in jax.tree.leaves(tree)]
+
+
+def test_sweep_tile_from_host_arguments_is_the_same_program(
+        one_chip, tpu_lowering):
+    """The host (NumPy) arguments lower to the program that the same
+    arguments as device arrays (`jnp.asarray`, which drops a 64-bit host
+    leaf to 32 bits) lower to: same shapes and dtypes, same v5e tile."""
+    stc, host = _sweep_tile_args()
+    assert all(type(x) is np.ndarray for x in jax.tree.leaves(host))
+    device = jax.tree.map(jnp.asarray, host)
+    assert _signature(host) == _signature(device)
+    texts = [
+        sim._batch_jit().lower(stc, *_abstract(args, one_chip))
+        .compile().as_text()
+        for args in (host, device)
+    ]
+    assert texts[0] == texts[1]
 
 
 def test_pallas_sweep_tile_compiles(one_chip, tpu_lowering):
