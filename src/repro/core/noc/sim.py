@@ -554,15 +554,17 @@ def _simulate_impl(
         # MC rows re-assert NT_MC — memory controllers are physical.  With
         # the identity stream all of these select the static topology
         # values bit-for-bit.
-        cls_e = placement_class(mp, config_idx, plc.cls0, plc.cls1)
-        ntype_e = jnp.where(is_mc, 2, cls_e)               # (R,) virtual type
-        is_gpu = ntype_e == 1
-        is_cpu = ntype_e == 0
-        node_cls = jnp.where(is_gpu, 1, 0)  # class a node's traffic belongs to
-        # request subnet of a node's own traffic; the reply subnet
-        # additionally depends on the requester's class under
-        # class-segregated routing.
-        req_sub = jnp.where(fs, 2 * node_cls, 0)
+        # (device label `epoch.placement`, DESIGN.md §18)
+        with set_xla_metadata(noc_layer="epoch.placement"):
+            cls_e = placement_class(mp, config_idx, plc.cls0, plc.cls1)
+            ntype_e = jnp.where(is_mc, 2, cls_e)           # (R,) virtual type
+            is_gpu = ntype_e == 1
+            is_cpu = ntype_e == 0
+            node_cls = jnp.where(is_gpu, 1, 0)  # class of a node's traffic
+            # request subnet of a node's own traffic; the reply subnet
+            # additionally depends on the requester's class under
+            # class-segregated routing.
+            req_sub = jnp.where(fs, 2 * node_cls, 0)
 
         # Epoch prologue: replies staged on the previous epoch's last cycle
         # inject under THIS epoch's masks.  The in-cycle merged inject is
@@ -795,14 +797,16 @@ def _simulate_impl(
             pr_rows = lanes.prof_rows(prof)
             # placement lane rows (DESIGN.md §17): the node-type row and
             # the req_match-bearing policy rows follow this epoch's plan
-            ntype_row = lanes.placement_rows(lane_dims, ntype_e)
-            req_match = (
-                (sub_ids[:, None] == req_sub[None, :]) & sub_enabled[:, None]
-            )
-            pol_sr, pol_r = lanes.policy_rows(
-                lane_dims, sub_enabled, sub_is_req, sub_is_rep, req_match,
-                fs, n_req_subs,
-            )
+            with set_xla_metadata(noc_layer="epoch.placement"):
+                ntype_row = lanes.placement_rows(lane_dims, ntype_e)
+                req_match = (
+                    (sub_ids[:, None] == req_sub[None, :])
+                    & sub_enabled[:, None]
+                )
+                pol_sr, pol_r = lanes.policy_rows(
+                    lane_dims, sub_enabled, sub_is_req, sub_is_rep, req_match,
+                    fs, n_req_subs,
+                )
             xi, xf = lanes.cycle_xs(
                 lane_dims, cycles, u_phase, u_gen, dests_all, sa_all,
                 active_all, rep_gate,
@@ -904,7 +908,8 @@ def _simulate_impl(
         # watchdog reports unhealthy, the applied configuration reverts to
         # the fair static split; `healthy` is constant True whenever the
         # guard is disarmed, so this is an identity on pre-guard programs.
-        policy = degrade_policy(policy, pred_state.healthy)
+        with set_xla_metadata(noc_layer="epoch.guard"):
+            policy = degrade_policy(policy, pred_state.healthy)
 
         # ---- IPC proxies (documented in metrics.py)
         gpu_ipc = metrics.gpu_ipc_proxy(
@@ -1073,14 +1078,18 @@ def sim_args(
     stc = cfg.static_spec(padded)
     if backend is not None:
         stc = dataclasses.replace(stc, backend=backend)
+    with span("noc.schedules"):
+        demand = resolve_source(source, stc.n_epochs)
+        flt = _run_faults(cfg.faults, stc)
+        plc = _run_placement(cfg.placement, stc)
     return (
         stc,
         cfg.mode_policy(padded),
-        resolve_source(source, stc.n_epochs),
+        demand,
         np.asarray(cfg.seed, np.int32),
         init_sim_state(stc),
-        _run_faults(cfg.faults, stc),
-        _run_placement(cfg.placement, stc),
+        flt,
+        plc,
     )
 
 
@@ -1181,9 +1190,9 @@ def batch_args(
     # detected by type (name or TrafficSource), not by Sequence-ness.
     if isinstance(sources, (str, TrafficSource)):
         sources = [sources] * B
-    profiles = [resolve_source(s, stc.n_epochs) for s in sources]
-    if len(profiles) != B:
-        raise ValueError(f"{len(profiles)} sources for {B} configs")
+    sources = list(sources)
+    if len(sources) != B:
+        raise ValueError(f"{len(sources)} sources for {B} configs")
     if seeds is None:
         seeds = [c.seed for c in cfgs]
     seeds = np.asarray(list(seeds), np.int32)
@@ -1194,9 +1203,12 @@ def batch_args(
         return jax.tree.map(lambda *xs: np.stack(xs), *trees)
 
     mp = stack([c.mode_policy() for c in cfgs])
-    prof = stack_profiles(profiles)
-    flt = stack([_run_faults(c.faults, stc) for c in cfgs])
-    plc = stack([_run_placement(c.placement, stc) for c in cfgs])
+    # every point's demand, fault and placement streams (DESIGN.md §18)
+    with span("noc.schedules"):
+        prof = stack_profiles(
+            [resolve_source(s, stc.n_epochs) for s in sources])
+        flt = stack([_run_faults(c.faults, stc) for c in cfgs])
+        plc = stack([_run_placement(c.placement, stc) for c in cfgs])
     return stc, mp, prof, seeds, flt, plc
 
 

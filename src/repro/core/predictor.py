@@ -48,6 +48,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from repro.core import kalman
 
@@ -202,27 +203,30 @@ def step_probed(
     zbar = jnp.mean(z)
     ema = pp.ema_alpha * zbar + (1.0 - pp.ema_alpha) * state.ema
 
-    # --- innovation gate ---------------------------------------------------
-    nis = kalman.innovation_nis(kf_params, kf_prior, z)
-    z_finite = jnp.all(jnp.isfinite(z))
-    # NaN NIS compares False against the threshold, hence the explicit
-    # finiteness term: a NaN observation must always reject.
-    reject = pp.guard & (~z_finite | (nis > pp.nis_threshold))
-    kf_x = jnp.where(reject, kf_prior.x, kf_post.x)
-    kf_p = jnp.where(reject, kf_prior.p, kf_post.p)
+    # the gate, the watchdog and the reset carry the simulator's device
+    # label `epoch.guard` (DESIGN.md §18); a compile-time annotation only
+    with set_xla_metadata(noc_layer="epoch.guard"):
+        # --- innovation gate -----------------------------------------------
+        nis = kalman.innovation_nis(kf_params, kf_prior, z)
+        z_finite = jnp.all(jnp.isfinite(z))
+        # NaN NIS compares False against the threshold, hence the explicit
+        # finiteness term: a NaN observation must always reject.
+        reject = pp.guard & (~z_finite | (nis > pp.nis_threshold))
+        kf_x = jnp.where(reject, kf_prior.x, kf_post.x)
+        kf_p = jnp.where(reject, kf_prior.p, kf_post.p)
 
-    # --- divergence watchdog + covariance reset ----------------------------
-    reject_run = jnp.where(reject, state.reject_run + 1, jnp.int32(0))
-    cov_tr = jnp.trace(kf_p)
-    cov_bad = ~jnp.isfinite(cov_tr) | (cov_tr > pp.cov_limit)
-    run_bad = reject_run >= pp.watchdog_limit
-    do_reset = pp.guard & ((reject_run == pp.watchdog_limit) | cov_bad)
-    n = kf_params.state_dim
-    kf_x = jnp.where(
-        do_reset, jnp.where(jnp.isfinite(kf_x), kf_x, 0.0), kf_x
-    )
-    kf_p = jnp.where(do_reset, jnp.eye(n, dtype=kf_p.dtype), kf_p)
-    healthy = ~pp.guard | ~(run_bad | cov_bad)
+        # --- divergence watchdog + covariance reset ------------------------
+        reject_run = jnp.where(reject, state.reject_run + 1, jnp.int32(0))
+        cov_tr = jnp.trace(kf_p)
+        cov_bad = ~jnp.isfinite(cov_tr) | (cov_tr > pp.cov_limit)
+        run_bad = reject_run >= pp.watchdog_limit
+        do_reset = pp.guard & ((reject_run == pp.watchdog_limit) | cov_bad)
+        n = kf_params.state_dim
+        kf_x = jnp.where(
+            do_reset, jnp.where(jnp.isfinite(kf_x), kf_x, 0.0), kf_x
+        )
+        kf_p = jnp.where(do_reset, jnp.eye(n, dtype=kf_p.dtype), kf_p)
+        healthy = ~pp.guard | ~(run_bad | cov_bad)
     kf_state = kalman.KalmanState(x=kf_x, p=kf_p)
 
     x_pred = kalman.one_step_prediction(kf_params, kf_state)[0]

@@ -3,9 +3,10 @@
 `repro.obs.profiling.span` reports each step of `sim.sweep`,
 `sim.simulate_batch` and `sim.simulate` to `jax.monitoring` span listeners
 as `/repro/noc/<step>`: `noc.sweep` around a sweep, `noc.args` around
-argument building, `noc.dispatch` around each call of the compiled
-program, `noc.rows` around cutting the answer into rows.  These tests pin
-which spans a call emits, in which order, and that they nest.
+argument building, `noc.schedules` inside it around the points' demand,
+fault and placement streams, `noc.dispatch` around each call of the
+compiled program, `noc.rows` around cutting the answer into rows.  These
+tests pin which spans a call emits, in which order, and that they nest.
 """
 import os
 import subprocess
@@ -21,8 +22,9 @@ from repro.obs import profiling
 
 TINY = dict(n_epochs=2, epoch_len=8)
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-SWEEP, ARGS, DISPATCH, ROWS = (f"/repro/noc/{s}" for s in
-                               ("sweep", "args", "dispatch", "rows"))
+SWEEP, ARGS, SCHEDULES, DISPATCH, ROWS = (
+    f"/repro/noc/{s}" for s in
+    ("sweep", "args", "schedules", "dispatch", "rows"))
 # 12 points: two tiles of the sweep's 6
 SPECS = [SweepSpec(mode, wl, seed=3)
          for wl in ("PATH", "BFS", "LIB")
@@ -77,15 +79,21 @@ def _tiled_sweep_spans():
 
 def test_sweep_spans_once_per_tile_inside_the_sweep():
     first = _tiled_sweep_spans()
-    # the configurations, `batch_args`, then each tile's arguments and
-    # dispatch; rows are cut once per batch and once per sweep
-    assert first.names() == [ARGS, ARGS, ARGS, DISPATCH, ARGS, DISPATCH,
-                             ROWS, ROWS, SWEEP]
+    # the configurations, `batch_args` (its streams in `noc.schedules`),
+    # then each tile's arguments and dispatch; rows are cut once per batch
+    # and once per sweep
+    assert first.names() == [ARGS, SCHEDULES, ARGS, ARGS, DISPATCH, ARGS,
+                             DISPATCH, ROWS, ROWS, SWEEP]
     (_, lo, hi), = [sp for sp in first.spans if sp[0] == SWEEP]
     for _, s, e in first.spans:
         assert lo <= s <= e <= hi
+    # the schedules lie inside `batch_args`' argument span
+    (_, s_lo, s_hi), = [sp for sp in first.spans if sp[0] == SCHEDULES]
+    (_, a_lo, a_hi) = first.spans[2]
+    assert a_lo <= s_lo <= s_hi <= a_hi
     # the steps never overlap, so no idle time is counted under two
-    steps = sorted((s, e) for n, s, e in first.spans if n != SWEEP)
+    steps = sorted((s, e) for n, s, e in first.spans
+                   if n not in (SWEEP, SCHEDULES))
     for (_, e0), (s1, _) in zip(steps, steps[1:]):
         assert s1 >= e0
     # a second, warm call emits the same spans in the same order
@@ -97,15 +105,17 @@ def test_simulate_spans_args_then_dispatch():
     for _ in range(2):
         with Recorder() as rec:
             jax.block_until_ready(sim.simulate(cfg, "BFS"))
-        assert rec.names() == [ARGS, DISPATCH]
-        (_, _, args_end), (_, dispatch_start, _) = rec.spans
-        assert args_end <= dispatch_start
+        assert rec.names() == [SCHEDULES, ARGS, DISPATCH]
+        (_, s_lo, s_hi), (_, a_lo, args_end), (_, dispatch_start, _) = \
+            rec.spans
+        assert a_lo <= s_lo <= s_hi <= args_end <= dispatch_start
 
 
 def test_sharded_sweep_spans():
     """`sweep_sharded` on 4 virtual CPU devices: one argument span for the
-    configurations, one for `batch_args`, one for the shard padding, one
-    dispatch, and rows cut once by the batch and once by the sweep.
+    configurations, one for `batch_args` (with the schedules inside it),
+    one for the shard padding, one dispatch, and rows cut once by the
+    batch and once by the sweep.
 
     Runs in a subprocess: the device count is fixed when JAX starts."""
     body = """
@@ -140,4 +150,5 @@ def test_sharded_sweep_spans():
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
-    assert "SPANS args,args,args,dispatch,rows,rows,sweep" in out.stdout
+    assert ("SPANS args,schedules,args,args,dispatch,rows,rows,sweep"
+            in out.stdout)

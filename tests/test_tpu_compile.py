@@ -134,12 +134,16 @@ def test_pallas_sweep_tile_compiles(one_chip, tpu_lowering):
 def test_sweep_tile_keeps_its_device_labels(one_chip, tpu_lowering):
     """The `noc_layer` labels of `_simulate_impl` (DESIGN.md §18) survive
     the TPU compiler on the operations a device trace names: top-level
-    fusions, the cycle loop and the kernel, whose own metadata the scan's
-    label sits beside without replacing it."""
+    fusions (the placement and guard labels among them), the cycle loop
+    and the kernel, whose own metadata the scan's label sits beside
+    without replacing it."""
     text = _sweep_tile(one_chip).as_text()
     assert re.search(r' fusion\(.*noc_layer="epoch\.rng"', text)
     assert re.search(r' while\(.*noc_layer="cycle\.scan"', text)
     assert re.search(r' fusion\(.*noc_layer="epoch\.boundary"', text)
+    # the placement rows and the KF guard, nested in the boundary
+    assert re.search(r' fusion\(.*noc_layer="epoch\.placement"', text)
+    assert re.search(r' fusion\(.*noc_layer="epoch\.guard"', text)
     kernel = re.search(
         r'custom_call_target="tpu_custom_call".*?'
         r'frontend_attributes=\{kernel_metadata=\{\s*'
