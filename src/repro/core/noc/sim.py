@@ -54,6 +54,7 @@ program as synthetic generators.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import defaultdict
 from typing import NamedTuple, Sequence
 
@@ -1115,6 +1116,47 @@ def _tree_rows(tree, sl):
     return jax.tree.map(lambda x: x[sl], tree)
 
 
+def _join(outs, ns, sharding=None):
+    """The tiles' answers `outs` joined along the batch axis, the first
+    `ns[k]` rows of tile k (its padded tail dropped); laid out on
+    `sharding` when one is given."""
+    joined = jax.tree.map(
+        lambda *xs: jnp.concatenate([x[:n] for x, n in zip(xs, ns)]), *outs)
+    if sharding is not None:
+        joined = jax.lax.with_sharding_constraint(joined, sharding)
+    return joined
+
+
+def _split(outs, ns, sharding=None):
+    """`_join`'s answer cut into one `SimResult` per point, in order."""
+    leaves, tree = jax.tree.flatten(_join(outs, ns, sharding))
+    return tuple(tree.unflatten(row)
+                 for row in zip(*(jnp.unstack(x) for x in leaves)))
+
+
+# Row-cutting cache: one jitted `_join` or `_split` per (function, mesh);
+# jit keys each on the answers' shapes and the static row counts.
+_ROWS_JIT: dict = {}
+
+
+def _rows_jit(fn, mesh=None):
+    """`fn` (`_join` or `_split`) as one compiled program, the row counts
+    static.  On a mesh the answer is gathered once and the rows come back
+    replicated over it, as the eager slices of a sharded answer did, so
+    any two rows share a device set and combine with `jnp` (cutting the
+    sharded leaves one by one instead compiles ~5x slower on a v5e mesh);
+    on one device the rows stay where the answer is."""
+    key = (fn, mesh)
+    if key not in _ROWS_JIT:
+        if mesh is None:
+            _ROWS_JIT[key] = jax.jit(fn, static_argnums=1)
+        else:
+            rep = NamedSharding(mesh, P())
+            _ROWS_JIT[key] = jax.jit(functools.partial(fn, sharding=rep),
+                                     static_argnums=1, out_shardings=rep)
+    return _ROWS_JIT[key]
+
+
 def _pad_rows(tree, n_pad: int):
     """Append n_pad copies of row 0 along axis 0 of every host leaf
     (discarded after the dispatch)."""
@@ -1212,6 +1254,59 @@ def batch_args(
     return stc, mp, prof, seeds, flt, plc
 
 
+def _run_batch(
+    cfgs: Sequence[NoCConfig],
+    sources: TrafficSourceLike | Sequence,
+    seeds: Sequence[int] | None,
+    batch_tile: int | None,
+    devices: int | None,
+    mesh,
+) -> tuple[tuple[SimResult, ...], tuple[int, ...], object]:
+    """Build a batch's arguments and call the program once per tile, or
+    once over the mesh (see `simulate_batch` for the arguments).
+
+    Returns the program's raw answers, the number of real rows at the head
+    of each, and the mesh the answer lives on (None on one device).  Waits
+    for nothing: the answers are still being computed."""
+    with span("noc.args"):
+        stc, mp, prof, seeds, flt, plc = batch_args(cfgs, sources, seeds)
+    B = int(seeds.shape[0])
+
+    if devices is not None or mesh is not None:
+        with span("noc.args"):
+            if mesh is None:
+                from repro.dist import sharding as dist_sharding
+
+                mesh = dist_sharding.sweep_mesh(devices)
+            ndev = int(mesh.devices.size)
+            padded_b = -(-B // ndev) * ndev
+            args = _pad_rows((mp, prof, seeds, init_sim_state(stc, B), flt,
+                              plc), padded_b - B)
+            # each chip receives its own shard of the batch axis
+            args = jax.device_put(args, NamedSharding(mesh, P(SWEEP_AXIS)))
+        with span("noc.dispatch"):
+            out = _sharded_jit(stc, mesh)(*args)
+        return (out,), (B,), mesh
+
+    tile = B if batch_tile is None else batch_tile
+    outs, ns = [], []
+    for lo in range(0, B, tile):
+        with span("noc.args"):
+            n = min(tile, B - lo)
+            sl = slice(lo, lo + n)
+            args = tuple(_tree_rows(t, sl) for t in (mp, prof, seeds))
+            args += (init_sim_state(stc, n),)
+            args += tuple(_tree_rows(t, sl) for t in (flt, plc))
+            # pad the ragged tail by repeating row 0 (discarded)
+            args = jax.device_put(_pad_rows(args, tile - n))
+        with span("noc.dispatch"):
+            out = _batch_jit()(stc, *args)
+        del args  # the running program holds them; freed when it ends
+        outs.append(out)
+        ns.append(n)
+    return tuple(outs), tuple(ns), None
+
+
 def simulate_batch(
     cfgs: Sequence[NoCConfig],
     sources: TrafficSourceLike | Sequence,
@@ -1243,56 +1338,19 @@ def simulate_batch(
                 `devices=N` builds a mesh over the first N local devices;
                 pass `mesh` to reuse one (must have a `sweep` axis).
 
-    Returns a `SimResult` whose leaves carry a leading (B,) axis.
+    Returns a `SimResult` whose leaves carry a leading (B,) axis
+    (replicated over the mesh on the sharded path).
 
     Host spans (DESIGN.md §18): `noc.args` around `batch_args` and each
     tile's arguments, `noc.dispatch` around each call of the program,
-    `noc.rows` around cutting the answer back to B rows.  Arguments are
-    built, sliced and padded on the host; each tile (or the sharded
-    batch) crosses to the device in one `jax.device_put`.
+    `noc.rows` around joining the answers into B rows, one compiled call.
+    Arguments are built, sliced and padded on the host; each tile (or the
+    sharded batch) crosses to the device in one `jax.device_put`.
     """
-    with span("noc.args"):
-        stc, mp, prof, seeds, flt, plc = batch_args(cfgs, sources, seeds)
-    B = int(seeds.shape[0])
-
-    if devices is not None or mesh is not None:
-        with span("noc.args"):
-            if mesh is None:
-                from repro.dist import sharding as dist_sharding
-
-                mesh = dist_sharding.sweep_mesh(devices)
-            ndev = int(mesh.devices.size)
-            padded_b = -(-B // ndev) * ndev
-            args = _pad_rows((mp, prof, seeds, init_sim_state(stc, B), flt,
-                              plc), padded_b - B)
-            # each chip receives its own shard of the batch axis
-            args = jax.device_put(args, NamedSharding(mesh, P(SWEEP_AXIS)))
-        with span("noc.dispatch"):
-            out = _sharded_jit(stc, mesh)(*args)
-        del args  # the running program holds them; freed when it ends
-        with span("noc.rows"):
-            return _tree_rows(out, slice(0, B))
-
-    tile = B if batch_tile is None else batch_tile
-    outs = []
-    for lo in range(0, B, tile):
-        with span("noc.args"):
-            n = min(tile, B - lo)
-            sl = slice(lo, lo + n)
-            args = tuple(_tree_rows(t, sl) for t in (mp, prof, seeds))
-            args += (init_sim_state(stc, n),)
-            args += tuple(_tree_rows(t, sl) for t in (flt, plc))
-            # pad the ragged tail by repeating row 0 (discarded)
-            args = jax.device_put(_pad_rows(args, tile - n))
-        with span("noc.dispatch"):
-            out = _batch_jit()(stc, *args)
-        del args  # the running program holds them; freed when it ends
-        outs.append((out, n))
+    outs, ns, mesh = _run_batch(cfgs, sources, seeds, batch_tile, devices,
+                                mesh)
     with span("noc.rows"):
-        parts = [_tree_rows(out, slice(0, n)) for out, n in outs]
-        if len(parts) == 1:
-            return parts[0]
-        return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
+        return _rows_jit(_join, mesh)(outs, ns)
 
 
 class SweepSpec(NamedTuple):
@@ -1353,8 +1411,10 @@ def sweep(
     Rows are grouped by `static_spec()` — since the S-padding refactor
     (DESIGN.md §10) every mode shares one spec, so the whole sweep is a
     single group and dispatches once — each group runs through
-    `simulate_batch`, and results come back as one `SimResult` per spec, in
-    input order.  `overrides` are forwarded to every row's `NoCConfig`
+    `simulate_batch`'s tiles, and results come back as one `SimResult` per
+    spec, in input order, cut from a group's answer by one compiled call
+    (`noc.rows`; on a mesh the rows are replicated over it).  Nothing waits
+    for the device.  `overrides` are forwarded to every row's `NoCConfig`
     (e.g. n_epochs=30); `devices`/`mesh` select the device-sharded dispatch
     path (see `simulate_batch`).  The whole call is the host span
     `noc.sweep` (DESIGN.md §18).
@@ -1378,16 +1438,14 @@ def sweep(
                 cfgs.append(cfg)
                 groups[cfg.static_spec()].append(i)
         for idxs in groups.values():
-            res = simulate_batch(
+            outs, ns, on = _run_batch(
                 [cfgs[i] for i in idxs],
                 [specs[i].workload for i in idxs],
-                batch_tile=batch_tile,
-                devices=devices,
-                mesh=mesh,
+                None, batch_tile, devices, mesh,
             )
             with span("noc.rows"):
-                for j, i in enumerate(idxs):
-                    rows[i] = _tree_rows(res, j)
+                for i, row in zip(idxs, _rows_jit(_split, on)(outs, ns)):
+                    rows[i] = row
         return rows
 
 

@@ -80,10 +80,9 @@ def _tiled_sweep_spans():
 def test_sweep_spans_once_per_tile_inside_the_sweep():
     first = _tiled_sweep_spans()
     # the configurations, `batch_args` (its streams in `noc.schedules`),
-    # then each tile's arguments and dispatch; rows are cut once per batch
-    # and once per sweep
+    # then each tile's arguments and dispatch; rows are cut once per sweep
     assert first.names() == [ARGS, SCHEDULES, ARGS, ARGS, DISPATCH, ARGS,
-                             DISPATCH, ROWS, ROWS, SWEEP]
+                             DISPATCH, ROWS, SWEEP]
     (_, lo, hi), = [sp for sp in first.spans if sp[0] == SWEEP]
     for _, s, e in first.spans:
         assert lo <= s <= e <= hi
@@ -114,8 +113,7 @@ def test_simulate_spans_args_then_dispatch():
 def test_sharded_sweep_spans():
     """`sweep_sharded` on 4 virtual CPU devices: one argument span for the
     configurations, one for `batch_args` (with the schedules inside it),
-    one for the shard padding, one dispatch, and rows cut once by the
-    batch and once by the sweep.
+    one for the shard padding, one dispatch, and rows cut once.
 
     Runs in a subprocess: the device count is fixed when JAX starts."""
     body = """
@@ -150,5 +148,5 @@ def test_sharded_sweep_spans():
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
-    assert ("SPANS args,schedules,args,args,dispatch,rows,rows,sweep"
+    assert ("SPANS args,schedules,args,args,dispatch,rows,sweep"
             in out.stdout)

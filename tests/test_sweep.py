@@ -9,7 +9,9 @@ The contract of `sim.simulate_batch` / `sim.sweep` (DESIGN.md §4, §10):
   3. the whole paper evaluation (Fig 2/3 + Fig 9/10/11 + Fig 12) costs
      exactly ONE trace of the simulator — 4-subnet included;
   4. `sweep_sharded` returns `sweep`'s rows exactly, including on a ragged
-     (non-divisible) point count.
+     (non-divisible) point count;
+  5. the sweep cuts its answer into rows with one compiled call, on one
+     device or over a mesh, and no row crosses to the host.
 """
 import os
 import subprocess
@@ -37,6 +39,23 @@ def _assert_rows_equal(row, ref, label):
             np.asarray(a), np.asarray(b), atol=1e-6, rtol=1e-6,
             err_msg=f"{label}: leaf {jax.tree_util.keystr(path)}",
         )
+
+
+def _on_four_devices(body):
+    """Run `body` in a subprocess on 4 virtual CPU devices; its stdout.
+
+    A subprocess because the XLA device count is locked at first jax init
+    (same pattern as tests/test_multidevice.py)."""
+    code = textwrap.dedent(f"""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        {textwrap.indent(textwrap.dedent(body), '        ').strip()}
+    """)
+    env = dict(os.environ, PYTHONPATH=SRC)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return out.stdout
 
 
 SPECS = [
@@ -109,9 +128,6 @@ def test_padded_program_matches_dedicated_trace():
 def test_sweep_sharded_matches_sweep_on_ragged_batch():
     """`sweep_sharded` == `sweep` on a point count that does NOT divide the
     device count (5 points, 4 devices -> one pad row per the padding rule).
-
-    Runs in a subprocess because the XLA device count is locked at first
-    jax init (same pattern as tests/test_multidevice.py).
     """
     body = """
         import jax, numpy as np
@@ -138,16 +154,7 @@ def test_sweep_sharded_matches_sweep_on_ragged_batch():
                     err_msg=f"row {i} {jax.tree_util.keystr(p)}")
         print("SHARDED_RAGGED_OK")
     """
-    code = textwrap.dedent(f"""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-        {textwrap.indent(textwrap.dedent(body), '        ').strip()}
-    """)
-    env = dict(os.environ, PYTHONPATH=SRC)
-    out = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True, timeout=600)
-    assert out.returncode == 0, out.stderr[-3000:]
-    assert "SHARDED_RAGGED_OK" in out.stdout
+    assert "SHARDED_RAGGED_OK" in _on_four_devices(body)
 
 
 def test_batch_profile_broadcast_and_seed_override():
@@ -321,13 +328,105 @@ def test_sharded_batch_puts_each_shard_on_its_own_device():
         assert res.gpu_ipc.shape[0] == 5
         print("SHARDS_OK")
     """
-    code = textwrap.dedent(f"""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-        {textwrap.indent(textwrap.dedent(body), '        ').strip()}
-    """)
-    env = dict(os.environ, PYTHONPATH=SRC)
-    out = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True, timeout=600)
-    assert out.returncode == 0, out.stderr[-3000:]
-    assert "SHARDS_OK" in out.stdout
+    assert "SHARDS_OK" in _on_four_devices(body)
+
+
+# ---------------------------------------------------------------------------
+# Rows (DESIGN.md §18): a sweep's answer is cut into per-point rows by one
+# compiled call per sweep, on one device or over a mesh, and the rows stay
+# on the device.
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n_points,tile", [(12, 6), (5, 6)])
+def test_sweep_cuts_its_rows_in_one_call(monkeypatch, n_points, tile):
+    """One call of the compiled split a sweep, traced once for a grid, with
+    no row crossing to the host; a ragged tile's pad rows never come
+    back, and every row is bitwise the joined answer's row."""
+    specs = [SweepSpec(("kf", "fair", "4subnet", "baseline")[i % 4],
+                       ("PATH", "BFS")[i % 2], seed=i)
+             for i in range(n_points)]
+    real = sim._rows_jit
+    split = real(sim._split)
+    calls = []
+
+    def spy(fn, mesh=None):
+        jitted = real(fn, mesh)
+
+        def run(*args):
+            calls.append((fn, mesh))
+            return jitted(*args)
+        return run
+
+    monkeypatch.setattr(sim, "_rows_jit", spy)
+    traced = []
+    for _ in range(2):
+        calls.clear()
+        with jax.transfer_guard_device_to_host("disallow"):
+            rows = sim.sweep(specs, batch_tile=tile, **FAST)
+            jax.block_until_ready(rows)
+        assert calls == [(sim._split, None)]
+        traced.append(split._cache_size())
+    assert traced[0] == traced[1]
+    assert len(rows) == n_points
+    cfgs = [NoCConfig(mode=sp.mode, seed=sp.seed, **FAST) for sp in specs]
+    joined = sim.simulate_batch(cfgs, [sp.workload for sp in specs],
+                                batch_tile=tile)
+    assert joined.gpu_ipc.shape[0] == n_points
+    for j, row in enumerate(rows):
+        assert row.gpu_ipc.shape == (FAST["n_epochs"],)
+        for (p, a), b in zip(jax.tree_util.tree_leaves_with_path(row),
+                             jax.tree.leaves(joined)):
+            np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(b)[j],
+                err_msg=f"row {j} {jax.tree_util.keystr(p)}")
+    _assert_rows_equal(rows[-1], sim.simulate(cfgs[-1], specs[-1].workload),
+                       "last row")
+
+
+def test_sweep_sharded_cuts_its_rows_over_the_mesh():
+    """`sweep_sharded(devices=4)` on 5 points (padded to 8): at most one
+    split call a chip, no row crossing to the host, rows bitwise `sweep`'s,
+    and every row on the whole mesh, so two rows combine with `jnp`."""
+    body = """
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.core.noc import sim
+        from repro.core.noc.sim import SweepSpec
+        F = dict(n_epochs=2, epoch_len=50)
+        specs = [SweepSpec(m, w, seed=i) for i, (m, w) in enumerate(
+            [("kf", "PATH"), ("fair", "LIB"), ("4subnet", "STO"),
+             ("baseline", "BFS"), ("kf", "MUM")])]
+        assert len(jax.devices()) == 4
+        rows = sim.sweep(specs, **F)
+        calls, real = [], sim._rows_jit
+        def spy(fn, mesh=None):
+            jitted = real(fn, mesh)
+            def run(*args):
+                calls.append(fn)
+                return jitted(*args)
+            return run
+        sim._rows_jit = spy
+        with jax.transfer_guard_device_to_host("disallow"):
+            rows_sh = sim.sweep_sharded(specs, devices=4, **F)
+            jax.block_until_ready(rows_sh)
+            both = jax.tree.map(lambda a, b: jnp.stack([a, b]),
+                                rows_sh[0], rows_sh[4])
+        assert 1 <= len(calls) <= 4 and set(calls) == {sim._split}, calls
+        assert len(rows_sh) == 5
+        mesh = frozenset(jax.devices())
+        for r in rows_sh:
+            for x in jax.tree.leaves(r):
+                assert x.sharding.device_set == mesh, x.sharding
+        for i, (a, b) in enumerate(zip(rows, rows_sh)):
+            for (p, x), (_, y) in zip(
+                jax.tree_util.tree_leaves_with_path(a),
+                jax.tree_util.tree_leaves_with_path(b),
+            ):
+                np.testing.assert_array_equal(
+                    np.asarray(x), np.asarray(y),
+                    err_msg=f"row {i} {jax.tree_util.keystr(p)}")
+        for x, a, b in zip(jax.tree.leaves(both), jax.tree.leaves(rows[0]),
+                           jax.tree.leaves(rows[4])):
+            np.testing.assert_array_equal(np.asarray(x), np.stack([a, b]))
+        print("SHARDED_ROWS_OK")
+    """
+    assert "SHARDED_ROWS_OK" in _on_four_devices(body)

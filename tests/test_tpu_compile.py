@@ -152,6 +152,39 @@ def test_sweep_tile_keeps_its_device_labels(one_chip, tpu_lowering):
     assert kernel
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_row_split_compiles(topo, one_chip, tpu_lowering, chips):
+    """`sim.sweep`'s row split for the paper grid's 24 points: four tiles
+    on one chip, or one answer sharded over the 2x2 mesh, cut into one
+    row per point by one program; over the mesh the answer is gathered
+    once and every row comes back replicated on all four chips."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    stc, args = _sweep_tile_args()
+    tile = jax.eval_shape(lambda *a: sim._batch_jit()(stc, *a), *args)
+    n = sim.SWEEP_TILE
+
+    def answer(rows, sharding):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            (rows,) + x.shape[1:], x.dtype, sharding=sharding), tile)
+
+    if chips == 1:
+        mesh, answers, ns = None, (answer(n, one_chip),) * 4, (n,) * 4
+    else:
+        mesh = Mesh(np.array(topo.devices).reshape(chips), (sim.SWEEP_AXIS,))
+        answers = (answer(4 * n, NamedSharding(mesh, P(sim.SWEEP_AXIS))),)
+        ns = (4 * n,)
+    compiled = sim._rows_jit(sim._split, mesh).lower(answers, ns).compile()
+    rows = compiled.output_shardings
+    assert len(rows) == 4 * n
+    leaves = jax.tree.leaves(rows)
+    assert len(leaves) == 4 * n * len(jax.tree.leaves(tile))
+    if mesh is not None:
+        assert "all-gather" in compiled.as_text()
+        assert all(s.is_fully_replicated and len(s.device_set) == chips
+                   for s in leaves)
+
+
 def test_kf_bank_compiles(one_chip, tpu_lowering):
     n, m = 131072, 3
     f32 = jnp.float32
