@@ -33,7 +33,7 @@ from __future__ import annotations
 
 import argparse
 
-BACKENDS = ("ref", "pallas", "pallas_arb")
+BACKENDS = ("ref", "pallas")
 
 # The registry name `--trace` files land under: drivers substitute it for
 # their builtin workload/scenario set when the flag is present.
@@ -54,10 +54,11 @@ def build_parser(
     )
     ap.add_argument("--devices", type=int, default=None,
                     help="shard the sweep batch axis across N devices")
-    ap.add_argument("--backend", choices=BACKENDS, default="ref",
-                    help="cycle engine: dense jnp (ref), fused full-cycle "
-                         "lane kernel (pallas), or arbitration-only kernel "
-                         "(pallas_arb); all bitwise-identical")
+    ap.add_argument("--backend", choices=BACKENDS, default=None,
+                    help="cycle engine: dense jnp (ref) or the fused "
+                         "full-cycle lane kernel (pallas), bitwise-"
+                         "identical; default: the platform's (pallas on "
+                         "a TPU, ref elsewhere)")
     ap.add_argument("--profile", metavar="DIR", default=None,
                     help="capture jax.profiler traces (compile + steady "
                          "phases) into DIR")

@@ -135,7 +135,7 @@ def run(n_epochs: int = 8, epoch_len: int = 100,
     which is why only full rows land in BENCH_noc.json.
 
     `sim_backend` switches the BATCHED arm's cycle engine ("ref" |
-    "pallas" fused full-cycle kernel | "pallas_arb"); the serial arms
+    "pallas" fused full-cycle kernel); the serial arms
     always run the dense ref engine so every row's serial baseline stays
     comparable across the committed trajectory, and the resulting
     `speedup_*` is the honest serial-ref-vs-batched-<backend> number
@@ -170,7 +170,6 @@ def run(n_epochs: int = 8, epoch_len: int = 100,
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S"),
         "backend": jax.default_backend(),
         "sim_backend": stc.backend,
-        "cycle_unroll": stc.cycle_unroll,
         "state_bytes": state_bytes(stc),
         "smoke": smoke,
         "grid": {"workloads": list(workloads), "ratios": list(ratios),
@@ -251,7 +250,7 @@ def main(argv=None):
     ap.add_argument("--devices", type=int, default=None,
                     help="also time the device-sharded dispatch over N "
                          "devices (asserts equality with the batched arm)")
-    ap.add_argument("--backend", choices=("ref", "pallas", "pallas_arb"),
+    ap.add_argument("--backend", choices=("ref", "pallas"),
                     default="ref",
                     help="cycle engine for the batched arm (serial arms "
                          "always time the dense ref engine)")

@@ -87,7 +87,7 @@ def load_records(path: str) -> list:
 def last_committed_row(records: list, bench: str = "noc_sweep_serial_vs_batched"):
     """Last committed row of the REF-engine trajectory.
 
-    Rows produced with `bench_sweep --backend pallas|pallas_arb` carry a
+    Rows produced with `bench_sweep --backend pallas` carry a
     `sim_backend` marker and are excluded here: they time a different
     engine (interpret-mode Pallas on CPU), so letting one become the
     baseline would silently relax — or falsely trip — every relative gate.

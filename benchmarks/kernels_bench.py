@@ -1,22 +1,21 @@
 """Kernel micro-benches: wall time of the Pallas kernels (interpret mode on
 CPU — correctness-shaped timings, not TPU perf) vs their jnp oracles, plus
-the kf_bank fleet-scale batch sweep and the noc_cycle engines (arbitration
-lane kernel and the fused full-cycle kernel vs the dense ref engine).
+the kf_bank fleet-scale batch sweep and the noc_cycle engines (the fused
+full-cycle kernel vs the dense ref engine).
 
-`--record` appends a `noc_cycle_kernels` row to BENCH_noc.json so the
+`--record` appends a `noc_cycle_engines` row to BENCH_noc.json so the
 kernel-vs-ref trajectory is tracked alongside the sweep records.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.core.noc import router as rt
 from repro.core.noc import sim as noc_sim
 from repro.core.noc.traffic import PROFILES
 from repro.kernels.flash_attn import ops as fa_ops
@@ -35,57 +34,23 @@ def _time(f, *args, reps=3):
     return (time.perf_counter() - t0) / reps * 1e6  # us
 
 
-def _arb_inputs(lead=(4, 36), P=5, V=4, B=4):
-    rng = np.random.default_rng(0)
-    PV = P * V
-    gm = jnp.asarray(rng.random(lead[:-1] + (1, V)) < 0.7)
-    cm = jnp.asarray(rng.random(lead[:-1] + (1, V)) < 0.7)
-    return dict(
-        valid=jnp.asarray(rng.random(lead + (PV,)) < 0.5),
-        cls=jnp.asarray(rng.integers(0, 2, lead + (PV,)), jnp.int32),
-        out_port=jnp.asarray(rng.integers(0, P, lead + (PV,)), jnp.int32),
-        rr_ptr=jnp.asarray(rng.integers(0, PV, lead + (P,)), jnp.int32),
-        down_count=jnp.asarray(
-            rng.integers(0, B + 1, lead + (P, V)), jnp.int32
-        ),
-        down_exists=jnp.asarray(rng.random(lead + (P,)) < 0.8),
-        gpu_vc_mask=jnp.broadcast_to(gm, lead + (V,)),
-        cpu_vc_mask=jnp.broadcast_to(cm, lead + (V,)),
-        sa_pref=jnp.asarray(rng.integers(-1, 2, lead), jnp.int32),
-        accept=jnp.asarray(rng.random(lead) < 0.7),
-        active=jnp.asarray(rng.random(lead) < 0.9),
-    )
-
-
 def noc_cycle_entries() -> dict:
-    """Time the noc_cycle engines at the paper's shapes (S=4, R=36).
-
-    * arbitration-only: `router.arbitrate` (dense oracle) vs
-      `ops.arbitrate_lanes` (lane kernel; interpret mode off-TPU);
-    * fused full cycle: `simulate` steady-state per backend — the dense ref
-      engine vs one `fused_cycle_kernel` launch per simulated cycle.
-    """
-    from repro.kernels.noc_cycle import ops as noc_ops
-
+    """Time the noc_cycle engines at the paper's shapes (S=4, R=36):
+    `simulate` steady-state per engine — the dense ref engine vs one
+    `fused_cycle_kernel` launch per simulated cycle (interpret mode
+    off-TPU)."""
     mode = "compiled" if jax.default_backend() == "tpu" else "interp"
-    inp = _arb_inputs()
-    t_arb_ref = _time(jax.jit(lambda: rt.arbitrate(**inp, depth=4)))
-    t_arb_lanes = _time(jax.jit(lambda: noc_ops.arbitrate_lanes(
-        **inp, depth=4)))
-
     cfg = noc_sim.NoCConfig(mode="static", static_gpu_vcs=3,
                             n_epochs=4, epoch_len=100)
     n_cycles = cfg.n_epochs * cfg.epoch_len
     prof = PROFILES["PATH"]
     t_sim = {
-        be: _time(lambda be=be: noc_sim.simulate(cfg, prof, backend=be))
+        be: _time(lambda be=be: noc_sim.simulate(
+            dataclasses.replace(cfg, backend=be), prof))
         for be in ("ref", "pallas")
     }
     return {
         "mode": mode,
-        "arb_shapes": "(4,36) lanes",
-        "arb_ref_us": round(t_arb_ref, 1),
-        "arb_lanes_us": round(t_arb_lanes, 1),
         "sim_cycles": n_cycles,
         "sim_ref_us": round(t_sim["ref"], 1),
         "sim_fused_us": round(t_sim["pallas"], 1),
@@ -97,7 +62,7 @@ def noc_cycle_entries() -> dict:
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--record", action="store_true",
-                    help="append a noc_cycle_kernels row to BENCH_noc.json")
+                    help="append a noc_cycle_engines row to BENCH_noc.json")
     args = ap.parse_args(argv)
 
     print("name,us_per_call,derived")
@@ -133,10 +98,8 @@ def main(argv=None):
         t = _time(lambda: kf_ops.kf_bank_step(x, p, z, h, r))
         print(f"kf_bank_{n},{t:.0f},filters_per_s={n / t * 1e6:.2e}")
 
-    # noc_cycle: arbitration lane kernel + fused full-cycle engine
+    # noc_cycle: the fused full-cycle engine
     noc = noc_cycle_entries()
-    print(f"noc_arb_lanes_{noc['mode']},{noc['arb_lanes_us']:.0f},"
-          f"ref={noc['arb_ref_us']:.0f}us")
     print(f"noc_cycle_fused_{noc['mode']},{noc['sim_fused_us']:.0f},"
           f"ref={noc['sim_ref_us']:.0f}us per {noc['sim_cycles']}-cycle sim "
           f"({noc['fused_us_per_cycle']:.1f}us/cycle, "
@@ -146,14 +109,14 @@ def main(argv=None):
         from benchmarks.bench_sweep import BENCH_PATH, append_record
 
         rec = {
-            "bench": "noc_cycle_kernels",
+            "bench": "noc_cycle_engines",
             "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S"),
             "backend": jax.default_backend(),
             **noc,
         }
         append_record(rec)
         print(json.dumps(rec, indent=2))
-        print(f"appended noc_cycle_kernels record to {BENCH_PATH}")
+        print(f"appended noc_cycle_engines record to {BENCH_PATH}")
 
 
 if __name__ == "__main__":

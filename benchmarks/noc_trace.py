@@ -10,7 +10,7 @@ narrative is built on.
 
     PYTHONPATH=src python -m benchmarks.noc_trace [--workload SHIFT_PATH_BFS]
         [--mode kf] [--epochs 24] [--epoch-len 200] [--seed 0]
-        [--backend ref|pallas|pallas_arb] [--csv] [--save F.npz] [--load F.npz]
+        [--backend ref|pallas] [--csv] [--save F.npz] [--load F.npz]
 
 Special modes:
 
@@ -45,8 +45,9 @@ def capture(workload: str = "SHIFT_PATH_BFS", mode: str = "kf",
     """Probes-on run -> flat dict of numpy arrays + run metadata."""
     cfg = sim.NoCConfig(mode=mode, n_epochs=n_epochs, epoch_len=epoch_len,
                         seed=seed, faults=faults, guard=guard,
-                        placement=placement, control=control)
-    res, trace = sim.simulate_with_trace(cfg, workload, backend=backend)
+                        placement=placement, control=control,
+                        backend=backend)
+    res, trace = sim.simulate_with_trace(cfg, workload)
     cap = {f: np.asarray(v) for f, v in zip(sim.SimTrace._fields, trace)}
     cap["kf_signal"] = np.asarray(res.kf_signal)
     cap["applied_config"] = np.asarray(res.applied_config)
@@ -215,7 +216,8 @@ def record(backend: str = "ref") -> dict:
     """Measure probe overhead and append the `noc_obs` ledger row."""
     from benchmarks.bench_sweep import append_record
 
-    cfg = sim.NoCConfig(mode="kf", n_epochs=8, epoch_len=100)
+    cfg = sim.NoCConfig(mode="kf", n_epochs=8, epoch_len=100,
+                        backend=backend)
     wl = "SHIFT_PATH_BFS"
 
     def steady(fn):
@@ -227,13 +229,13 @@ def record(backend: str = "ref") -> dict:
         return time.perf_counter() - t0
 
     sim.reset_trace_count()
-    t_off = steady(lambda: sim.simulate(cfg, wl, backend=backend))
+    t_off = steady(lambda: sim.simulate(cfg, wl))
     traces_off = sim.trace_count()
     sim.reset_trace_count()
     res_trace = []
     t_on = steady(
         lambda: res_trace.append(
-            sim.simulate_with_trace(cfg, wl, backend=backend)
+            sim.simulate_with_trace(cfg, wl)
         ) or res_trace[-1]
     )
     traces_on = sim.trace_count()
@@ -275,8 +277,8 @@ def main(argv=None) -> int:
     ap.add_argument("--epoch-len", type=int, default=200)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--backend", default="ref",
-                    choices=("ref", "pallas", "pallas_arb"),
-                    help="cycle engine; all bitwise-identical, incl. probes")
+                    choices=("ref", "pallas"),
+                    help="cycle engine; both bitwise-identical, incl. probes")
     ap.add_argument("--faults", metavar="NAME", default=None,
                     help="inject a registered fault scenario (DESIGN.md §16)"
                          " and render the fault/reject/reset/recover columns")

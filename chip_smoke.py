@@ -6,9 +6,9 @@
 Phase A runs the Fig. 9/10/11 NoC sweep (6 workloads x 4 modes, seed 0)
 at the paper's package and length -- the default `NoCConfig`: 6x6 mesh,
 8 MCs, 4 VCs, buffer depth 4, 120 epochs x 500 cycles -- through
-`sim.sweep` once per cycle engine (`ref`, `pallas`, `pallas_arb`).  Every
-`SimResult` leaf must be bitwise equal across the engines, every summary
-value finite, and both Pallas programs must hold a compiled Mosaic kernel
+`sim.sweep` once per cycle engine (`ref`, `pallas`).  Every `SimResult`
+leaf must be bitwise equal across the engines, every summary value finite,
+and the `pallas` program must hold a compiled Mosaic kernel
 (`tpu_custom_call`).
 
 Phase B serves 8 requests of the bursty workload with the KF-arbitrated
@@ -37,7 +37,7 @@ import time
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
-ENGINES = ("ref", "pallas", "pallas_arb")
+ENGINES = ("ref", "pallas")
 SERVE_ARCH = "stablelm-1.6b"
 N_REQUESTS = 8
 # Max |serve - reference| over one decode step's logits, as a share of the

@@ -45,7 +45,7 @@ the same commit.
 """
 from __future__ import annotations
 
-from typing import Callable, NamedTuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -194,7 +194,6 @@ def router_cycle(
     sa_pref_class: Array,   # () int32: -1 round-robin, else preferred class
     mc_can_accept: Array,   # (S, R) bool — ejection credit at local sink
     active: Array,          # (S,) bool — link active this cycle
-    arbitrate_fn: Callable[..., Arbitration] = arbitrate,
     link_ok: Array | None = None,    # (R, P) bool — fault mask: port usable
     router_ok: Array | None = None,  # (R,) bool — fault mask: router granting
 ) -> tuple[SubnetState, CycleEvents]:
@@ -232,7 +231,7 @@ def router_cycle(
     if router_ok is not None:
         granting = granting & router_ok[None, :]
 
-    arb = arbitrate_fn(
+    arb = arbitrate(
         valid.reshape(S, R, P * V),
         cls.reshape(S, R, P * V),
         out_port.reshape(S, R, P * V),

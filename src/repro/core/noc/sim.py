@@ -79,6 +79,15 @@ from repro.core.allocator import (
 from repro.core.allocator import degrade_policy
 from repro.core.noc import metrics
 from repro.core.noc import router as rt
+from repro.core.noc.engine import (
+    CycleRows,
+    EpochCounters,
+    EpochInputs,
+    Fabric,
+    MCState,
+    ref_engine,
+    staged_reply_want,
+)
 from repro.core.noc.faults import (
     TELEM_DROP,
     TELEM_NAN,
@@ -100,20 +109,34 @@ from repro.core.noc.traffic import (
     TrafficSourceLike,
     WorkloadProfile,
     init_phase,
-    injection_rates,
     resolve_source,
     stack_profiles,
-    step_phase_u,
 )
 
 Array = jax.Array
-
-BCAP = 64  # per-node source-queue (shader/LSQ) capacity
 
 # Padded subnet-axis length shared by every mode's program (DESIGN.md §10):
 # large enough for the 4-subnet network; 2-subnet modes leave rows 2..3
 # zero-width (never injected into, links never active).
 S_MAX = 4
+
+
+def platform_engine() -> str:
+    """The cycle engine for the default platform: the fused Pallas kernel
+    on a TPU, the dense jnp engine elsewhere (DESIGN.md §11, §13)."""
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
+def _cycle_engine(backend: str):
+    """The builder of the named cycle engine (`repro.core.noc.engine`)."""
+    if backend == "ref":
+        return ref_engine
+    if backend == "pallas":
+        from repro.kernels.noc_cycle.engine import pallas_engine
+
+        return pallas_engine
+    raise ValueError(
+        f"unknown cycle-engine backend {backend!r}; expected ref|pallas")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,11 +163,9 @@ class SimStatic:
     z_scales: tuple[float, float, float]
     kf_q: float
     kf_r: float
-    # cycle-engine knobs (DESIGN.md §11, §13): scan unroll factor for the
-    # inner cycle loop, and which engine to trace ("ref" = dense jnp,
-    # "pallas" = the fused full-cycle repro.kernels.noc_cycle lane kernel,
-    # "pallas_arb" = dense body with only arbitration on the lane kernel).
-    cycle_unroll: int = 1
+    # the cycle engine to trace (DESIGN.md §11, §13): "ref" = the dense jnp
+    # engine, "pallas" = the fused full-cycle repro.kernels.noc_cycle lane
+    # kernel; `NoCConfig.static_spec` resolves the platform's default.
     backend: str = "ref"
     # injection-stamp dtype: "auto" picks uint16 whenever every age the run
     # can produce is wraparound-exact (see init_sim_state); "int32" forces
@@ -187,8 +208,8 @@ class NoCConfig:
     kf_q: float = 1e-3
     kf_r: float = 2e-1
     seed: int = 0
-    cycle_unroll: int = 1         # inner cycle-scan unroll factor
-    backend: str = "ref"          # cycle engine: ref | pallas | pallas_arb
+    # cycle engine: ref | pallas; None = the platform's (`platform_engine`)
+    backend: str | None = None
     stamp_dtype: str = "auto"     # injection-stamp dtype: auto | int32
     # predictor-ablation knobs (DESIGN.md §12): which bank member drives the
     # hysteresis machine (only meaningful for mode="kf") and the EMA
@@ -247,8 +268,7 @@ class NoCConfig:
             z_scales=tuple(self.z_scales),
             kf_q=self.kf_q,
             kf_r=self.kf_r,
-            cycle_unroll=self.cycle_unroll,
-            backend=self.backend,
+            backend=self.backend or platform_engine(),
             stamp_dtype=self.stamp_dtype,
             probe=self.probe,
             width=self.width,
@@ -264,62 +284,6 @@ class NoCConfig:
             predictor=self.predictor, ema_alpha=self.ema_alpha,
             guard=self.guard, control=self.control,
         )
-
-
-class MCState(NamedTuple):
-    q_meta: Array     # (R, Q) int8 — pending request src | cls << 6
-    head: Array       # (R,)
-    count: Array      # (R,)
-    timer: Array      # (R,) cycles until current service completes
-    stage_valid: Array  # (R,) staged reply waiting to inject
-    stage_dst: Array
-    stage_cls: Array
-
-
-class EpochCounters(NamedTuple):
-    gpu_push: Array           # GPU request injections accepted
-    gpu_stall_icnt: Array     # GPU node-cycles blocked at MSHR/injection
-    gpu_stall_dram: Array     # GPU dramfull events
-    cpu_push: Array
-    gpu_done: Array           # completed GPU transactions
-    cpu_done: Array
-    gpu_gen: Array            # generated GPU demand
-    cpu_gen: Array
-    lat_sum: Array            # all ejected packets: sum of network latency
-    lat_cnt: Array
-    cpu_lat_sum: Array        # per-class NETWORK latency of ejected packets
-    cpu_lat_cnt: Array        # (excludes DRAM queue wait: the NoC's own share)
-    gpu_lat_sum: Array
-    gpu_lat_cnt: Array
-    moved: Array
-
-
-def _zero_counters() -> EpochCounters:
-    z = jnp.int32(0)
-    return EpochCounters(z, z, z, z, z, z, z, z, z, z, z, z, z, z, z)
-
-
-class _ProbeAcc(NamedTuple):
-    """Dense-engine flight-recorder accumulators (repro.obs, DESIGN.md §14):
-    the per-cycle carry the probes-on cycle scan threads next to the
-    counters.  The fused engine's twin is `fused.ProbeLanes`; both sample
-    END-of-cycle state, so the two agree bitwise."""
-
-    occ: Array      # (S, R, P, V) int32 — summed VC occupancy
-    grant: Array    # (S, R) int32 — switch grants, summed over outputs
-    deny: Array     # (S, R) int32 — refused requests, summed over outputs
-    mcq_sum: Array  # (R,) int32 — summed MC queue depth
-    mcq_max: Array  # (R,) int32 — running max MC queue depth
-
-
-def _zero_probe_acc(S: int, R: int, V: int) -> _ProbeAcc:
-    return _ProbeAcc(
-        occ=jnp.zeros((S, R, rt.N_PORTS, V), jnp.int32),
-        grant=jnp.zeros((S, R), jnp.int32),
-        deny=jnp.zeros((S, R), jnp.int32),
-        mcq_sum=jnp.zeros((R,), jnp.int32),
-        mcq_max=jnp.zeros((R,), jnp.int32),
-    )
 
 
 class SimResult(NamedTuple):
@@ -459,8 +423,8 @@ def _simulate_impl(
     # Traced subnet structure (DESIGN.md §10): which rows of the padded
     # subnet axis are live, which carry requests, and whether routing is
     # class-segregated.  Padded rows are zero-width: excluded from every
-    # inject want-matrix below and link-inactive in cycle_body, so no packet
-    # can ever enter them.
+    # inject want-matrix and link-inactive in the cycle engines, so no
+    # packet can ever enter them.
     fs = mp.four_subnet                      # () bool
     sub_enabled = mp.sub_enabled             # (S,) bool
     sub_is_req = mp.sub_is_req               # (S,) bool
@@ -474,56 +438,23 @@ def _simulate_impl(
     subnets0, mc0, outstanding0, backlog0 = state0
 
     # flight recorder (DESIGN.md §14): a STATIC switch — probes off traces
-    # the exact pre-probe program (the accumulators below are Python-gated,
-    # not lax.cond-gated), probes on is its own single trace.
+    # the exact pre-probe program (the accumulators below and in the cycle
+    # engines are Python-gated, not lax.cond-gated), probes on is its own
+    # single trace.
     probe_on = stc.probe.enabled
 
     kf_params = _make_kf(stc)
     z_scales = jnp.asarray(stc.z_scales, jnp.float32)
 
-    # cycle-engine backend (DESIGN.md §11, §13) — all three agree bitwise
-    # (tests/test_cycle_engine.py), so the choice is pure perf:
-    #   "ref"        — the dense jnp cycle body below.
-    #   "pallas"     — the FUSED full-cycle Pallas kernel: one launch per
-    #                  simulated cycle with the whole carry in lane refs
-    #                  (repro.kernels.noc_cycle, interpret-mode off-TPU).
-    #   "pallas_arb" — dense cycle body with only switch allocation swapped
-    #                  for the arbitration lane kernel (the PR-4 path).
-    fused_engine = stc.backend == "pallas"
-    if stc.backend == "pallas_arb":
-        from repro.kernels.noc_cycle.ops import arbitrate_lanes as arb_fn
-    elif stc.backend in ("ref", "pallas"):
-        arb_fn = rt.arbitrate
-    else:
-        raise ValueError(f"unknown cycle-engine backend {stc.backend!r}")
-    if fused_engine:
-        from repro.kernels.noc_cycle import fused as lanes
-        from repro.kernels.noc_cycle import ops as lane_ops
-
-        assert lanes.COUNTER_FIELDS == EpochCounters._fields, (
-            "fused kernel counter lanes out of sync with EpochCounters"
-        )
-        # the lane engine carries stamps as int32 and masks the latency
-        # subtraction instead, reproducing the uint16 wraparound bitwise
-        stamp_mask = 0xFFFF if subnets0.buf_binj.dtype == jnp.uint16 else 0
-        lane_dims = lanes.lane_dims(
-            S=S, R=R, V=V, B=stc.buf_depth, Q=stc.mc_queue_cap,
-            width=topo.width, mc_service_period=stc.mc_service_period,
-            mshr_limit=stc.mshr_limit, bcap=BCAP, stamp_mask=stamp_mask,
-        )
-        # the node-type row and the req_match-bearing policy rows are now
-        # per-epoch data (placement, DESIGN.md §17) — rebuilt in epoch_body
-        route_rows, exists_rows, _ = lanes.run_consts(lane_dims, topo)
-
-    def make_want_rep(mc):
-        """Want-matrix for staged MC replies (reply subnet of requester
-        class c is 2c+1 under class-segregated routing, subnet 1 otherwise)."""
-        rep_target = jnp.where(fs, 2 * mc.stage_cls + 1, 1)
-        return (
-            (sub_ids[:, None] == rep_target[None, :])
-            & (mc.stage_valid & is_mc)[None, :]
-            & sub_enabled[:, None]
-        )
+    # the cycle engine (DESIGN.md §11, §13), built once a run and called
+    # once an epoch; both engines agree bitwise (tests/test_cycle_engine.py)
+    fab = Fabric(
+        route=route_t, neighbor=nb_t, opposite=opp_t, is_mc=is_mc, nodes=ar,
+        four_subnet=fs, sub_ids=sub_ids, sub_enabled=sub_enabled,
+        sub_is_req=sub_is_req, sub_is_rep=sub_is_rep, n_req_subs=n_req_subs,
+    )
+    run_cycles = _cycle_engine(stc.backend)(
+        stc, topo, fab, subnets0.buf_binj.dtype)
 
     def epoch_body(carry, epoch_xs):
         # device labels (DESIGN.md §18): the epoch step is `epoch.boundary`
@@ -574,7 +505,7 @@ def _simulate_impl(
         # reply staged at cycle E-1 always entered the network with the
         # *new* epoch's VC partition.
         subs, ok0 = rt.inject_all(
-            subs, make_want_rep(mc), mc.stage_dst, ar,
+            subs, staged_reply_want(fab, mc), mc.stage_dst, ar,
             mc.stage_cls, cycle0, gpu_masks, cpu_masks,
         )
         mc = mc._replace(stage_valid=mc.stage_valid & ~jnp.any(ok0, axis=0))
@@ -602,276 +533,18 @@ def _simulate_impl(
         alternating = (cycles[:, None] % 2) == (jnp.arange(S)[None, :] % 2)
         active_all = sub_enabled[None, :] & jnp.where(fs, alternating, True)
         rep_gate = jnp.arange(ep_len) < ep_len - 1
-        xs = (cycles, u_phase, u_gen, dests_all, sa_all, active_all, rep_gate)
 
-        def cycle_body(carry, x):
-            if probe_on:
-                subs, mc, phase, outstanding, bl_count, cnt, prb = carry
-            else:
-                subs, mc, phase, outstanding, bl_count, cnt = carry
-            cycle, u_ph, u_gen_c, dests, sa_pref, active, gate = x
-
-            # MC acceptance applies to ejections on *request* subnets at MC
-            # nodes, judged on the queue depth BEFORE this cycle's service
-            # frees a slot.  With multiple request subnets (4-subnet mode)
-            # up to S/2 packets can arrive at one MC per cycle, so reserve
-            # that many slots.
-            mc_space = mc.count <= stc.mc_queue_cap - n_req_subs
-            can_accept = jnp.where(is_mc, mc_space, True)  # (R,)
-            accept_s = jnp.where(
-                sub_is_req[:, None], can_accept[None, :], True
-            )
-
-            # ---- 1. MC service: tick timers, move head request -> staging
-            # (a stalled MC — flt.mc_ok False — freezes its timer and
-            # staging; the queue keeps filling until it back-pressures)
-            can_serve = is_mc & (mc.count > 0) & ~mc.stage_valid & flt.mc_ok
-            timer = jnp.where(
-                can_serve, jnp.maximum(mc.timer - 1, 0), mc.timer
-            )
-            done = can_serve & (timer == 0)
-            hq = mc.head[:, None]
-            q_head = jnp.take_along_axis(
-                mc.q_meta, hq, axis=1
-            )[:, 0].astype(jnp.int32)
-            # MC-queue meta is src | cls << META_SRC_SHIFT (router ids fit
-            # the shift width — asserted once in rt.device_tables)
-            src_out = q_head & ((1 << rt.META_SRC_SHIFT) - 1)
-            cls_out = q_head >> rt.META_SRC_SHIFT
-            mc = mc._replace(
-                head=jnp.where(
-                    done, (mc.head + 1) % stc.mc_queue_cap, mc.head
-                ),
-                count=mc.count - done.astype(jnp.int32),
-                timer=jnp.where(done, stc.mc_service_period, timer),
-                stage_valid=mc.stage_valid | done,
-                stage_dst=jnp.where(done, src_out, mc.stage_dst),
-                stage_cls=jnp.where(done, cls_out, mc.stage_cls),
-            )
-
-            # ---- 2. route/arbitrate every subnet (per-epoch fault masks:
-            # a dead link back-pressures, a browned-out router grants
-            # nothing — DESIGN.md §16)
-            subs, events = rt.router_cycle(
-                subs, route_t, nb_t, opp_t,
-                gpu_masks, cpu_masks, sa_pref, accept_s, active,
-                arbitrate_fn=arb_fn,
-                link_ok=flt.link_ok, router_ok=flt.router_ok,
-            )
-
-            # ---- 3. ejection handling
-            # request-subnet ejections at MC nodes -> enqueue into MC
-            # queues.  A per-subnet exclusive prefix count serializes
-            # same-MC arrivals into consecutive ring slots; the write is a
-            # dense masked where over (R, Q) (no scatter).
-            req_ej = (
-                events.eject_valid & sub_is_req[:, None] & is_mc[None, :]
-            )  # (S, R)
-            arr_i = req_ej.astype(jnp.int32)
-            slot_off = jnp.cumsum(arr_i, axis=0) - arr_i
-            slot = (
-                mc.head[None, :] + mc.count[None, :] + slot_off
-            ) % stc.mc_queue_cap
-            qmask = req_ej[..., None] & (
-                slot[..., None] == jnp.arange(stc.mc_queue_cap)
-            )  # (S, R, Q) — at most one subnet hits each slot
-            qhit = jnp.any(qmask, axis=0)
-            q_val = events.eject_src + (events.eject_cls << rt.META_SRC_SHIFT)
-            qm = jnp.sum(jnp.where(qmask, q_val[..., None], 0), axis=0)
-            mc = mc._replace(
-                q_meta=jnp.where(qhit, qm.astype(jnp.int8), mc.q_meta),
-                count=mc.count + jnp.sum(arr_i, axis=0),
-            )
-            # reply-subnet ejections at source nodes -> complete
-            # transactions (masked to live reply rows under S-padding)
-            rep_ej = (
-                events.eject_valid & sub_is_rep[:, None] & (~is_mc)[None, :]
-            )
-            rep_done = jnp.any(rep_ej, axis=0)
-            outstanding = outstanding - rep_done.astype(jnp.int32)
-            rep_cls = jnp.sum(jnp.where(rep_ej, events.eject_cls, 0), axis=0)
-
-            # Fig. 11 packet latency: network time (injection -> ejection).
-            # The subtraction runs in the stamp dtype — wraparound-exact
-            # for uint16 stamps because ages are <= total - 1 <= 2^16 - 1
-            # by construction (the init_sim_state stamp-dtype gate).
-            dt = events.eject_binj.dtype
-            age = (cycle.astype(dt) - events.eject_binj).astype(jnp.int32)
-            ej_lat = jnp.where(events.eject_valid, age, 0)
-            cpu_ej = events.eject_valid & (events.eject_cls == 0)
-            gpu_ej = events.eject_valid & (events.eject_cls == 1)
-
-            # ---- 4. source generation -> per-node source-queue depth
-            phase = step_phase_u(prof, phase, u_ph)
-            rates = injection_rates(prof, ntype_e, phase)
-            gen = (u_gen_c < rates) & ~is_mc  # == bernoulli(k_gen, rates)
-            # push into the per-node source queue (drop + stall if full)
-            can_push = gen & (bl_count < BCAP)
-            bl_count = bl_count + can_push.astype(jnp.int32)
-
-            can_inj = (
-                (bl_count > 0) & (outstanding < stc.mshr_limit) & ~is_mc
-            )
-
-            # ---- 5. ONE merged inject: this cycle's sources (request
-            # rows) + the replies staged this cycle (reply rows — the old
-            # engine injected those at the TOP of the next cycle; nothing
-            # between the two points touches reply-row state, so fusing
-            # them here is value-identical; `gate` defers the epoch's last
-            # cycle to the next epoch's prologue).
-            want_src = (
-                (sub_ids[:, None] == req_sub[None, :])
-                & can_inj[None, :]
-                & sub_enabled[:, None]
-            )
-            want_rep = make_want_rep(mc) & gate
-            is_req_row = sub_is_req[:, None]
-            subs, ok = rt.inject_all(
-                subs, want_src | want_rep,
-                jnp.where(is_req_row, dests[None, :], mc.stage_dst[None, :]),
-                jnp.broadcast_to(ar, (S, R)),
-                jnp.where(
-                    is_req_row, node_cls[None, :], mc.stage_cls[None, :]
-                ),
-                jnp.where(is_req_row, cycle, cycle + 1),
-                gpu_masks, cpu_masks,
-            )
-            inj_ok = jnp.any(ok & is_req_row, axis=0)
-            mc = mc._replace(
-                stage_valid=mc.stage_valid
-                & ~jnp.any(ok & ~is_req_row, axis=0)
-            )
-            bl_count = bl_count - inj_ok.astype(jnp.int32)
-            outstanding = outstanding + inj_ok.astype(jnp.int32)
-
-            # ---- 6. counters
-            gpu_blocked = is_gpu & (bl_count > 0)  # shader stuck at ICNT
-            cnt = EpochCounters(
-                gpu_push=cnt.gpu_push
-                + jnp.sum((inj_ok & is_gpu).astype(jnp.int32)),
-                gpu_stall_icnt=cnt.gpu_stall_icnt
-                + jnp.sum(gpu_blocked.astype(jnp.int32)),
-                gpu_stall_dram=cnt.gpu_stall_dram + events.dram_block_gpu,
-                cpu_push=cnt.cpu_push
-                + jnp.sum((inj_ok & is_cpu).astype(jnp.int32)),
-                gpu_done=cnt.gpu_done
-                + jnp.sum((rep_done & (rep_cls == 1)).astype(jnp.int32)),
-                cpu_done=cnt.cpu_done
-                + jnp.sum((rep_done & (rep_cls == 0)).astype(jnp.int32)),
-                gpu_gen=cnt.gpu_gen + jnp.sum((gen & is_gpu).astype(jnp.int32)),
-                cpu_gen=cnt.cpu_gen + jnp.sum((gen & is_cpu).astype(jnp.int32)),
-                lat_sum=cnt.lat_sum + jnp.sum(ej_lat),
-                lat_cnt=cnt.lat_cnt
-                + jnp.sum(events.eject_valid.astype(jnp.int32)),
-                cpu_lat_sum=cnt.cpu_lat_sum
-                + jnp.sum(jnp.where(cpu_ej, ej_lat, 0)),
-                cpu_lat_cnt=cnt.cpu_lat_cnt
-                + jnp.sum(cpu_ej.astype(jnp.int32)),
-                gpu_lat_sum=cnt.gpu_lat_sum
-                + jnp.sum(jnp.where(gpu_ej, ej_lat, 0)),
-                gpu_lat_cnt=cnt.gpu_lat_cnt
-                + jnp.sum(gpu_ej.astype(jnp.int32)),
-                moved=cnt.moved + events.moved,
-            )
-            if probe_on:
-                # ---- 7. flight-recorder accumulation from END-of-cycle
-                # state (the fused engine samples at the same point)
-                prb = _ProbeAcc(
-                    occ=prb.occ + subs.count.astype(jnp.int32),
-                    grant=prb.grant + events.grant_cnt,
-                    deny=prb.deny + events.deny_cnt,
-                    mcq_sum=prb.mcq_sum + mc.count,
-                    mcq_max=jnp.maximum(prb.mcq_max, mc.count),
-                )
-                return (
-                    subs, mc, phase, outstanding, bl_count, cnt, prb
-                ), None
-            return (subs, mc, phase, outstanding, bl_count, cnt), None
-
-        if fused_engine:
-            # ---- fused path (DESIGN.md §13): pack the carry into lane
-            # layout once per epoch, run ONE pallas_call per cycle with the
-            # whole state in kernel refs, unpack at the epoch boundary.
-            # Everything outside the cycle scan (prologue inject, RNG, KF,
-            # policy) is byte-for-byte the dense engine's code above/below.
-            gm_rows, cm_rows = lanes.mask_rows(lane_dims, g_vec, c_vec)
-            pr_rows = lanes.prof_rows(prof)
-            # placement lane rows (DESIGN.md §17): the node-type row and
-            # the req_match-bearing policy rows follow this epoch's plan
-            with set_xla_metadata(noc_layer="epoch.placement"):
-                ntype_row = lanes.placement_rows(lane_dims, ntype_e)
-                req_match = (
-                    (sub_ids[:, None] == req_sub[None, :])
-                    & sub_enabled[:, None]
-                )
-                pol_sr, pol_r = lanes.policy_rows(
-                    lane_dims, sub_enabled, sub_is_req, sub_is_rep, req_match,
-                    fs, n_req_subs,
-                )
-            xi, xf = lanes.cycle_xs(
-                lane_dims, cycles, u_phase, u_gen, dests_all, sa_all,
-                active_all, rep_gate,
-                router_ok=flt.router_ok, mc_ok=flt.mc_ok,
-            )
-            # epoch link-fault mask folded into the link-exists rows: the
-            # lane kernel sees a dead link exactly as a non-existent one
-            link_rows = jnp.tile(
-                jnp.pad(
-                    flt.link_ok.astype(jnp.int32).T,
-                    ((0, 0), (0, lanes.R_PAD - R)),
-                ),
-                (1, S),
-            )
-            exists_ep = exists_rows * link_rows
-            ls0 = lanes.pack_state(lane_dims, subs, mc, outst, backlog, phase)
-
-            def fused_cycle(ls, x):
-                ls = lane_ops.fused_cycle_step(
-                    lane_dims, ls, x[0], x[1], gm_rows, cm_rows, pr_rows,
-                    pol_sr, pol_r, ntype_row, route_rows, exists_ep,
-                )
-                return ls, None
-
-            def fused_cycle_probed(carry, x):
-                ls, pb = carry
-                ls, pb = lane_ops.fused_cycle_step(
-                    lane_dims, ls, x[0], x[1], gm_rows, cm_rows, pr_rows,
-                    pol_sr, pol_r, ntype_row, route_rows, exists_ep,
-                    probe=pb,
-                )
-                return (ls, pb), None
-
-            if probe_on:
-                with set_xla_metadata(noc_layer="cycle.scan"):
-                    (ls, pb), _ = jax.lax.scan(
-                        fused_cycle_probed,
-                        (ls0, lanes.zero_probe(lane_dims)),
-                        (xi, xf), unroll=stc.cycle_unroll,
-                    )
-                prb = _ProbeAcc(*lanes.unpack_probe(lane_dims, pb))
-            else:
-                with set_xla_metadata(noc_layer="cycle.scan"):
-                    ls, _ = jax.lax.scan(
-                        fused_cycle, ls0, (xi, xf), unroll=stc.cycle_unroll
-                    )
-            subs, mc, outst, backlog, phase = lanes.unpack_state(
-                lane_dims, ls, MCState, subnets0.buf_binj.dtype
-            )
-            cnt = EpochCounters(
-                *(ls.cnt[0, i] for i in range(lanes.N_COUNTERS))
-            )
-        else:
-            inner0 = (subs, mc, phase, outst, backlog, _zero_counters())
-            if probe_on:
-                inner0 = inner0 + (_zero_probe_acc(S, R, V),)
-            with set_xla_metadata(noc_layer="cycle.scan"):
-                inner, _ = jax.lax.scan(
-                    cycle_body, inner0, xs, unroll=stc.cycle_unroll
-                )
-            if probe_on:
-                subs, mc, phase, outst, backlog, cnt, prb = inner
-            else:
-                subs, mc, phase, outst, backlog, cnt = inner
+        # ---- the epoch's cycles: one call of the run's cycle engine
+        ep = EpochInputs(
+            g_vec=g_vec, c_vec=c_vec, gpu_masks=gpu_masks,
+            cpu_masks=cpu_masks, ntype=ntype_e, is_gpu=is_gpu, is_cpu=is_cpu,
+            node_cls=node_cls, req_sub=req_sub,
+            rows=CycleRows(cycles, u_phase, u_gen, dests_all, sa_all,
+                           active_all, rep_gate),
+            prof=prof, flt=flt,
+        )
+        (subs, mc, phase, outst, backlog), cnt, prb = run_cycles(
+            (subs, mc, phase, outst, backlog), ep)
         cycle = cycle0 + jnp.int32(stc.epoch_len)
 
         # ---- KF epoch update (paper §3.2)
@@ -1040,7 +713,6 @@ def simulate(
     cfg: NoCConfig,
     source: TrafficSourceLike,
     padded: bool = True,
-    backend: str | None = None,
 ) -> SimResult:
     """Run one configuration (compiles at most once per `SimStatic`).
 
@@ -1054,13 +726,11 @@ def simulate(
     With ``padded=True`` (default) every mode runs the shared S/V-padded
     program; ``padded=False`` compiles the mode's dedicated trace, kept so
     the equivalence tests can pin padded == dedicated bit-for-bit.
-    ``backend`` overrides the config's cycle-engine backend ("ref" |
-    "pallas" | "pallas_arb", see DESIGN.md §11/§13 — "pallas" is the fused
-    full-cycle lane kernel); each backend is its own `SimStatic`, so opting
-    into a Pallas path never perturbs the default program's trace count.
+    The cycle engine is ``cfg.backend``, the platform's when None (see
+    `platform_engine`); each engine is its own `SimStatic`.
     """
     with span("noc.args"):
-        stc, *args = sim_args(cfg, source, padded, backend)
+        stc, *args = sim_args(cfg, source, padded)
         args = jax.device_put(args)
     with span("noc.dispatch"):
         return _SIM_JIT(stc, *args)
@@ -1070,15 +740,12 @@ def sim_args(
     cfg: NoCConfig,
     source: TrafficSourceLike,
     padded: bool = True,
-    backend: str | None = None,
 ) -> tuple:
     """The `_SIM_JIT` arguments `simulate` dispatches: (SimStatic, policy,
     demand rows, seed, initial state, faults, placement), every array a
     host (NumPy) array; `simulate` puts them on the device in one
     transfer."""
     stc = cfg.static_spec(padded)
-    if backend is not None:
-        stc = dataclasses.replace(stc, backend=backend)
     with span("noc.schedules"):
         demand = resolve_source(source, stc.n_epochs)
         flt = _run_faults(cfg.faults, stc)
@@ -1098,7 +765,6 @@ def simulate_with_trace(
     cfg: NoCConfig,
     source: TrafficSourceLike,
     padded: bool = True,
-    backend: str | None = None,
 ) -> tuple[SimResult, SimTrace]:
     """`simulate` with the flight recorder on: returns (SimResult, SimTrace).
 
@@ -1109,7 +775,7 @@ def simulate_with_trace(
     (tests/test_obs.py)."""
     if not cfg.probe.enabled:
         cfg = dataclasses.replace(cfg, probe=ProbeConfig(enabled=True))
-    return simulate(cfg, source, padded=padded, backend=backend)
+    return simulate(cfg, source, padded=padded)
 
 
 def _tree_rows(tree, sl):

@@ -1,15 +1,15 @@
 """Lane-layout twins of the dense cycle engine + the fused full-cycle step.
 
 This module is the pure-jnp half of the fused Pallas cycle kernel
-(DESIGN.md §13): every stage of `sim._simulate_impl`'s `cycle_body` — MC
+(DESIGN.md §13): every stage of the dense engine's `engine.ref_cycle` — MC
 acceptance/service, route + switch allocation, buffer dequeue/enqueue
-writes, MC enqueue, reply completion, source generation, the merged
-inject, and the metrics counters — rewritten over the packed lane layout
-the arbitration kernel (kernel.py) introduced, as plain 2D
-(sublane, lane) ops with NO captured constant arrays.  `cycle_step_lanes`
-is therefore callable both as a regular jitted function (the dense twin
-the micro-congruence tests compare stage by stage) and as the body of one
-`pallas_call` per simulated cycle (`kernel.fused_cycle_kernel`).
+writes, MC enqueue, reply completion, source generation, the merged inject,
+and the metrics counters — rewritten over the packed lane layout below, as
+plain 2D (sublane, lane) ops with NO captured constant arrays.
+`cycle_step_lanes` is therefore callable both as a regular jitted function
+(the dense twin the micro-congruence tests compare stage by stage) and as
+the body of one `pallas_call` per simulated cycle
+(`kernel.fused_cycle_kernel`).
 
 Lane layout
 -----------
@@ -36,12 +36,12 @@ to/from the narrow packed dtypes (int16 meta, uint16/int32 stamps, int8
 head/count/rr/q_meta) with value-exact casts (meta < 2^15, q_meta < 2^7,
 and a uint16 stamp cast reproduces the dense engine's wraparound stores).
 
-Garbage-value conventions are inherited from the arbitration kernel
-(DESIGN.md §11): padded lanes and cross-block shift reads hold arbitrary
-values, but every such site is masked (by `exists`, a false grant, or a
-false eject) before it can reach a state write or a counter — the dense
-engine and this module agree BITWISE on all carried state and counters,
-which tests/test_cycle_engine.py pins per stage and end to end.
+Garbage-value conventions (DESIGN.md §11): padded lanes and cross-block
+shift reads hold arbitrary values, but every such site is masked (by
+`exists`, a false grant, or a false eject) before it can reach a state
+write or a counter — the dense engine and this module agree BITWISE on all
+carried state and counters, which tests/test_cycle_engine.py pins per stage
+and end to end.
 """
 from __future__ import annotations
 
@@ -71,17 +71,17 @@ Array = jax.Array
 
 R_PAD = 64     # router lanes per subnet block (shift-closed padding)
 LANES_R = 128  # per-node state rides one 128-lane block
-BIG = 1 << 20  # grant-rank sentinel — must match kernel.py / router.py
+BIG = 1 << 20  # grant-rank sentinel — must match router.py
 
 OPP = tuple(int(p) for p in OPPOSITE)
 
-# `mc` row indices (mirror sim.MCState field order)
+# `mc` row indices (mirror engine.MCState field order)
 MC_HEAD, MC_COUNT, MC_TIMER, MC_SVALID, MC_SDST, MC_SCLS = range(6)
 MC_ROWS = 6
 # `node` row indices
 ND_OUTST, ND_BACKLOG, ND_PHASE = range(3)
 ND_ROWS = 3
-# counter lanes — must equal sim.EpochCounters._fields (asserted at dispatch)
+# counter lanes — must equal engine.EpochCounters._fields (asserted at build)
 COUNTER_FIELDS = (
     "gpu_push", "gpu_stall_icnt", "gpu_stall_dram", "cpu_push",
     "gpu_done", "cpu_done", "gpu_gen", "cpu_gen",
@@ -181,9 +181,8 @@ def zero_probe(d: LaneDims) -> ProbeLanes:
 class LaneArb(NamedTuple):
     """Per-output-port arbitration results as lists of (rows, L) blocks.
 
-    The list-of-rows form keeps the port loop unrolled at trace time for
-    both consumers: the standalone arbitration kernel concatenates the
-    lists into its output refs, the fused step indexes them per port.
+    The list-of-rows form keeps the port loop unrolled at trace time: the
+    fused step indexes them per port.
     """
 
     grant: list      # O x (1, L) bool
@@ -223,12 +222,12 @@ def lane_arbitrate(
     *,
     depth: int,
 ) -> LaneArb:
-    """Switch allocation over lanes — the value-level arbitration kernel.
+    """Switch allocation over lanes.
 
     Bitwise-identical to `router.arbitrate` on every output (the packed-min
     winner pick, the min-of-iota first-free-VC pick mirroring argmax-of-bool,
     and the garbage-when-ungranted conventions are all mirrored exactly);
-    shared verbatim by `kernel._noc_cycle_kernel` and `cycle_step_lanes`.
+    the switch-allocation stage of `cycle_step_lanes`.
     """
     PV, _ = valid.shape
     O = rr.shape[0]
@@ -336,14 +335,14 @@ def _s_slices(d: LaneDims, x: Array):
 
 
 # ---------------------------------------------------------------------------
-# stage twins — each mirrors one `sim.cycle_body` stage over lanes
+# stage twins — each mirrors one `engine.ref_cycle` stage over lanes
 # ---------------------------------------------------------------------------
 
 def mc_service_lanes(
     d: LaneDims, mc: Array, mcq: Array, ntype: Array,
     mc_ok: Array | None = None,
 ):
-    """MC service tick: timers, head request -> staging (cycle_body stage 1).
+    """MC service tick: timers, head request -> staging (ref_cycle stage 1).
 
     Returns the six updated `mc` rows; the queue head peek is a Q-step
     one-hot sum (head is always in [0, Q), so it equals the dense
@@ -388,7 +387,7 @@ def router_stage_lanes(
     gmask: Array, cmask: Array, sa: Array, accept: Array, active: Array,
     route: Array, exists: Array,
 ):
-    """One full router cycle over lanes (cycle_body stage 2 / router_cycle).
+    """One full router cycle over lanes (ref_cycle stage 2 / router_cycle).
 
     Head peeks are B-step one-hot sums over the slot-major buffer blocks,
     the route lookup is an R-step one-hot sum over the route table rows,
@@ -566,7 +565,7 @@ def mc_enqueue_lanes(
     d: LaneDims, mcq: Array, head: Array, count: Array,
     req_ej: Array, q_val: Array,
 ):
-    """Enqueue request ejections into MC ring slots (cycle_body stage 3a).
+    """Enqueue request ejections into MC ring slots (ref_cycle stage 3a).
 
     The per-subnet exclusive prefix over the S blocks serializes same-MC
     arrivals into consecutive slots, matching the dense cumsum exactly.
@@ -628,7 +627,7 @@ def cycle_step_lanes(
 ):
     """ONE full simulated NoC cycle over lanes — the fused kernel body.
 
-    Stage order and semantics mirror `sim.cycle_body` exactly; every
+    Stage order and semantics mirror `engine.ref_cycle` exactly; every
     input/output is a 2D (sublane, lane) int32/float32 block so the same
     function traces as a Pallas kernel body and as a plain jitted twin.
 
@@ -790,7 +789,7 @@ def cycle_step_lanes(
     if probe is None:
         return st2
     # ---- 7. flight-recorder accumulation from END-of-cycle state — the
-    # lane twin of the dense engine's ProbeAcc update (sim.cycle_body)
+    # lane twin of the dense engine's ProbeAcc update (engine.ref_cycle)
     probe2 = ProbeLanes(
         occ=probe.occ + count,
         arb=probe.arb + jnp.concatenate([grant_cnt, deny_cnt], axis=0),
@@ -1015,7 +1014,7 @@ def pack_state(
 
 def unpack_state(d: LaneDims, ls: LaneState, mc_cls, binj_dtype):
     """Lane layout -> dense sim carry.  `mc_cls` is the dense MCState class
-    (sim.MCState — passed in to avoid a circular import); the int32 ->
+    (`repro.core.noc.engine.MCState`, passed in by the caller); the int32 ->
     narrow-dtype casts reproduce the dense engine's stored values exactly
     (meta < 2^15, q_meta < 2^7, and the uint16 stamp cast IS the dense
     engine's wraparound store)."""

@@ -1,112 +1,27 @@
-"""Pallas TPU kernel: the NoC router-arbitration inner loop over lanes.
+"""Pallas TPU kernel: one simulated NoC cycle over the whole lane state.
 
-One simulated cycle's switch allocation — VC allocation at the downstream
-router, per-output round-robin arbitration, and the one-traversal-per-input
-grant filter — for EVERY (subnet, router) pair at once.  The pairs ride the
-128-wide TPU lanes as a flattened `(S*R)` lane axis (batched sweeps flatten
-`(B*S*R)`), and the small microarchitectural axes (P*V requesters, O output
-ports, V virtual channels) ride sublanes with the port/VC loops unrolled at
-trace time — every op in the kernel is a 2D (sublane, lane) VPU op.
+`fused_cycle_kernel` runs every stage of a simulated cycle — MC service,
+route and switch allocation, the buffer writes, MC enqueue, reply
+completion, source generation, the merged inject and the counters — for
+EVERY (subnet, router) pair at once, as ONE `pallas_call` with the whole
+scan carry in its refs (DESIGN.md §13).  The pairs ride the 128-wide TPU
+lanes, the small microarchitectural axes (P*V requesters, O output ports,
+V virtual channels) ride sublanes with the port/VC loops unrolled at trace
+time, so every op in the kernel is a 2D (sublane, lane) VPU op.
 
-This is the jax_pallas-facing half of the cycle engine (DESIGN.md §11, §13):
-the dense-jnp `router.arbitrate` is the oracle, `ops.arbitrate_lanes` is the
-`simulate(..., backend="pallas_arb")` entry with interpret-mode fallback
-off-TPU, and the two must agree BITWISE — the packed-min trick, the
-argmax-of-bool VC pick and the garbage-when-ungranted conventions are all
-mirrored exactly.  The value-level arbitration body lives in
-`fused.lane_arbitrate` and is shared with `fused_cycle_kernel` — the
-full-cycle kernel that `simulate(..., backend="pallas")` launches once per
-simulated cycle with the whole scan carry in its refs.
+The value-level body is `fused.cycle_step_lanes` (its switch allocation is
+`fused.lane_arbitrate`); the dense `ref` engine (`repro.core.noc.engine`)
+is the oracle it must match BITWISE, and `engine.pallas_engine` launches
+it once per simulated cycle.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.noc_cycle import fused
-
-BIG = fused.BIG
-
-
-def _noc_cycle_kernel(
-    valid_ref, cls_ref, out_port_ref, rr_ref, down_ref, exists_ref,
-    gmask_ref, cmask_ref, sa_ref, accept_ref, active_ref,
-    grant_ref, winner_ref, down_vc_ref, deq_ref, new_rr_ref,
-    any_req_ref, w_cls_ref,
-    *,
-    depth: int,
-):
-    arb = fused.lane_arbitrate(
-        valid_ref[...] != 0,
-        cls_ref[...],
-        out_port_ref[...],
-        rr_ref[...],
-        down_ref[...],
-        exists_ref[...] != 0,
-        gmask_ref[...] != 0,
-        cmask_ref[...] != 0,
-        sa_ref[...],
-        accept_ref[...] != 0,
-        active_ref[...] != 0,
-        depth=depth,
-    )
-    grant_ref[...] = fused.stack_rows(arb.grant)
-    winner_ref[...] = jnp.concatenate(arb.winner, axis=0)
-    down_vc_ref[...] = jnp.concatenate(arb.down_vc, axis=0)
-    deq_ref[...] = arb.deq
-    new_rr_ref[...] = jnp.concatenate(arb.new_rr, axis=0)
-    any_req_ref[...] = fused.stack_rows(arb.any_req)
-    w_cls_ref[...] = jnp.concatenate(arb.w_cls, axis=0)
-
-
-def noc_cycle_kernel(
-    valid: jax.Array,       # (PV, L) int32 0/1
-    cls: jax.Array,         # (PV, L) int32
-    out_port: jax.Array,    # (PV, L) int32
-    rr_ptr: jax.Array,      # (O, L) int32
-    down_count: jax.Array,  # (O*V, L) int32
-    down_exists: jax.Array,  # (O, L) int32 0/1
-    gmask: jax.Array,       # (V, L) int32 0/1
-    cmask: jax.Array,       # (V, L) int32 0/1
-    sa_pref: jax.Array,     # (1, L) int32
-    accept: jax.Array,      # (1, L) int32 0/1
-    active: jax.Array,      # (1, L) int32 0/1
-    *,
-    depth: int,
-    n_vcs: int,
-    block_l: int = 128,
-    interpret: bool = False,
-) -> tuple[jax.Array, ...]:
-    """Lane-blocked dispatch; L must be a multiple of `block_l`."""
-    pv, lanes = valid.shape
-    o = rr_ptr.shape[0]
-    assert lanes % block_l == 0, (lanes, block_l)
-    grid = (lanes // block_l,)
-
-    def spec(rows):
-        return pl.BlockSpec((rows, block_l), lambda i: (0, i))
-
-    out_rows = [o, o, o, pv, o, o, o]
-    kernel = functools.partial(_noc_cycle_kernel, depth=depth)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            spec(pv), spec(pv), spec(pv), spec(o), spec(o * n_vcs),
-            spec(o), spec(n_vcs), spec(n_vcs), spec(1), spec(1), spec(1),
-        ],
-        out_specs=[spec(r) for r in out_rows],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, lanes), jnp.int32) for r in out_rows
-        ],
-        interpret=interpret,
-        name="noc_cycle_arbitrate",
-        metadata={"noc_layer": "cycle.arbitrate"},
-    )(valid, cls, out_port, rr_ptr, down_count, down_exists,
-      gmask, cmask, sa_pref, accept, active)
 
 
 # ---------------------------------------------------------------------------

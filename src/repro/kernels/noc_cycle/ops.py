@@ -1,26 +1,17 @@
-"""Lane-flattening wrappers for the noc_cycle kernels + backend dispatch.
+"""Dispatch entry for the fused cycle kernel, with interpret mode off-TPU.
 
-`arbitrate_lanes` is signature-compatible with `repro.core.noc.router.
-arbitrate` (the oracle in ref.py): it flattens every leading dimension of
-the router state onto the kernel's lane axis — `(S, R)` for a single run,
-`(B, S, R)` under a batched sweep — pads lanes to the 128-wide block, and
-returns the same `Arbitration` pytree.  It backs
-`simulate(..., backend="pallas_arb")`, the arbitration-only kernel swap.
-
-`fused_cycle_step` is the full-cycle entry behind
-`simulate(..., backend="pallas")`: one `fused_cycle_kernel` launch per
-simulated cycle with the whole scan carry in lane layout (DESIGN.md §13).
-Off-TPU both run in interpret mode (like `repro.kernels.kf_bank`), so every
-backend works everywhere the tests run.
+`fused_cycle_step` is one `fused_cycle_kernel` launch: one simulated
+cycle with the whole scan carry in lane layout (DESIGN.md §13), the step
+that `engine.pallas_engine` scans.  Off-TPU it runs in interpret mode (like
+`repro.kernels.kf_bank`), so the `pallas` engine runs everywhere the tests
+run.
 """
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
-from repro.core.noc.router import Arbitration
 from repro.kernels.noc_cycle import fused
-from repro.kernels.noc_cycle.kernel import fused_cycle_kernel, noc_cycle_kernel
+from repro.kernels.noc_cycle.kernel import fused_cycle_kernel
 
 
 def _interpret() -> bool:
@@ -44,72 +35,4 @@ def fused_cycle_step(
         state, xi, xf, gmask, cmask, prof, pol_sr, pol_r,
         ntype, route, exists,
         dims=dims, interpret=_interpret(), probe=probe,
-    )
-
-
-def arbitrate_lanes(
-    valid: jax.Array,        # (..., P*V) bool
-    cls: jax.Array,          # (..., P*V) int32
-    out_port: jax.Array,     # (..., P*V) int32
-    rr_ptr: jax.Array,       # (..., O) int32
-    down_count: jax.Array,   # (..., O, V) int32
-    down_exists: jax.Array,  # (..., O) bool
-    gpu_vc_mask: jax.Array,  # (..., V) bool
-    cpu_vc_mask: jax.Array,  # (..., V) bool
-    sa_pref: jax.Array,      # (...,) int32
-    accept: jax.Array,       # (...,) bool
-    active: jax.Array,       # (...,) bool
-    *,
-    depth: int,
-    block_l: int = 128,
-) -> Arbitration:
-    lead = valid.shape[:-1]
-    pv = valid.shape[-1]
-    o = rr_ptr.shape[-1]
-    v = down_count.shape[-1]
-    lanes = 1
-    for d in lead:
-        lanes *= d
-    pad = (-lanes) % block_l
-
-    def to_lanes(x, tail: tuple[int, ...]):
-        """Broadcast to full lead shape, flatten, pad, lanes-last layout."""
-        rows = 1
-        for d in tail:
-            rows *= d
-        x = jnp.broadcast_to(x, lead + tail).reshape(lanes, rows)
-        x = jnp.pad(x.astype(jnp.int32), ((0, pad), (0, 0)))
-        return x.T                                      # (rows, L)
-
-    outs = noc_cycle_kernel(
-        to_lanes(valid, (pv,)),
-        to_lanes(cls, (pv,)),
-        to_lanes(out_port, (pv,)),
-        to_lanes(rr_ptr, (o,)),
-        to_lanes(down_count, (o, v)),
-        to_lanes(down_exists, (o,)),
-        to_lanes(gpu_vc_mask, (v,)),
-        to_lanes(cpu_vc_mask, (v,)),
-        to_lanes(sa_pref, ()),
-        to_lanes(accept, ()),
-        to_lanes(active, ()),
-        depth=depth,
-        n_vcs=v,
-        block_l=block_l,
-        interpret=_interpret(),
-    )
-
-    def back(x, tail: tuple[int, ...], as_bool: bool = False):
-        x = x.T[:lanes].reshape(lead + tail)
-        return x != 0 if as_bool else x
-
-    grant, winner, down_vc, deq, new_rr, any_req, w_cls = outs
-    return Arbitration(
-        grant=back(grant, (o,), as_bool=True),
-        winner=back(winner, (o,)),
-        down_vc=back(down_vc, (o,)),
-        deq=back(deq, (pv,), as_bool=True),
-        new_rr=back(new_rr, (o,)),
-        any_req=back(any_req, (o,), as_bool=True),
-        w_cls=back(w_cls, (o,)),
     )

@@ -25,8 +25,8 @@ Names the program uses (DESIGN.md §18).  Host spans in `sim.sweep`,
 `noc.args` (argument building), `noc.dispatch` (each call of the compiled
 program), `noc.rows` (cutting the answer into rows).  Device labels, the
 XLA frontend attribute `noc_layer` on the compiled operations of
-`_simulate_impl`: `epoch.rng`, `epoch.boundary`, `cycle.scan`; the
-`pallas_call`s carry `cycle.kernel` (fused) and `cycle.arbitrate` in their
+`_simulate_impl` and the cycle engines: `epoch.rng`, `epoch.boundary`,
+`cycle.scan`; the fused `pallas_call` carries `cycle.kernel` in its
 kernel metadata.
 """
 

@@ -55,7 +55,7 @@ class TraceRecorder:
     name: str = "capture"
     observe: bool = True
 
-    def record(self, cfg, source, backend: str | None = None) -> RecordedTrace:
+    def record(self, cfg, source) -> RecordedTrace:
         """Capture the per-epoch demand rows a (cfg, source) run consumes.
 
         Returns a `RecordedTrace` whose rows replay bitwise-identical to
@@ -76,22 +76,21 @@ class TraceRecorder:
             "n_epochs": int(cfg.n_epochs),
             "epoch_len": int(cfg.epoch_len),
             "seed": int(cfg.seed),
-            "backend": backend or cfg.backend,
+            "backend": cfg.static_spec().backend,
             "recorder": "TraceRecorder",
         }
         if self.observe:
             from repro.obs.probes import summarize_trace
 
-            res, trace = sim.simulate_with_trace(cfg, demand, backend=backend)
+            res, trace = sim.simulate_with_trace(cfg, demand)
             meta["observed"] = summarize_trace(trace)
             meta["result"] = sim.summarize(res)
         return RecordedTrace(demand=rows, fit="exact", name=self.name,
                              meta=meta)
 
-    def record_to(self, path, cfg, source,
-                  backend: str | None = None) -> RecordedTrace:
+    def record_to(self, path, cfg, source) -> RecordedTrace:
         """`record` and save the capture as a versioned npz trace file."""
-        trace = self.record(cfg, source, backend=backend)
+        trace = self.record(cfg, source)
         trace.save(path)
         return trace
 

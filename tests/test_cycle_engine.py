@@ -10,10 +10,12 @@ engine produced them.  Three layers of pinning:
      PR-3 padded program; the new engine must reproduce it bit-for-bit;
   2. rewrite micro-tests — each equivalence-preserving rewrite is checked
      directly against the formulation it replaced;
-  3. ref <-> Pallas congruence — `kernels.noc_cycle` (interpret mode off
-     TPU) must agree with `router.arbitrate` exactly, from a single
-     arbitration step up to a whole `simulate(backend="pallas")` run.
+  3. ref <-> Pallas congruence — the fused `pallas` engine
+     (`kernels.noc_cycle`, interpret mode off TPU) must agree with the
+     dense `ref` engine exactly, from a single arbitration step through
+     one epoch called at the engine interface up to a whole run.
 """
+import dataclasses
 import json
 import os
 
@@ -28,6 +30,7 @@ from repro.core.noc import sim
 from repro.core.noc.sim import NoCConfig
 from repro.core.noc.topology import N_PORTS, make_topology
 from repro.core.noc.traffic import PROFILES
+from repro.obs.probes import ProbeConfig
 
 FAST = dict(n_epochs=8, epoch_len=100)
 GOLDEN_PATH = os.path.join(
@@ -53,7 +56,7 @@ def test_outputs_match_pr3_golden_capture():
     for key, g in golden.items():
         mode, wl, gs, ss = key.split("/")
         cfg = NoCConfig(mode=mode, static_gpu_vcs=int(gs[1:]),
-                        seed=int(ss[1:]), **FAST)
+                        seed=int(ss[1:]), backend="ref", **FAST)
         res = sim.simulate(cfg, PROFILES[wl])
         sums = {n: int(np.sum(np.asarray(leaf)))
                 for n, leaf in zip(res.counters._fields, res.counters)}
@@ -231,63 +234,80 @@ def _random_arbitrate_inputs(rng, lead, P=N_PORTS, V=4, B=4):
     )
 
 
-def test_noc_cycle_kernel_matches_ref_on_random_states():
-    """Every `Arbitration` output agrees exactly — including the ragged
-    lane tail (S*R = 144 pads up to the 256-lane grid)."""
-    from repro.kernels.noc_cycle.ops import arbitrate_lanes
-    from repro.kernels.noc_cycle.ref import noc_cycle_ref
+def _arbitrate_on_lanes(inp, depth):
+    """`fused.lane_arbitrate` on `router.arbitrate`'s inputs: every leading
+    dimension flattened onto the lane axis and padded to whole 128-lane
+    blocks, as the fused kernel lays out `(S, R)`, and the answer cut back
+    to `Arbitration`'s shapes."""
+    from repro.kernels.noc_cycle import fused
 
+    lead = inp["valid"].shape[:-1]
+    lanes = int(np.prod(lead))
+    pad = (-lanes) % 128
+    pv = inp["valid"].shape[-1]
+    o = inp["rr_ptr"].shape[-1]
+    v = inp["down_count"].shape[-1]
+
+    def to_lanes(x, tail=()):
+        rows = int(np.prod(tail, dtype=int))
+        x = jnp.broadcast_to(x, lead + tail).reshape(lanes, rows)
+        return jnp.pad(x.astype(jnp.int32), ((0, pad), (0, 0))).T
+
+    def back(x, tail, as_bool=False):
+        x = x.T[:lanes].reshape(lead + tail)
+        return x != 0 if as_bool else x
+
+    arb = fused.lane_arbitrate(
+        to_lanes(inp["valid"], (pv,)) != 0,
+        to_lanes(inp["cls"], (pv,)),
+        to_lanes(inp["out_port"], (pv,)),
+        to_lanes(inp["rr_ptr"], (o,)),
+        to_lanes(inp["down_count"], (o, v)),
+        to_lanes(inp["down_exists"], (o,)) != 0,
+        to_lanes(inp["gpu_vc_mask"], (v,)) != 0,
+        to_lanes(inp["cpu_vc_mask"], (v,)) != 0,
+        to_lanes(inp["sa_pref"]),
+        to_lanes(inp["accept"]) != 0,
+        to_lanes(inp["active"]) != 0,
+        depth=depth,
+    )
+    return rt.Arbitration(
+        grant=back(fused.stack_rows(arb.grant), (o,), as_bool=True),
+        winner=back(jnp.concatenate(arb.winner, axis=0), (o,)),
+        down_vc=back(jnp.concatenate(arb.down_vc, axis=0), (o,)),
+        deq=back(arb.deq, (pv,), as_bool=True),
+        new_rr=back(jnp.concatenate(arb.new_rr, axis=0), (o,)),
+        any_req=back(fused.stack_rows(arb.any_req), (o,), as_bool=True),
+        w_cls=back(jnp.concatenate(arb.w_cls, axis=0), (o,)),
+    )
+
+
+def test_lane_arbitrate_matches_ref_on_random_states():
+    """`fused.lane_arbitrate`, the fused kernel's switch allocation, agrees
+    with `router.arbitrate` on every `Arbitration` output — including the
+    ragged lane tail (S*R = 144 pads up to the 256-lane grid)."""
     rng = np.random.default_rng(3)
     for lead in [(4, 36), (2, 36), (1, 7)]:
         inp = _random_arbitrate_inputs(rng, lead)
-        ref = noc_cycle_ref(**inp, depth=4)
-        ker = arbitrate_lanes(**inp, depth=4)
-        for name, a, b in zip(ref._fields, ref, ker):
+        ref = rt.arbitrate(**inp, depth=4)
+        lane = _arbitrate_on_lanes(inp, depth=4)
+        for name, a, b in zip(ref._fields, ref, lane):
             np.testing.assert_array_equal(
                 np.asarray(a), np.asarray(b),
                 err_msg=f"lead={lead}: arbitration output {name}",
             )
 
 
-def test_noc_cycle_kernel_matches_ref_on_full_router_cycle():
-    """A whole `router_cycle` step (peek -> arbitrate -> dequeue/traverse)
-    agrees exactly between the ref and Pallas arbitration backends."""
-    from repro.kernels.noc_cycle.ops import arbitrate_lanes
-
-    rng = np.random.default_rng(11)
-    topo = make_topology()
-    route_t, nb_t, opp_t, ntype, _ = rt.device_tables(topo)
-    S, V = 4, 4
-    state = _random_subnet_state(rng)
-    gmask = jnp.asarray(rng.random((S, V)) < 0.7)
-    cmask = jnp.asarray(rng.random((S, V)) < 0.7)
-    sa = jnp.int32(1)
-    accept = jnp.asarray(rng.random((S, topo.n_routers)) < 0.8)
-    active = jnp.asarray([True, True, False, True])
-
-    ref_state, ref_ev = rt.router_cycle(
-        state, route_t, nb_t, opp_t, gmask, cmask, sa, accept, active
-    )
-    pal_state, pal_ev = rt.router_cycle(
-        state, route_t, nb_t, opp_t, gmask, cmask, sa, accept, active,
-        arbitrate_fn=arbitrate_lanes,
-    )
-    _states_equal(ref_state, pal_state)
-    for name, a, b in zip(ref_ev._fields, ref_ev, pal_ev):
-        np.testing.assert_array_equal(
-            np.asarray(a), np.asarray(b), err_msg=f"event {name}"
-        )
-
-
 def test_simulate_pallas_backend_runs_fig2_3_smoke():
-    """`simulate(..., backend="pallas")` runs a Fig. 2/3 grid point end to
-    end and reproduces the default backend bit-for-bit (the backend is its
+    """A `NoCConfig(backend="pallas")` run of a Fig. 2/3 grid point runs end
+    to end and reproduces the `ref` engine bit-for-bit (the backend is its
     own `SimStatic`, so this never disturbs the paper sweep's single
     compiled program)."""
     tiny = dict(n_epochs=2, epoch_len=40)
     cfg = NoCConfig(mode="static", static_gpu_vcs=3, **tiny)
     ref = sim.simulate(cfg, PROFILES["PATH"])
-    pal = sim.simulate(cfg, PROFILES["PATH"], backend="pallas")
+    pal = sim.simulate(dataclasses.replace(cfg, backend="pallas"),
+                       PROFILES["PATH"])
     for (path, a), (_, b) in zip(
         jax.tree_util.tree_leaves_with_path(ref),
         jax.tree_util.tree_leaves_with_path(pal),
@@ -298,32 +318,85 @@ def test_simulate_pallas_backend_runs_fig2_3_smoke():
         )
 
 
-def test_simulate_pallas_arb_backend_matches_ref():
-    """`backend="pallas_arb"` (dense body + arbitration lane kernel — the
-    pre-fusion Pallas path) still reproduces the ref engine bit-for-bit,
-    and each backend compiles exactly one program (its own `SimStatic`)."""
-    tiny = dict(n_epochs=2, epoch_len=40)
-    cfg = NoCConfig(mode="static", static_gpu_vcs=3, **tiny)
-    sim.reset_trace_count()
-    ref = sim.simulate(cfg, PROFILES["PATH"])
-    pal = sim.simulate(cfg, PROFILES["PATH"], backend="pallas_arb")
-    # at most one trace per backend (jit cache hits from earlier tests on
-    # the same SimStatic may make it fewer, never more)
-    assert sim.trace_count() <= 2
+@pytest.mark.parametrize("backend", ["cuda", "pallas_arb"])
+def test_unknown_backend_rejected(backend):
+    cfg = NoCConfig(mode="baseline", n_epochs=1, epoch_len=10,
+                    backend=backend)
+    with pytest.raises(ValueError, match=f"backend {backend!r}"):
+        sim.simulate(cfg, PROFILES["PATH"])
+
+
+@pytest.mark.parametrize(
+    "platform,engine", [("cpu", "ref"), ("tpu", "pallas")])
+def test_platform_picks_the_engine(monkeypatch, platform, engine):
+    """With no engine named, the platform picks it: the fused kernel on a
+    TPU, the dense engine elsewhere; a named engine is kept."""
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert NoCConfig().static_spec().backend == engine
+    for named in ("ref", "pallas"):
+        assert NoCConfig(backend=named).static_spec().backend == named
+
+
+SEAM_CASES = {
+    "4subnet": dict(mode="4subnet"),
+    "kf-flap-guard": dict(mode="kf", faults="FLAP_DURING_SHIFT", guard=True),
+    "probes": dict(mode="kf", probe=ProbeConfig(enabled=True)),
+}
+
+
+@pytest.mark.parametrize("case", list(SEAM_CASES))
+def test_engines_agree_from_a_mid_run_carry(monkeypatch, case):
+    """Both engines, called directly through the engine interface from one
+    mid-run carry over one epoch's inputs, return the same carry, counters
+    and probe accumulators bitwise.  The carry and the inputs are those a
+    real `ref` run hands its engine, recorded on the host as the run goes:
+    of the epoch with the most dead links, the latest if none has any."""
+    cfg = NoCConfig(n_epochs=20, epoch_len=20, seed=1, backend="ref",
+                    **SEAM_CASES[case])
+    build = {name: sim._cycle_engine(name) for name in ("ref", "pallas")}
+    seen = []
+
+    def record(cycle0, *args):
+        seen.append((int(cycle0), args))
+
+    def spy_build(backend):
+        def build_spy(stc, topo, fab, binj_dtype):
+            run = build[backend](stc, topo, fab, binj_dtype)
+
+            def run_spy(carry, ep):
+                jax.debug.callback(record, ep.rows.cycle[0], fab, carry, ep)
+                return run(carry, ep)
+            return run_spy
+        return build_spy
+
+    monkeypatch.setattr(sim, "_cycle_engine", spy_build)
+    stc, *args = sim.sim_args(cfg, "SHIFT_PATH_BFS")
+    jax.block_until_ready(
+        jax.jit(lambda *a: sim._simulate_impl(stc, *a))(*args))
+    assert len(seen) == cfg.n_epochs
+    cycle0, (fab, carry, ep) = max(
+        seen, key=lambda rec: (np.sum(~rec[1][2].flt.link_ok), rec[0]))
+    assert cycle0 > 0
+    assert np.any(~ep.flt.link_ok) == (cfg.faults is not None)
+    subs, mc, _, outstanding, _ = carry
+    assert np.sum(subs.count) > 0 and np.sum(outstanding) > 0, "not mid-run"
+
+    topo = make_topology(stc.width, stc.height, stc.n_mc)
+    outs = {
+        name: jax.jit(b(stc, topo, fab, subs.buf_binj.dtype))(carry, ep)
+        for name, b in build.items()
+    }
+    ref, pal = outs["ref"], outs["pallas"]
+    assert (ref[2] is None) == (not cfg.probe.enabled)
+    assert jax.tree.structure(ref) == jax.tree.structure(pal)
     for (path, a), (_, b) in zip(
         jax.tree_util.tree_leaves_with_path(ref),
         jax.tree_util.tree_leaves_with_path(pal),
     ):
         np.testing.assert_array_equal(
             np.asarray(a), np.asarray(b),
-            err_msg=f"leaf {jax.tree_util.keystr(path)}",
+            err_msg=f"{case}: leaf {jax.tree_util.keystr(path)}",
         )
-
-
-def test_unknown_backend_rejected():
-    cfg = NoCConfig(mode="baseline", n_epochs=1, epoch_len=10)
-    with pytest.raises(ValueError, match="backend"):
-        sim.simulate(cfg, PROFILES["PATH"], backend="cuda")
 
 
 # ---------------------------------------------------------------------------
@@ -373,7 +446,8 @@ def test_fused_backend_matches_golden_capture():
         mode, wl, gs, ss = key.split("/")
         cfg = NoCConfig(mode=mode, static_gpu_vcs=int(gs[1:]),
                         seed=int(ss[1:]), **FAST)
-        res = sim.simulate(cfg, PROFILES[wl], backend="pallas")
+        res = sim.simulate(dataclasses.replace(cfg, backend="pallas"),
+                           PROFILES[wl])
         sums = {n: int(np.sum(np.asarray(leaf)))
                 for n, leaf in zip(res.counters._fields, res.counters)}
         assert sums == g["counter_sums"], f"{key}: fused counter drift"
@@ -597,7 +671,8 @@ def test_fused_single_cycle_counters_match_ref():
     for mode, ep_len in [("kf", 1), ("4subnet", 1), ("kf", 3)]:
         cfg = NoCConfig(mode=mode, n_epochs=1, epoch_len=ep_len, seed=3)
         ref = sim.simulate(cfg, PROFILES["BFS"])
-        pal = sim.simulate(cfg, PROFILES["BFS"], backend="pallas")
+        pal = sim.simulate(dataclasses.replace(cfg, backend="pallas"),
+                           PROFILES["BFS"])
         for name, a, b in zip(
             ref.counters._fields, ref.counters, pal.counters
         ):

@@ -8,9 +8,8 @@ Pins the robustness layer from four sides:
      bitwise the pre-fault program, and the healthy x faulty x guarded
      grid still compiles exactly ONE simulate trace;
   3. backend congruence — every registered fault scenario produces a
-     bitwise-identical SimResult AND SimTrace on ref / pallas /
-     pallas_arb (fault masks ride the same lane contract as the
-     architectural state);
+     bitwise-identical SimResult AND SimTrace on ref and pallas (fault
+     masks ride the same lane contract as the architectural state);
   4. self-healing semantics — the innovation gate rejects corrupted
      telemetry, the watchdog resets a poisoned filter, the allocator
      falls back to the fair split while unhealthy and recovers after.
@@ -42,7 +41,7 @@ from repro.core.noc.topology import (
 )
 
 TINY = dict(n_epochs=8, epoch_len=80)
-BACKENDS = ("ref", "pallas", "pallas_arb")
+BACKENDS = ("ref", "pallas")
 
 
 def _bitwise_equal(a, b, label):
@@ -172,17 +171,18 @@ def fault_runs(request):
     cfg = NoCConfig(mode="kf", seed=1, guard=True,
                     faults=request.param, **TINY)
     return request.param, {
-        be: sim.simulate_with_trace(cfg, "SHIFT_PATH_BFS", backend=be)
+        be: sim.simulate_with_trace(dataclasses.replace(cfg, backend=be),
+                                    "SHIFT_PATH_BFS")
         for be in BACKENDS
     }
 
 
 def test_fault_scenarios_backend_congruent(fault_runs):
-    """SimResult AND SimTrace bitwise across ref/pallas/pallas_arb for
-    every registered fault scenario."""
+    """SimResult AND SimTrace bitwise across ref/pallas for every
+    registered fault scenario."""
     name, runs = fault_runs
     res_ref, tr_ref = runs["ref"]
-    for be in ("pallas", "pallas_arb"):
+    for be in BACKENDS[1:]:
         res_be, tr_be = runs[be]
         _bitwise_equal(res_ref, res_be, f"{name}: SimResult ref vs {be}")
         _bitwise_equal(tr_ref, tr_be, f"{name}: SimTrace ref vs {be}")

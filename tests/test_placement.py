@@ -18,7 +18,7 @@ Pins the placement refactor from four sides:
   4. relocation semantics — a scheduled SWAP_MID migration moves every
      non-MC tile at the midpoint epoch (visible in `SimTrace.place_cls`
      and `place_moves_total`), and an active-relocation run is bitwise
-     congruent across ref / pallas / pallas_arb, on 6x6 and on a
+     congruent across ref and pallas, on 6x6 and on a
      non-paper 4x4 grid.
 """
 import json
@@ -52,7 +52,7 @@ from repro.core.noc.topology import (
 
 TINY = dict(n_epochs=8, epoch_len=80)
 FAST = dict(n_epochs=8, epoch_len=100)  # the golden capture's dims
-BACKENDS = ("ref", "pallas", "pallas_arb")
+BACKENDS = ("ref", "pallas")
 GOLDEN_PATH = os.path.join(
     os.path.dirname(__file__), "golden_cycle_engine.json"
 )
@@ -213,8 +213,9 @@ class TestIdentityPlacement:
             for key, g in golden.items():
                 mode, wl, gs, ss = key.split("/")
                 cfg = NoCConfig(mode=mode, static_gpu_vcs=int(gs[1:]),
-                                seed=int(ss[1:]), placement=None, **FAST)
-                res = sim.simulate(cfg, wl, backend=backend)
+                                seed=int(ss[1:]), placement=None,
+                                backend=backend, **FAST)
+                res = sim.simulate(cfg, wl)
                 sums = {n: int(np.sum(np.asarray(leaf)))
                         for n, leaf in zip(res.counters._fields,
                                            res.counters)}
@@ -291,16 +292,20 @@ class TestRelocation:
     def test_active_relocation_congruent_across_backends(self, backend):
         cfg = NoCConfig(mode="kf", placement="SWAP_MID", control="joint",
                         faults="FLAP_BFS", guard=True, **TINY)
-        ref = sim.simulate(cfg, "SHIFT_PATH_BFS", backend="ref")
-        other = sim.simulate(cfg, "SHIFT_PATH_BFS", backend=backend)
+        ref = sim.simulate(dataclasses_replace(cfg, backend="ref"),
+                           "SHIFT_PATH_BFS")
+        other = sim.simulate(dataclasses_replace(cfg, backend=backend),
+                             "SHIFT_PATH_BFS")
         _bitwise_equal(ref, other, f"relocation+faults ref vs {backend}")
 
     @pytest.mark.parametrize("backend", BACKENDS[1:])
     def test_non_paper_grid_congruent_across_backends(self, backend):
         cfg = NoCConfig(mode="kf", width=4, height=4, placement="SWAP_MID",
                         control="joint", **TINY)
-        ref = sim.simulate(cfg, "SHIFT_PATH_BFS", backend="ref")
-        other = sim.simulate(cfg, "SHIFT_PATH_BFS", backend=backend)
+        ref = sim.simulate(dataclasses_replace(cfg, backend="ref"),
+                           "SHIFT_PATH_BFS")
+        other = sim.simulate(dataclasses_replace(cfg, backend=backend),
+                             "SHIFT_PATH_BFS")
         _bitwise_equal(ref, other, f"4x4 ref vs {backend}")
 
     def test_non_paper_grid_runs_and_differs(self):

@@ -82,7 +82,7 @@ def _assert_kernel(compiled):
 
 
 @pytest.mark.parametrize("backend,side", [
-    ("pallas", 6), ("pallas_arb", 6),
+    ("pallas", 6),
     ("pallas", 8),  # 64 routers: the largest grid the lane layout holds
 ])
 def test_sim_program_compiles(one_chip, tpu_lowering, backend, side):
