@@ -37,7 +37,7 @@ def _time(f, *args, reps=3):
 def noc_cycle_entries() -> dict:
     """Time the noc_cycle engines at the paper's shapes (S=4, R=36):
     `simulate` steady-state per engine — the dense ref engine vs one
-    `fused_cycle_kernel` launch per simulated cycle (interpret mode
+    `fused_cycle_kernel` launch per chunk of cycles (interpret mode
     off-TPU)."""
     mode = "compiled" if jax.default_backend() == "tpu" else "interp"
     cfg = noc_sim.NoCConfig(mode="static", static_gpu_vcs=3,

@@ -10,8 +10,9 @@ interface and agree bitwise (tests/test_cycle_engine.py):
                `ref_cycle` over `(S, R, ...)` state.  The CPU path and the
                reference every other engine is held to.
   * `pallas` — `repro.kernels.noc_cycle.engine.pallas_engine`: the fused
-               full-cycle Pallas kernel, one launch per cycle with the
-               carry in lane layout.  The TPU path.
+               full-cycle Pallas kernel, one launch per chunk of K
+               cycles with the carry resident in VMEM in lane layout.
+               The TPU path.
 
 An engine is built as ``build(stc, topo, fab, binj_dtype) -> run`` from the
 run's `SimStatic`, its `Topology`, its `Fabric` and the carry's stamp

@@ -5,8 +5,10 @@ for the interface).  This module owns the lane layout's side of the seam:
 at build it fixes the `fused.LaneDims` and the run's constant lane tables;
 each epoch it builds the mask, profile, placement, policy and per-cycle
 rows, folds the epoch's link faults into the link-exists rows, packs the
-carry into lane layout, scans one `fused_cycle_kernel` launch per cycle
-with the whole state in kernel refs, and unpacks at the epoch's end.
+carry into lane layout, scans one `fused_cycle_kernel` launch per chunk of
+K cycles (`kernel.cycles_per_launch`, from the epoch's length and the
+lane shapes) with the whole state resident in kernel refs across the
+chunk, and unpacks at the epoch's end.
 """
 from __future__ import annotations
 
@@ -23,6 +25,7 @@ from repro.core.noc.engine import (
     ProbeAcc,
 )
 from repro.kernels.noc_cycle import fused as lanes
+from repro.kernels.noc_cycle import kernel as lane_kernel
 from repro.kernels.noc_cycle import ops as lane_ops
 
 
@@ -44,6 +47,7 @@ def pallas_engine(stc, topo, fab: Fabric, binj_dtype):
     # the node-type row and the req_match-bearing policy rows are
     # per-epoch data (placement, DESIGN.md §17) — rebuilt in `run`
     route_rows, exists_rows, _ = lanes.run_consts(lane_dims, topo)
+    K = lane_kernel.cycles_per_launch(lane_dims, stc.epoch_len)
 
     def run(carry, ep: EpochInputs):
         subs, mc, phase, outst, backlog = carry
@@ -77,35 +81,21 @@ def pallas_engine(stc, topo, fab: Fabric, binj_dtype):
         )
         exists_ep = exists_rows * link_rows
         ls0 = lanes.pack_state(lane_dims, subs, mc, outst, backlog, phase)
+        pb0 = lanes.zero_probe(lane_dims) if probe_on else None
+        # the epoch's cycles in chunks of K, one kernel launch a chunk
+        chunks = tuple(
+            x.reshape(x.shape[0] // K, K, *x.shape[1:]) for x in (xi, xf))
 
-        def fused_cycle(ls, x):
-            ls = lane_ops.fused_cycle_step(
-                lane_dims, ls, x[0], x[1], gm_rows, cm_rows, pr_rows,
-                pol_sr, pol_r, ntype_row, route_rows, exists_ep,
-            )
-            return ls, None
+        def launch(lane_carry, x):
+            return lane_ops.fused_cycles(
+                lane_dims, *lane_carry, x[0], x[1], gm_rows, cm_rows,
+                pr_rows, pol_sr, pol_r, ntype_row, route_rows, exists_ep,
+            ), None
 
-        def fused_cycle_probed(carry, x):
-            ls, pb = carry
-            ls, pb = lane_ops.fused_cycle_step(
-                lane_dims, ls, x[0], x[1], gm_rows, cm_rows, pr_rows,
-                pol_sr, pol_r, ntype_row, route_rows, exists_ep,
-                probe=pb,
-            )
-            return (ls, pb), None
-
-        prb = None
-        if probe_on:
-            with set_xla_metadata(noc_layer="cycle.scan"):
-                (ls, pb), _ = jax.lax.scan(
-                    fused_cycle_probed,
-                    (ls0, lanes.zero_probe(lane_dims)),
-                    (xi, xf),
-                )
-            prb = ProbeAcc(*lanes.unpack_probe(lane_dims, pb))
-        else:
-            with set_xla_metadata(noc_layer="cycle.scan"):
-                ls, _ = jax.lax.scan(fused_cycle, ls0, (xi, xf))
+        with set_xla_metadata(noc_layer="cycle.scan"):
+            (ls, pb), _ = jax.lax.scan(launch, (ls0, pb0), chunks)
+        prb = (ProbeAcc(*lanes.unpack_probe(lane_dims, pb)) if probe_on
+               else None)
         subs, mc, outst, backlog, phase = lanes.unpack_state(
             lane_dims, ls, MCState, binj_dtype
         )

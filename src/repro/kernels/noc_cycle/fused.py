@@ -8,8 +8,8 @@ and the metrics counters — rewritten over the packed lane layout below, as
 plain 2D (sublane, lane) ops with NO captured constant arrays.
 `cycle_step_lanes` is therefore callable both as a regular jitted function
 (the dense twin the micro-congruence tests compare stage by stage) and as
-the body of one `pallas_call` per simulated cycle
-(`kernel.fused_cycle_kernel`).
+the body of a `pallas_call` that loops it over a chunk of simulated
+cycles (`kernel.fused_cycle_kernel`).
 
 Lane layout
 -----------

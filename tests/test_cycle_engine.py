@@ -399,6 +399,77 @@ def test_engines_agree_from_a_mid_run_carry(monkeypatch, case):
         )
 
 
+def test_cycles_per_launch_divides_the_epoch_and_fits_vmem():
+    """K is the largest divisor of the epoch whose per-cycle input block
+    fits the VMEM budget: 125 of the paper's 500 cycles, 1 for a prime
+    epoch longer than the budget holds, the whole epoch when it fits."""
+    from repro.kernels.noc_cycle import fused, kernel
+
+    for S in (2, 4):
+        d = _lane_dims(S=S)
+        per_cycle = (kernel._vmem_bytes(fused.XI_ROWS, d.lanes_sr)
+                     + kernel._vmem_bytes(fused.XF_ROWS, fused.LANES_R))
+        for epoch_len in range(1, 1201):
+            k = kernel.cycles_per_launch(d, epoch_len)
+            assert epoch_len % k == 0
+            assert k == 1 or k * per_cycle <= kernel.XS_VMEM_BUDGET
+            assert not any(
+                epoch_len % j == 0 and j * per_cycle <= kernel.XS_VMEM_BUDGET
+                for j in range(k + 1, epoch_len + 1)), (S, epoch_len, k)
+    d = _lane_dims()
+    assert kernel.cycles_per_launch(d, 500) == 125
+    assert kernel.cycles_per_launch(d, 173) == 1
+    assert kernel.cycles_per_launch(d, 40) == 40
+
+
+@pytest.mark.parametrize("probes", [False, True], ids=["probes-off",
+                                                         "probes-on"])
+@pytest.mark.parametrize("epoch_len,k", [
+    (40, 40),   # one launch an epoch
+    (180, 90),  # two launches an epoch: the carry crosses between them
+    (173, 1),   # a prime epoch past the budget: one launch a cycle
+], ids=["one-chunk", "two-chunks", "prime"])
+def test_pallas_engine_chunks_match_ref(epoch_len, k, probes):
+    """The `pallas` engine, K cycles a launch, reproduces the `ref`
+    engine bitwise for every chunking of the epoch, probes off and on."""
+    from repro.kernels.noc_cycle import kernel
+
+    assert kernel.cycles_per_launch(_lane_dims(), epoch_len) == k
+    cfg = NoCConfig(mode="kf", n_epochs=2, epoch_len=epoch_len, seed=3,
+                    probe=ProbeConfig(enabled=probes))
+    ref, pal = (
+        sim.simulate(dataclasses.replace(cfg, backend=b), PROFILES["PATH"])
+        for b in ("ref", "pallas"))
+    assert jax.tree.structure(ref) == jax.tree.structure(pal)
+    for (path, a), (_, b) in zip(
+        jax.tree_util.tree_leaves_with_path(ref),
+        jax.tree_util.tree_leaves_with_path(pal),
+    ):
+        np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b),
+            err_msg=f"K={k}: leaf {jax.tree_util.keystr(path)}",
+        )
+
+
+def test_pallas_sweep_chunks_match_ref():
+    """Under the sweep's `vmap` (a batch grid axis on every launch, the
+    carry aliased in place per point): two launches an epoch, `pallas`
+    against `ref` bitwise, point for point."""
+    specs = [sim.SweepSpec(m, "BFS", seed=s)
+             for m, s in (("kf", 0), ("fair", 1), ("4subnet", 2))]
+    ref, pal = (sim.sweep(specs, n_epochs=2, epoch_len=180, backend=b)
+                for b in ("ref", "pallas"))
+    for i, (a, b) in enumerate(zip(ref, pal, strict=True)):
+        for (path, x), (_, y) in zip(
+            jax.tree_util.tree_leaves_with_path(a),
+            jax.tree_util.tree_leaves_with_path(b),
+        ):
+            np.testing.assert_array_equal(
+                np.asarray(x), np.asarray(y),
+                err_msg=f"point {i}: leaf {jax.tree_util.keystr(path)}",
+            )
+
+
 # ---------------------------------------------------------------------------
 # 4. fused full-cycle kernel (DESIGN.md §13): golden pinning + stage twins
 # ---------------------------------------------------------------------------

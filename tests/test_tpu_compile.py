@@ -93,7 +93,9 @@ def test_sim_program_compiles(one_chip, tpu_lowering, backend, side):
 
 
 def _sweep_tile_args():
-    cfgs = [sim.NoCConfig(mode=m, backend="pallas", **SHORT)
+    # the paper's 500-cycle epochs: 4 launches of 125 cycles an epoch
+    cfgs = [sim.NoCConfig(mode=m, backend="pallas", n_epochs=4,
+                          epoch_len=500)
             for m in ("4subnet", "baseline", "fair", "kf", "kf", "fair")]
     assert len(cfgs) == sim.SWEEP_TILE
     stc, mp, prof, seeds, flt, plc = sim.batch_args(cfgs, "BFS")
@@ -135,8 +137,8 @@ def test_sweep_tile_keeps_its_device_labels(one_chip, tpu_lowering):
     """The `noc_layer` labels of `_simulate_impl` (DESIGN.md §18) survive
     the TPU compiler on the operations a device trace names: top-level
     fusions (the placement and guard labels among them), the cycle loop
-    and the kernel, whose own metadata the scan's label sits beside
-    without replacing it."""
+    and the kernel, whose own metadata (its layer and the cycles it runs
+    a launch) the scan's label sits beside without replacing it."""
     text = _sweep_tile(one_chip).as_text()
     assert re.search(r' fusion\(.*noc_layer="epoch\.rng"', text)
     assert re.search(r' while\(.*noc_layer="cycle\.scan"', text)
@@ -147,6 +149,7 @@ def test_sweep_tile_keeps_its_device_labels(one_chip, tpu_lowering):
     kernel = re.search(
         r'custom_call_target="tpu_custom_call".*?'
         r'frontend_attributes=\{kernel_metadata=\{\s*'
+        r'"cycles_per_launch":125,\s*'
         r'"noc_layer":"cycle\.kernel"\s*\},noc_layer="cycle\.scan"\}',
         text, re.S)
     assert kernel
