@@ -71,9 +71,24 @@ class EpochCounters(NamedTuple):
     moved: Array
 
 
-def zero_counters() -> EpochCounters:
-    z = jnp.int32(0)
-    return EpochCounters(z, z, z, z, z, z, z, z, z, z, z, z, z, z, z)
+# The epoch counters of a run with a peer table (DESIGN.md §19):
+# `EpochCounters`' fields, then the tile-to-tile pair —
+#   gpu_peer_push  GPU request injections toward a GPU tile
+#   peer_served    requests served by a router that is not an MC.
+# A run without one keeps `EpochCounters`, so its program and its answer
+# are those of the MC-only simulator.
+PeerCounters = NamedTuple("PeerCounters", [
+    *((f, Array) for f in EpochCounters._fields),
+    ("gpu_peer_push", Array), ("peer_served", Array)])
+
+
+def counters_type(peers: bool) -> type:
+    return PeerCounters if peers else EpochCounters
+
+
+def zero_counters(peers: bool = False):
+    kind = counters_type(peers)
+    return kind(*(jnp.int32(0) for _ in kind._fields))
 
 
 class ProbeAcc(NamedTuple):
@@ -109,6 +124,10 @@ class Fabric(NamedTuple):
     neighbor: Array     # (R, P) int32
     opposite: Array     # (P,) int32
     is_mc: Array        # (R,) bool — physical: placement never moves MCs
+    # (R,) bool — routers that accept, queue and serve requests and stage
+    # their replies: the MCs, plus the GPU tiles a peer table gives
+    # experts (DESIGN.md §19); `is_mc` itself without one
+    is_server: Array
     nodes: Array        # (R,) router ids
     four_subnet: Array  # () bool — class-segregated routing
     sub_ids: Array      # (S,) int32
@@ -125,7 +144,7 @@ class CycleRows(NamedTuple):
     cycle: Array    # (L,) int32 — absolute cycle
     u_phase: Array  # (L,) float32 — burst-phase uniforms
     u_gen: Array    # (L, R) float32 — generation uniforms
-    dest: Array     # (L, R) int32 — request destinations (MC ids)
+    dest: Array     # (L, R) int32 — request destinations (servers)
     sa: Array       # (L,) int32 — switch-allocation preference
     active: Array   # (L, S) bool — subnet link activation
     gate: Array     # (L,) bool — merged reply inject allowed (not the last)
@@ -149,20 +168,25 @@ class EpochInputs(NamedTuple):
 
 
 def staged_reply_want(fab: Fabric, mc: MCState) -> Array:
-    """Want-matrix for staged MC replies (reply subnet of requester
+    """Want-matrix for staged server replies (reply subnet of requester
     class c is 2c+1 under class-segregated routing, subnet 1 otherwise)."""
     rep_target = jnp.where(fab.four_subnet, 2 * mc.stage_cls + 1, 1)
     return (
         (fab.sub_ids[:, None] == rep_target[None, :])
-        & (mc.stage_valid & fab.is_mc)[None, :]
+        & (mc.stage_valid & fab.is_server)[None, :]
         & fab.sub_enabled[:, None]
     )
 
 
 def ref_cycle(stc, fab: Fabric, ep: EpochInputs, carry, x: CycleRows):
     """One simulated cycle of the dense engine: the `lax.scan` body of
-    `ref_engine` (``stc`` is the run's `SimStatic`)."""
+    `ref_engine` (``stc`` is the run's `SimStatic`).
+
+    The MC role is two masks (DESIGN.md §19): `fab.is_server` accepts,
+    queues and serves requests and stages their replies; `fab.is_mc`
+    generates nothing, injects no requests and completes no replies."""
     is_mc, sub_ids, sub_enabled = fab.is_mc, fab.sub_ids, fab.sub_enabled
+    is_server = fab.is_server
     sub_is_req, sub_is_rep = fab.sub_is_req, fab.sub_is_rep
     is_gpu, is_cpu, flt, prof = ep.is_gpu, ep.is_cpu, ep.flt, ep.prof
     S, R = stc.n_subnets, is_mc.shape[0]
@@ -178,7 +202,7 @@ def ref_cycle(stc, fab: Fabric, ep: EpochInputs, carry, x: CycleRows):
     # up to S/2 packets can arrive at one MC per cycle, so reserve
     # that many slots.
     mc_space = mc.count <= stc.mc_queue_cap - fab.n_req_subs
-    can_accept = jnp.where(is_mc, mc_space, True)  # (R,)
+    can_accept = jnp.where(is_server, mc_space, True)  # (R,)
     accept_s = jnp.where(
         sub_is_req[:, None], can_accept[None, :], True
     )
@@ -186,7 +210,7 @@ def ref_cycle(stc, fab: Fabric, ep: EpochInputs, carry, x: CycleRows):
     # ---- 1. MC service: tick timers, move head request -> staging
     # (a stalled MC — flt.mc_ok False — freezes its timer and
     # staging; the queue keeps filling until it back-pressures)
-    can_serve = is_mc & (mc.count > 0) & ~mc.stage_valid & flt.mc_ok
+    can_serve = is_server & (mc.count > 0) & ~mc.stage_valid & flt.mc_ok
     timer = jnp.where(
         can_serve, jnp.maximum(mc.timer - 1, 0), mc.timer
     )
@@ -225,7 +249,7 @@ def ref_cycle(stc, fab: Fabric, ep: EpochInputs, carry, x: CycleRows):
     # same-MC arrivals into consecutive ring slots; the write is a
     # dense masked where over (R, Q) (no scatter).
     req_ej = (
-        events.eject_valid & sub_is_req[:, None] & is_mc[None, :]
+        events.eject_valid & sub_is_req[:, None] & is_server[None, :]
     )  # (S, R)
     arr_i = req_ej.astype(jnp.int32)
     slot_off = jnp.cumsum(arr_i, axis=0) - arr_i
@@ -306,7 +330,7 @@ def ref_cycle(stc, fab: Fabric, ep: EpochInputs, carry, x: CycleRows):
 
     # ---- 6. counters
     gpu_blocked = is_gpu & (bl_count > 0)  # shader stuck at ICNT
-    cnt = EpochCounters(
+    cnt = cnt._replace(
         gpu_push=cnt.gpu_push
         + jnp.sum((inj_ok & is_gpu).astype(jnp.int32)),
         gpu_stall_icnt=cnt.gpu_stall_icnt
@@ -333,6 +357,14 @@ def ref_cycle(stc, fab: Fabric, ep: EpochInputs, carry, x: CycleRows):
         + jnp.sum(gpu_ej.astype(jnp.int32)),
         moved=cnt.moved + events.moved,
     )
+    if stc.peer_weights is not None:
+        to_peer = ~jnp.take(is_mc, dests)
+        cnt = cnt._replace(
+            gpu_peer_push=cnt.gpu_peer_push
+            + jnp.sum((inj_ok & is_gpu & to_peer).astype(jnp.int32)),
+            peer_served=cnt.peer_served
+            + jnp.sum((done & ~is_mc).astype(jnp.int32)),
+        )
     if stc.probe.enabled:
         # ---- 7. flight-recorder accumulation from END-of-cycle
         # state (the fused engine samples at the same point)
@@ -358,7 +390,8 @@ def ref_engine(stc, topo, fab: Fabric, binj_dtype):
 
     def run(carry, ep: EpochInputs):
         subs, mc, phase, outst, backlog = carry
-        inner0 = (subs, mc, phase, outst, backlog, zero_counters())
+        inner0 = (subs, mc, phase, outst, backlog,
+                  zero_counters(stc.peer_weights is not None))
         if probe_on:
             inner0 = inner0 + (zero_probe_acc(S, R, V),)
         with set_xla_metadata(noc_layer="cycle.scan"):

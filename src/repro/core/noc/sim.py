@@ -85,6 +85,7 @@ from repro.core.noc.engine import (
     EpochInputs,
     Fabric,
     MCState,
+    PeerCounters,
     ref_engine,
     staged_reply_want,
 )
@@ -101,10 +102,11 @@ from repro.core.noc.placement import (
     PlacementStream,
     resolve_placement,
 )
-from repro.core.noc.topology import make_topology
+from repro.core.noc.topology import NT_GPU, Topology, make_topology
 from repro.obs.probes import ProbeConfig, SimTrace
 from repro.obs.profiling import span
 from repro.core.noc.traffic import (
+    PeerProfile,
     TrafficSource,
     TrafficSourceLike,
     WorkloadProfile,
@@ -119,6 +121,64 @@ Array = jax.Array
 # large enough for the 4-subnet network; 2-subnet modes leave rows 2..3
 # zero-width (never injected into, links never active).
 S_MAX = 4
+
+# Tile-to-tile destinations (DESIGN.md §19).  The peer draw's key is the
+# epoch key folded with DEST_STREAM: past any cycle index, so it never
+# meets a key of the epoch's per-cycle split and every existing stream
+# stays as it was.  A draw is the top PEER_BITS bits of one uint32 a
+# (cycle, router); the peer pick scales the share's threshold by the
+# source's cumulative peer weights in int32, hence the cap on their sum.
+DEST_STREAM = 0xFFFFFFFF
+PEER_BITS = 23
+PEER_WEIGHT_MAX = (2**31 - 1) // 2**PEER_BITS      # 255
+
+
+class PeerTable(NamedTuple):
+    """A peer table lowered for the destination draw (host arrays): the
+    routers that hold experts, and per source router the inclusive
+    cumulative weight over them with the source's own left out."""
+
+    ids: np.ndarray    # (H,) int32 — routers with a nonzero weight
+    cum: np.ndarray    # (R, H) int32
+
+
+def peer_dests(peers: PeerTable, epoch_key, share, is_gpu, dests):
+    """An epoch's request destinations `dests` (L, R) with peer ones drawn
+    in (DESIGN.md §19): one PEER_BITS-bit draw u a (cycle, router) from
+    the epoch's own stream; a GPU source with peers sends to a peer iff
+    u < t = floor(share * 2^PEER_BITS), and then to the first peer whose
+    bound t * cum // total exceeds u, so each peer of a source is drawn in
+    proportion to its weight and the source never is."""
+    key = jax.random.fold_in(epoch_key, DEST_STREAM)
+    u = (jax.random.bits(key, dests.shape, jnp.uint32)
+         >> (32 - PEER_BITS)).astype(jnp.int32)
+    t = jnp.floor(share * float(2**PEER_BITS)).astype(jnp.int32)
+    total = peers.cum[:, -1]
+    bound = t * peers.cum // np.maximum(total, 1)[:, None]
+    pick = jnp.sum(bound[None] <= u[..., None], axis=-1)
+    to_peer = is_gpu & (total > 0) & (u < t)
+    return jnp.where(to_peer, jnp.take(peers.ids, pick, mode="clip"), dests)
+
+
+def peer_table(weights: Sequence[int], topo: Topology) -> PeerTable:
+    """Validate `NoCConfig.peer_weights` against the package and lower it.
+
+    One non-negative integer a router, in router order, nonzero only on
+    GPU tiles, summing to at most PEER_WEIGHT_MAX."""
+    w = np.asarray(weights)
+    R = topo.n_routers
+    if w.shape != (R,) or not np.issubdtype(w.dtype, np.integer):
+        raise ValueError(
+            f"peer_weights needs one integer per router ({R}); got {weights}")
+    if (w < 0).any() or w.sum() == 0 or w.sum() > PEER_WEIGHT_MAX:
+        raise ValueError(
+            "peer_weights must be non-negative with a sum in "
+            f"[1, {PEER_WEIGHT_MAX}]; got sum {int(w.sum())}")
+    if (w[topo.node_type != NT_GPU] != 0).any():
+        raise ValueError("peer_weights may be nonzero on GPU tiles only")
+    ids = np.flatnonzero(w).astype(np.int32)
+    own = np.where(np.arange(R)[:, None] == ids[None, :], 0, w[ids][None, :])
+    return PeerTable(ids, np.cumsum(own, axis=1).astype(np.int32))
 
 
 def platform_engine() -> str:
@@ -184,6 +244,10 @@ class SimStatic:
     width: int = 6
     height: int = 6
     n_mc: int = 8
+    # tile-to-tile destinations (DESIGN.md §19): the experts each router
+    # holds, in router order.  None traces the MC-only program; a table
+    # makes its GPU tiles servers and draws peer destinations.
+    peer_weights: tuple[int, ...] | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -238,6 +302,8 @@ class NoCConfig:
     width: int = 6
     height: int = 6
     n_mc: int = 8
+    # tile-to-tile destinations (DESIGN.md §19) — structural; see SimStatic
+    peer_weights: tuple[int, ...] | None = None
 
     @property
     def n_subnets(self) -> int:
@@ -274,6 +340,8 @@ class NoCConfig:
             width=self.width,
             height=self.height,
             n_mc=self.n_mc,
+            peer_weights=(None if self.peer_weights is None
+                          else tuple(int(w) for w in self.peer_weights)),
         )
 
     def mode_policy(self, padded: bool = True) -> ModePolicy:
@@ -292,7 +360,8 @@ class SimResult(NamedTuple):
     avg_latency: Array    # (E,) mean packet network latency
     kf_signal: Array      # (E,) binarized KF output
     applied_config: Array  # (E,) configuration actually applied
-    counters: EpochCounters  # (E,) leaves
+    # (E,) leaves: `EpochCounters`, or `PeerCounters` with a peer table
+    counters: EpochCounters | PeerCounters
     gpu_inj_rate: Array   # (E,) offered GPU load (Fig. 4 trace)
     # VCs the GPU class could occupy during the epoch — pins the hoisted
     # per-epoch masks to the policy state that entered the epoch (the mask
@@ -408,7 +477,13 @@ def _simulate_impl(
     runs share this ONE trace; the identity stream carries the topology's
     own layout, making a static run's derived values bit-for-bit the
     pre-placement program's.  MCs are physical and never relocate: `is_mc`
-    stays a static table and the virtual node type re-asserts it."""
+    stays a static table and the virtual node type re-asserts it.
+
+    With a peer table (``stc.peer_weights``, DESIGN.md §19) the GPU tiles
+    holding experts serve requests beside the MCs (`Fabric.is_server`),
+    and a GPU source sends a request to a peer with probability
+    ``profile.gpu_peer_share`` of a `traffic.PeerProfile` (0 for a plain
+    `WorkloadProfile`; label `epoch.dest`)."""
     _trace_counter[0] += 1  # Python side effect: runs only at trace time
 
     topo = make_topology(stc.width, stc.height, stc.n_mc)
@@ -419,6 +494,10 @@ def _simulate_impl(
 
     is_mc = ntype == 2  # static: MCs are physical, placement never moves them
     ar = jnp.arange(R)
+    peers = None if stc.peer_weights is None else peer_table(
+        stc.peer_weights, topo)
+    is_server = is_mc if peers is None else (
+        is_mc | jnp.asarray(np.isin(np.arange(R), peers.ids)))
 
     # Traced subnet structure (DESIGN.md §10): which rows of the padded
     # subnet axis are live, which carry requests, and whether routing is
@@ -449,7 +528,8 @@ def _simulate_impl(
     # the cycle engine (DESIGN.md §11, §13), built once a run and called
     # once an epoch; both engines agree bitwise (tests/test_cycle_engine.py)
     fab = Fabric(
-        route=route_t, neighbor=nb_t, opposite=opp_t, is_mc=is_mc, nodes=ar,
+        route=route_t, neighbor=nb_t, opposite=opp_t, is_mc=is_mc,
+        is_server=is_server, nodes=ar,
         four_subnet=fs, sub_ids=sub_ids, sub_enabled=sub_enabled,
         sub_is_req=sub_is_req, sub_is_rep=sub_is_rep, n_req_subs=n_req_subs,
     )
@@ -526,6 +606,12 @@ def _simulate_impl(
                 lambda k: jax.random.randint(k, (R,), 0, mc_ids.shape[0])
             )(k3[:, 2])
             dests_all = jnp.take(mc_ids, d_idx)                 # (L, R)
+        if peers is not None:
+            with set_xla_metadata(noc_layer="epoch.dest"):
+                share = (prof.gpu_peer_share if isinstance(prof, PeerProfile)
+                         else jnp.float32(0.0))
+                dests_all = peer_dests(peers, epoch_key, share, is_gpu,
+                                       dests_all)
         cycles = cycle0 + jnp.arange(ep_len, dtype=jnp.int32)
         sa_all = epoch_sa_prefs(mp, config_idx, cycles)         # (L,)
         # subnet link activation: full width (2-subnet) or alternating-cycle
@@ -631,6 +717,10 @@ def _simulate_impl(
     if probe_on:
         outs, (prb, kfi, z_obs, faults_active, place_cls) = outs
     gpu_ipc, cpu_ipc, avg_lat, sig, conf, cnt, inj, quota = outs
+    # the tile-to-tile channels read 0 without a peer table
+    peer_push, peer_served = (
+        (cnt.gpu_peer_push, cnt.peer_served) if peers is not None
+        else (jnp.zeros_like(cnt.moved),) * 2)
     result = SimResult(
         gpu_ipc=gpu_ipc,
         cpu_ipc=cpu_ipc,
@@ -660,6 +750,8 @@ def _simulate_impl(
         kf_healthy=kfi.healthy,
         faults_active=faults_active,
         place_cls=place_cls,
+        gpu_peer_push=peer_push,
+        peer_served=peer_served,
     )
     return result, trace
 
@@ -704,9 +796,18 @@ def _run_faults(source: FaultSourceLike, stc: SimStatic) -> FaultStream:
 def _run_placement(
     source: PlacementSourceLike, stc: SimStatic
 ) -> PlacementStream:
-    """Lower a config's placement source against the run topology."""
+    """Lower a config's placement source against the run topology.  A
+    peer table pins experts to tiles, so it takes the identity plan
+    only."""
     topo = make_topology(stc.width, stc.height, stc.n_mc)
-    return resolve_placement(source, stc.n_epochs, topo)
+    stream = resolve_placement(source, stc.n_epochs, topo)
+    if stc.peer_weights is not None:
+        peer_table(stc.peer_weights, topo)
+        if any((np.asarray(c) != topo.node_type).any() for c in stream):
+            raise ValueError(
+                "a peer table pins experts to GPU tiles: its runs take the "
+                "identity placement only")
+    return stream
 
 
 def simulate(

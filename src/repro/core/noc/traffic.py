@@ -86,7 +86,9 @@ class WorkloadProfile(NamedTuple):
         lowering is value-invisible (pinned by
         tests/test_predictor_ablation.py).  Already-per-epoch leaves pass
         through after a length check, so a materialized ``EpochDemand`` is
-        itself a valid source.
+        itself a valid source.  Every leaf lowers alike, so a
+        `PeerProfile` (which shares this method) comes back a
+        `PeerProfile` with its ``gpu_peer_share`` row.
         """
 
         def lower(x):
@@ -101,6 +103,31 @@ class WorkloadProfile(NamedTuple):
             return x
 
         return jax.tree.map(lower, self)
+
+
+class PeerProfile(NamedTuple):
+    """A `WorkloadProfile` whose GPU tiles also send requests to each
+    other (expert-parallel dispatch, DESIGN.md §19): its five rates, then
+    `gpu_peer_share`, the share of GPU requests addressed to a GPU tile
+    that holds experts instead of to an MC.  A run draws such peers only
+    where its `NoCConfig.peer_weights` names tiles that hold experts.
+
+    A type of its own rather than a sixth `WorkloadProfile` leaf, so every
+    profile, schedule and recorded trace of the five rates keeps its
+    five-leaf pytree.  It lowers like a `WorkloadProfile`, one row per
+    leaf."""
+
+    gpu_rate_lo: float | Array
+    gpu_rate_hi: float | Array
+    p_enter: float | Array
+    p_exit: float | Array
+    cpu_rate: float | Array = 0.12
+    gpu_peer_share: float | Array = 0.0
+
+    epoch_demand = WorkloadProfile.epoch_demand
+
+
+assert PeerProfile._fields[:-1] == WorkloadProfile._fields
 
 
 # Burstiness/demand ordering mirrors the paper's figures: BFS and MUM show the
@@ -127,8 +154,12 @@ PROFILES: dict[str, WorkloadProfile] = {
 
 def stack_profiles(profiles: Iterable[WorkloadProfile]) -> WorkloadProfile:
     """Stack profiles into one pytree with (B, ...) float32 NumPy leaves
-    (vmap axis 0)."""
+    (vmap axis 0).  Where any is a `PeerProfile`, the others join it with
+    a zero share."""
     rows = list(profiles)
+    if any(isinstance(p, PeerProfile) for p in rows):
+        rows = [p if isinstance(p, PeerProfile) else PeerProfile(
+            *p, gpu_peer_share=np.zeros_like(p.gpu_rate_lo)) for p in rows]
     return jax.tree.map(
         lambda *xs: np.stack(xs).astype(np.float32, copy=False), *rows
     )
@@ -426,7 +457,8 @@ class TrafficSource(Protocol):
     """Anything that can lower itself to per-epoch demand rows.
 
     ``epoch_demand(n_epochs)`` must return an ``EpochDemand``: a
-    ``WorkloadProfile`` whose five leaves are ``(n_epochs,)`` float32 rows.
+    ``WorkloadProfile`` whose five leaves are ``(n_epochs,)`` float32 rows,
+    or a ``PeerProfile`` whose six are.
     ``resolve_source`` validates that contract after the call, so custom
     sources cannot silently feed the simulator a second program shape.
     """
@@ -709,7 +741,9 @@ def resolve_source(source: "TrafficSourceLike", n_epochs: int) -> EpochDemand:
                             to ``WorkloadProfile`` for one release.
 
     The result is validated to have exactly ``(n_epochs,)`` float32 leaves,
-    so every source kind feeds the simulator the same program shape.  Its
+    so every source kind feeds the simulator the same program shape; a
+    `PeerProfile` source lowers to a `PeerProfile` of rows, its share in
+    [0, 1].  Its
     leaves are host (NumPy) rows whatever the source returned: the
     simulator's argument layer builds on the host and crosses to the
     device once per dispatch (DESIGN.md §18).
@@ -730,8 +764,9 @@ def resolve_source(source: "TrafficSourceLike", n_epochs: int) -> EpochDemand:
                 "TrafficSource"
             )
     demand = source.epoch_demand(n_epochs)
+    kind = PeerProfile if isinstance(demand, PeerProfile) else WorkloadProfile
     rows = {}
-    for f in WorkloadProfile._fields:
+    for f in kind._fields:
         leaf = getattr(demand, f)
         if tuple(leaf.shape) != (n_epochs,) or leaf.dtype != np.float32:
             raise ValueError(
@@ -753,9 +788,15 @@ def resolve_source(source: "TrafficSourceLike", n_epochs: int) -> EpochDemand:
                 f"source {type(source).__name__} produced negative demand "
                 f"in leaf {f!r}"
             )
-    return WorkloadProfile(**rows)
+    if kind is PeerProfile and np.any(rows["gpu_peer_share"] > 1):
+        raise ValueError(
+            f"source {type(source).__name__} produced a gpu_peer_share "
+            "above 1"
+        )
+    return kind(**rows)
 
 
 # The union accepted by resolve_source (and, transitionally, the old
 # entry-point signatures): a workload name or any TrafficSource.
-TrafficSourceLike = str | WorkloadProfile | ScenarioSchedule | RecordedTrace
+TrafficSourceLike = (str | WorkloadProfile | PeerProfile | ScenarioSchedule
+                     | RecordedTrace)

@@ -18,11 +18,12 @@ from jax.experimental.xla_metadata import set_xla_metadata
 
 from repro.core.noc.engine import (
     BCAP,
-    EpochCounters,
     EpochInputs,
     Fabric,
     MCState,
+    PeerCounters,
     ProbeAcc,
+    counters_type,
 )
 from repro.kernels.noc_cycle import fused as lanes
 from repro.kernels.noc_cycle import kernel as lane_kernel
@@ -31,8 +32,8 @@ from repro.kernels.noc_cycle import ops as lane_ops
 
 def pallas_engine(stc, topo, fab: Fabric, binj_dtype):
     """The fused engine for a run (``stc`` is its `SimStatic`)."""
-    assert lanes.COUNTER_FIELDS == EpochCounters._fields, (
-        "fused kernel counter lanes out of sync with EpochCounters"
+    assert lanes.COUNTER_FIELDS == PeerCounters._fields, (
+        "fused kernel counter lanes out of sync with PeerCounters"
     )
     S, R = stc.n_subnets, topo.n_routers
     probe_on = stc.probe.enabled
@@ -43,7 +44,9 @@ def pallas_engine(stc, topo, fab: Fabric, binj_dtype):
         S=S, R=R, V=stc.n_vcs, B=stc.buf_depth, Q=stc.mc_queue_cap,
         width=topo.width, mc_service_period=stc.mc_service_period,
         mshr_limit=stc.mshr_limit, bcap=BCAP, stamp_mask=stamp_mask,
+        peer_ids=tuple(i for i, w in enumerate(stc.peer_weights or ()) if w),
     )
+    counters = counters_type(stc.peer_weights is not None)
     # the node-type row and the req_match-bearing policy rows are
     # per-epoch data (placement, DESIGN.md §17) — rebuilt in `run`
     route_rows, exists_rows, _ = lanes.run_consts(lane_dims, topo)
@@ -99,9 +102,7 @@ def pallas_engine(stc, topo, fab: Fabric, binj_dtype):
         subs, mc, outst, backlog, phase = lanes.unpack_state(
             lane_dims, ls, MCState, binj_dtype
         )
-        cnt = EpochCounters(
-            *(ls.cnt[0, i] for i in range(lanes.N_COUNTERS))
-        )
+        cnt = counters(*(ls.cnt[0, i] for i in range(len(counters._fields))))
         return (subs, mc, phase, outst, backlog), cnt, prb
 
     return run

@@ -81,14 +81,14 @@ MC_ROWS = 6
 # `node` row indices
 ND_OUTST, ND_BACKLOG, ND_PHASE = range(3)
 ND_ROWS = 3
-# counter lanes — must equal engine.EpochCounters._fields (asserted at build)
+# counter lanes — must equal engine.PeerCounters._fields (asserted at build);
+# the last two (DESIGN.md §19) are kept only with peer tiles
 COUNTER_FIELDS = (
     "gpu_push", "gpu_stall_icnt", "gpu_stall_dram", "cpu_push",
     "gpu_done", "cpu_done", "gpu_gen", "cpu_gen",
     "lat_sum", "lat_cnt", "cpu_lat_sum", "cpu_lat_cnt",
-    "gpu_lat_sum", "gpu_lat_cnt", "moved",
+    "gpu_lat_sum", "gpu_lat_cnt", "moved", "gpu_peer_push", "peer_served",
 )
-N_COUNTERS = len(COUNTER_FIELDS)
 
 # per-cycle xs rows (int / float blocks); XI_MCOK carries the per-epoch MC
 # fault mask (DESIGN.md §16) — R-padded then S-tiled like every lane row, and
@@ -112,7 +112,10 @@ class LaneDims(NamedTuple):
     uint16 injection stamps (total cycles <= 2^16) and 0 for int32 stamps;
     the lane engine carries stamps as int32 and applies the mask to the
     latency subtraction, which reproduces the uint16 wraparound arithmetic
-    bit for bit (see `cycle_step_lanes`).
+    bit for bit (see `cycle_step_lanes`).  `peer_ids` are the GPU tiles a
+    peer table gives experts (DESIGN.md §19): they serve requests beside
+    the MCs, and the peer counters are kept; empty, the kernel is the
+    MC-only one.
     """
 
     S: int
@@ -125,6 +128,7 @@ class LaneDims(NamedTuple):
     mshr_limit: int
     bcap: int
     stamp_mask: int
+    peer_ids: tuple[int, ...] = ()
 
     @property
     def PV(self) -> int:
@@ -339,10 +343,12 @@ def _s_slices(d: LaneDims, x: Array):
 # ---------------------------------------------------------------------------
 
 def mc_service_lanes(
-    d: LaneDims, mc: Array, mcq: Array, ntype: Array,
+    d: LaneDims, mc: Array, mcq: Array, server: Array,
     mc_ok: Array | None = None,
 ):
-    """MC service tick: timers, head request -> staging (ref_cycle stage 1).
+    """Server tick: timers, head request -> staging (ref_cycle stage 1) on
+    the lanes of `server` (1, 128) bool, the MCs and any GPU tile that
+    serves peers.
 
     Returns the six updated `mc` rows; the queue head peek is a Q-step
     one-hot sum (head is always in [0, Q), so it equals the dense
@@ -354,12 +360,11 @@ def mc_service_lanes(
     `can_serve & mc_ok` gate.  None behaves as all-True.
     """
     i32 = jnp.int32
-    is_mc = ntype == NT_MC
     head = mc[MC_HEAD:MC_HEAD + 1]
     count = mc[MC_COUNT:MC_COUNT + 1]
     svalid = mc[MC_SVALID:MC_SVALID + 1] != 0
 
-    can_serve = is_mc & (count > 0) & ~svalid
+    can_serve = server & (count > 0) & ~svalid
     if mc_ok is not None:
         can_serve = can_serve & mc_ok
     timer = jnp.where(
@@ -597,16 +602,17 @@ def mc_enqueue_lanes(
 
 
 def counter_row(d: LaneDims, values: dict) -> Array:
-    """Scatter the 15 counter increments onto their `cnt` lanes.
+    """Scatter the counter increments onto their `cnt` lanes.
 
-    `values` maps every COUNTER_FIELDS name to its scalar increment; the
-    row add is a 15-step one-hot sum so the kernel never materializes a
-    scatter.
+    `values` maps COUNTER_FIELDS names to their scalar increments (a name
+    left out adds nothing); the row add is a one-hot sum so the kernel
+    never materializes a scatter.
     """
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, LANES_R), 1)
     inc = jnp.zeros((1, LANES_R), jnp.int32)
     for i, name in enumerate(COUNTER_FIELDS):
-        inc = inc + jnp.where(iota == i, values[name], 0)
+        if name in values:
+            inc = inc + jnp.where(iota == i, values[name], 0)
     return inc
 
 
@@ -617,7 +623,7 @@ def cycle_step_lanes(
     xf: Array,      # (XF_ROWS, 128) float32 — per-cycle uniforms
     gmask: Array,   # (V, S*64) int32 0/1 — epoch VC masks
     cmask: Array,   # (V, S*64) int32 0/1
-    prof: Array,    # (5, 128) float32 — WorkloadProfile rows
+    prof: Array,    # (n_rates, 128) float32 — WorkloadProfile rows
     pol_sr: Array,  # (PS_ROWS, S*64) int32 — subnet structure rows
     pol_r: Array,   # (PR_ROWS, 128) int32
     ntype: Array,   # (1, 128) int32 (padded lanes -1)
@@ -663,16 +669,27 @@ def cycle_step_lanes(
     is_mc_sr = _tile_r(is_mc_r, S)
     node_cls_sr = _tile_r(jnp.where(is_gpu_r, 1, 0), S)
     sub_id_sr = jax.lax.broadcasted_iota(i32, cycle.shape, 1) // R_PAD
+    # the servers (DESIGN.md §19): the MCs, plus the peer tiles
+    is_server_r, is_server_sr = is_mc_r, is_mc_sr
+    if d.peer_ids:
+        lane_r = jax.lax.broadcasted_iota(i32, ntype.shape, 1)
+        for p in d.peer_ids:
+            is_server_r = is_server_r | (lane_r == p)
+        is_server_sr = _tile_r(is_server_r, S)
 
-    # ---- MC acceptance: queue depth BEFORE this cycle's service
+    # ---- server acceptance: queue depth BEFORE this cycle's service
     mc_count0 = st.mc[MC_COUNT:MC_COUNT + 1]
-    can_accept = ~is_mc_r | (mc_count0 <= Q - n_req)
+    can_accept = ~is_server_r | (mc_count0 <= Q - n_req)
     accept = ~sub_req | _tile_r(can_accept, S)
 
-    # ---- 1. MC service
+    # ---- 1. server service
     mc_head, mc_count, mc_timer, svalid, sdst, scls = mc_service_lanes(
-        d, st.mc, st.mcq, ntype, mc_ok
+        d, st.mc, st.mcq, is_server_r, mc_ok
     )
+    if d.peer_ids:  # requests a peer tile took into service this cycle
+        peer_served = jnp.sum((
+            svalid & ~(st.mc[MC_SVALID:MC_SVALID + 1] != 0) & ~is_mc_r
+        ).astype(i32))
 
     # ---- 2. route/arbitrate every subnet
     (buf_meta, buf_binj, head, count, rr,
@@ -683,8 +700,8 @@ def cycle_step_lanes(
         gmask_b, cmask_b, sa, accept, active, route, exists,
     )
 
-    # ---- 3a. request ejections at MCs -> MC queues
-    req_ej = ej & sub_req & is_mc_sr
+    # ---- 3a. request ejections at servers -> their queues
+    req_ej = ej & sub_req & is_server_sr
     q_val = eject_src + (eject_cls << META_SRC_SHIFT)
     mcq, mc_count64, _ = mc_enqueue_lanes(
         d, st.mcq, mc_head, mc_count, req_ej, q_val
@@ -716,9 +733,7 @@ def cycle_step_lanes(
     gpu_ej = ej & (eject_cls == 1)
 
     # ---- 4. source generation -> per-node source-queue depth
-    prof_t = WorkloadProfile(
-        *(prof[i:i + 1] for i in range(len(WorkloadProfile._fields)))
-    )
+    prof_t = WorkloadProfile(*(prof[i:i + 1] for i in range(prof.shape[0])))
     phase = step_phase_u(prof_t, st.node[ND_PHASE:ND_PHASE + 1], u_ph)
     rates = injection_rates(prof_t, ntype, phase)
     gen = (u_gen < rates) & ~is_mc_r
@@ -732,7 +747,7 @@ def cycle_step_lanes(
     rep_target = jnp.where(fs_sr, 2 * _tile_r(scls, S) + 1, 1)
     want_rep = (
         (sub_id_sr == rep_target)
-        & _tile_r(svalid & is_mc_r, S)
+        & _tile_r(svalid & is_server_r, S)
         & sub_en & gate
     )
     dest_i = jnp.where(sub_req, dests, _tile_r(sdst, S))
@@ -758,7 +773,7 @@ def cycle_step_lanes(
 
     # ---- 6. counters
     gpu_blocked = is_gpu_r & (backlog > 0)
-    inc = counter_row(d, {
+    counts = {
         "gpu_push": jnp.sum((inj_ok & is_gpu_r).astype(i32)),
         "gpu_stall_icnt": jnp.sum(gpu_blocked.astype(i32)),
         "gpu_stall_dram": dram_gpu,
@@ -774,7 +789,16 @@ def cycle_step_lanes(
         "gpu_lat_sum": jnp.sum(jnp.where(gpu_ej, ej_lat, 0)),
         "gpu_lat_cnt": jnp.sum(gpu_ej.astype(i32)),
         "moved": moved,
-    })
+    }
+    if d.peer_ids:
+        dest_r = dests[:, :LANES_R]
+        to_peer = jnp.zeros(dest_r.shape, jnp.bool_)
+        for p in d.peer_ids:
+            to_peer = to_peer | (dest_r == p)
+        counts["gpu_peer_push"] = jnp.sum(
+            (inj_ok & is_gpu_r & to_peer).astype(i32))
+        counts["peer_served"] = peer_served
+    inc = counter_row(d, counts)
 
     mc_rows = jnp.concatenate(
         [mc_head, mc_count, mc_timer, svalid.astype(i32), sdst, scls], axis=0
@@ -811,13 +835,14 @@ def cycle_step_lanes(
 def lane_dims(
     *, S: int, R: int, V: int, B: int, Q: int, width: int,
     mc_service_period: int, mshr_limit: int, bcap: int, stamp_mask: int,
+    peer_ids: tuple[int, ...] = (),
 ) -> LaneDims:
     assert R <= R_PAD <= LANES_R, (R, R_PAD, LANES_R)
     assert (S * R_PAD) % LANES_R == 0, (S, R_PAD)
     return LaneDims(
         S=S, R=R, V=V, B=B, Q=Q, width=width,
         mc_service_period=mc_service_period, mshr_limit=mshr_limit,
-        bcap=bcap, stamp_mask=stamp_mask,
+        bcap=bcap, stamp_mask=stamp_mask, peer_ids=tuple(peer_ids),
     )
 
 
@@ -888,11 +913,12 @@ def mask_rows(d: LaneDims, g_vec: Array, c_vec: Array):
 
 
 def prof_rows(prof: WorkloadProfile) -> Array:
-    """This epoch's scalar profile leaves broadcast to (n_fields, 128)."""
+    """This epoch's rates (the `WorkloadProfile` leaves, a `PeerProfile`'s
+    share left to the epoch layer) broadcast to (n_rates, 128)."""
     return jnp.stack(
         [
             jnp.broadcast_to(jnp.asarray(leaf, jnp.float32), (LANES_R,))
-            for leaf in prof
+            for leaf in prof[:len(WorkloadProfile._fields)]
         ],
         axis=0,
     )

@@ -76,6 +76,10 @@ class SimTrace(NamedTuple):
     # relocation timeline `noc_trace` renders.  Appended LAST so older
     # positional consumers of the fault channels keep their indices.
     place_cls: Array      # (E, R) int32 node class per router (NT_* values)
+    # tile-to-tile channels (DESIGN.md §19): the epoch counters of the same
+    # names, 0 without a peer table.  Appended after the placement channel.
+    gpu_peer_push: Array  # (E,)   int32 GPU requests injected to a GPU tile
+    peer_served: Array    # (E,)   int32 requests served by a non-MC router
 
 
 def summarize_trace(trace: SimTrace) -> dict:

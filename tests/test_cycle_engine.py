@@ -653,7 +653,7 @@ def test_fused_mc_service_stage_matches_dense():
     )
     ntype_row = jnp.pad(ntype, (0, 128 - R), constant_values=-1)[None, :]
     head, count, timer_l, svalid, sdst, scls = fused.mc_service_lanes(
-        d, ls.mc, ls.mcq, ntype_row
+        d, ls.mc, ls.mcq, ntype_row == 2
     )
     for name, ref_v, lane_row in [
         ("head", ref.head, head), ("count", ref.count, count),

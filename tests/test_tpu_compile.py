@@ -92,19 +92,28 @@ def test_sim_program_compiles(one_chip, tpu_lowering, backend, side):
         sim._SIM_JIT.lower(stc, *_abstract(args, one_chip)).compile())
 
 
-def _sweep_tile_args():
+# the routed experts of the expert-parallel configuration on the 6x6
+# package's GPU tiles (bench/configs/noc6x6-moe-ep.json)
+PEER_WEIGHTS = tuple(
+    {4: 5, 7: 5, 9: 5, 11: 5, 13: 5, 15: 5, 17: 5, 19: 5, 21: 4, 23: 4,
+     25: 4, 27: 4, 29: 4, 34: 4}.get(r, 0) for r in range(36))
+
+
+def _sweep_tile_args(peer_weights=None):
     # the paper's 500-cycle epochs: 4 launches of 125 cycles an epoch
     cfgs = [sim.NoCConfig(mode=m, backend="pallas", n_epochs=4,
-                          epoch_len=500)
+                          epoch_len=500, peer_weights=peer_weights)
             for m in ("4subnet", "baseline", "fair", "kf", "kf", "fair")]
     assert len(cfgs) == sim.SWEEP_TILE
-    stc, mp, prof, seeds, flt, plc = sim.batch_args(cfgs, "BFS")
+    workload = "BFS" if peer_weights is None else \
+        sim.PeerProfile(0.03, 0.4, 0.0003, 0.00012, 0.12, 0.39)
+    stc, mp, prof, seeds, flt, plc = sim.batch_args(cfgs, workload)
     state0 = sim.init_sim_state(stc, len(cfgs))
     return stc, (mp, prof, seeds, state0, flt, plc)
 
 
-def _sweep_tile(one_chip):
-    stc, args = _sweep_tile_args()
+def _sweep_tile(one_chip, peer_weights=None):
+    stc, args = _sweep_tile_args(peer_weights)
     return sim._batch_jit().lower(stc, *_abstract(args, one_chip)).compile()
 
 
@@ -131,6 +140,15 @@ def test_sweep_tile_from_host_arguments_is_the_same_program(
 
 def test_pallas_sweep_tile_compiles(one_chip, tpu_lowering):
     _assert_kernel(_sweep_tile(one_chip))
+
+
+def test_peer_sweep_tile_compiles_with_its_label(one_chip, tpu_lowering):
+    """The expert-parallel tile (DESIGN.md §19): the kernel with its GPU
+    servers and peer counters, and the destination draw under its own
+    `epoch.dest` label."""
+    text = _sweep_tile(one_chip, PEER_WEIGHTS).as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r'noc_layer="epoch\.dest"', text)
 
 
 def test_sweep_tile_keeps_its_device_labels(one_chip, tpu_lowering):
